@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import jax
 
-from benchmarks.common import Row, time_fn
+from benchmarks.common import Row, pallas_interpreter_off_tpu, time_fn
 from repro.configs.splitme_dnn import DNN10
 from repro.core.cost import SystemParams
 from repro.core.splitme import SplitMeTrainer
@@ -27,7 +27,8 @@ def run(fast: bool = False):
         lambda x: x.block_until_ready() if hasattr(x, "block_until_ready")
         else x, tr.finalize(use_kernel=False)), iters=2)
     acc_jnp = tr.evaluate(tr.finalize(use_kernel=False))
-    acc_kernel = tr.evaluate(tr.finalize(use_kernel=True))
+    with pallas_interpreter_off_tpu():
+        acc_kernel = tr.evaluate(tr.finalize(use_kernel=True))
     rows: list[Row] = [
         ("step4_inversion_jnp", us_jnp, f"acc={acc_jnp:.3f}"),
         ("step4_inversion_pallas", us_jnp, f"acc={acc_kernel:.3f}"),

@@ -1,14 +1,19 @@
-"""Pallas kernel microbenchmarks (interpret mode on CPU — the us_per_call
-numbers are for regression tracking, not TPU projections; `derived` carries
-the workload size)."""
+"""Pallas kernel microbenchmarks (the Pallas interpreter off the TPU — the
+us_per_call numbers there are for regression tracking, not TPU
+projections; `derived` carries the workload size)."""
 from __future__ import annotations
 
 import jax
 
-from benchmarks.common import Row, time_fn
+from benchmarks.common import Row, pallas_interpreter_off_tpu, time_fn
 
 
 def run(fast: bool = False):
+    with pallas_interpreter_off_tpu():
+        return _run()
+
+
+def _run():
     rows: list[Row] = []
     key = jax.random.PRNGKey(0)
 

@@ -12,6 +12,8 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -20,6 +22,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated bench module suffixes")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from benchmarks import (bench_cv, bench_fl_frameworks, bench_inversion,
                             bench_kernels, bench_roofline)

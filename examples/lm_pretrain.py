@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.checkpoint import io as ckpt
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import build_model
 from repro.runtime.steps import default_optimizer, make_train_step
 
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -73,6 +73,7 @@ from repro.core.baselines import (EcoFLTrainer, FedAvgTrainer, FedORATrainer,
 from repro.core.cost import SystemParams
 from repro.core.splitme import SplitMeTrainer
 from repro.data import oran
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -132,6 +133,7 @@ def main():
                     help="population mode: clients sampled per round "
                          "(default 32)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.population is not None and args.seeds <= 1:
         ap.error("--population needs the scanned campaign runner "
                  "(--seeds N with N > 1)")

@@ -13,6 +13,7 @@ from repro.configs.splitme_dnn import DNN10
 from repro.core.cost import SystemParams
 from repro.core.splitme import SplitMeTrainer
 from repro.data import oran
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -20,6 +21,7 @@ def main():
     ap.add_argument("--rounds", type=int, default=10,
                     help="global rounds to train (default 10)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     X, y = oran.generate(n_per_class=1000, seed=0)
     (Xtr, ytr), (Xte, yte) = oran.train_test_split(X, y)

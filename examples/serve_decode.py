@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import build_model
 from repro.runtime.steps import make_serve_step
 
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--full", action="store_true",
                     help="full config instead of the reduced variant")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

@@ -2,21 +2,24 @@
 
     PYTHONPATH=src python scripts/crash_resume_check.py
 
-The parent process
+The parent process never imports JAX: a device (a TPU chip) belongs to one
+process at a time, so every campaign runs in a child of its own, and each
+child exits before the next one starts.
 
-1. runs the UNINTERRUPTED reference campaign in-process,
-2. launches the same campaign as a ``--victim`` subprocess with
-   ``checkpoint_every`` armed (the victim sleeps briefly after each
-   committed checkpoint so the kill window is wide),
-3. waits for the first committed checkpoint manifest to appear, then
-   SIGKILLs the victim — a real, unhandled kill mid-campaign,
-4. resumes via ``resilience.resume_campaign`` in-process and asserts the
-   final params, losses and per-round metrics are BYTE-IDENTICAL to the
+1. ``--role reference`` runs the UNINTERRUPTED campaign and saves its
+   final params, losses and per-round metrics,
+2. ``--role victim`` runs the same campaign with ``checkpoint_every``
+   armed (it marks each committed checkpoint and sleeps briefly after it,
+   so the kill window is wide); the parent SIGKILLs it once the first
+   mark appears — a real, unhandled kill mid-campaign,
+3. ``--role resume`` resumes via ``resilience.resume_campaign`` and saves
+   the same results,
+4. the parent asserts the resumed results are BYTE-IDENTICAL to the
    uninterrupted reference.
 
-Exit code 0 on success; any mismatch or timeout is a hard failure.  The
-victim mode (``--victim DIR``) is this same file re-entered under
-``subprocess`` so both halves share one campaign definition.
+Exit code 0 on success; any mismatch or timeout is a hard failure.  All
+roles are this same file re-entered under ``subprocess`` so they share one
+campaign definition.
 """
 from __future__ import annotations
 
@@ -35,6 +38,7 @@ CHECKPOINT_EVERY = 4
 SEEDS = (0, 1)
 FRAMEWORK = "fedavg"
 SCENARIO = "faults:0.2"          # crash-resume under fault injection too
+TIMEOUT_S = 300.0
 
 
 def _setup():
@@ -54,44 +58,92 @@ def _setup():
     return cfg, sp, clients, kw
 
 
-def run_victim(ckpt_dir: str) -> None:
-    """The process that gets SIGKILLed: a checkpointed campaign that naps
-    after each committed save so the parent's kill always lands mid-run."""
-    from repro.launch import campaign
+def _save(res, out: str) -> None:
+    import jax
+    leaves = {f"param_{i}": np.asarray(l)
+              for i, l in enumerate(jax.tree.leaves(res.params))}
+    np.savez(out, losses=res.losses,
+             metrics=np.array([repr(m) for m in res.metrics]),
+             skipped_rounds=res.skipped_rounds,
+             crashed_rounds=res.crashed_rounds, **leaves)
 
+
+def run_role(role: str, ckpt_dir: str, out: str) -> None:
+    from repro.launch import campaign, resilience
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     cfg, sp, clients, kw = _setup()
-    campaign.run_campaign(FRAMEWORK, cfg, sp, clients,
-                          checkpoint_every=CHECKPOINT_EVERY,
-                          checkpoint_dir=ckpt_dir,
-                          _checkpoint_hook=lambda r: time.sleep(0.5), **kw)
+    if role == "reference":
+        _save(campaign.run_campaign(FRAMEWORK, cfg, sp, clients, **kw), out)
+    elif role == "victim":
+        def committed(r):
+            with open(out, "a") as f:        # the parent's kill signal
+                f.write(f"{r}\n")
+            time.sleep(0.5)
+        campaign.run_campaign(FRAMEWORK, cfg, sp, clients,
+                              checkpoint_every=CHECKPOINT_EVERY,
+                              checkpoint_dir=ckpt_dir,
+                              _checkpoint_hook=committed, **kw)
+    else:
+        _save(resilience.resume_campaign(
+            FRAMEWORK, cfg, sp, clients, checkpoint_dir=ckpt_dir,
+            checkpoint_every=CHECKPOINT_EVERY, **kw), out)
+
+
+def _child(role: str, ckpt_dir: str, out: str) -> subprocess.Popen:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in ("src", env.get("PYTHONPATH", "")) if p)
+    return subprocess.Popen(
+        [sys.executable, os.path.abspath(__file__), "--role", role,
+         "--ckpt-dir", ckpt_dir, "--out", out], env=env)
+
+
+def _finish(proc: subprocess.Popen, role: str) -> bool:
+    try:
+        rc = proc.wait(timeout=TIMEOUT_S)
+    except subprocess.TimeoutExpired:
+        proc.kill()
+        proc.wait()
+        print(f"[crash-resume] FAIL: {role} did not finish in {TIMEOUT_S}s")
+        return False
+    if rc != 0:
+        print(f"[crash-resume] FAIL: {role} exited with {rc}")
+    return rc == 0
 
 
 def main() -> int:
-    import jax
-    from repro.launch import campaign, resilience
+    with tempfile.TemporaryDirectory(prefix="crash_resume_") as tmp:
+        ckpt_dir = os.path.join(tmp, "ckpt")
+        ref_out = os.path.join(tmp, "reference.npz")
+        res_out = os.path.join(tmp, "resumed.npz")
+        marks = os.path.join(tmp, "committed.txt")
 
-    cfg, sp, clients, kw = _setup()
-
-    print("[crash-resume] reference (uninterrupted) campaign ...")
-    ref = campaign.run_campaign(FRAMEWORK, cfg, sp, clients, **kw)
-
-    with tempfile.TemporaryDirectory(prefix="crash_resume_") as ckpt_dir:
-        print("[crash-resume] launching victim subprocess ...")
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.pathsep.join(
-            p for p in ("src", env.get("PYTHONPATH", "")) if p)
-        victim = subprocess.Popen(
-            [sys.executable, os.path.abspath(__file__), "--victim", ckpt_dir],
-            env=env)
-        found = resilience.wait_for_checkpoint(ckpt_dir, timeout=300.0)
-        if found is None:
-            victim.kill()
-            print("[crash-resume] FAIL: no checkpoint appeared in 300s")
+        print("[crash-resume] reference (uninterrupted) campaign ...")
+        if not _finish(_child("reference", ckpt_dir, ref_out), "reference"):
             return 1
+
+        print("[crash-resume] launching victim subprocess ...")
+        victim = _child("victim", ckpt_dir, marks)
+        deadline = time.monotonic() + TIMEOUT_S
+        while not os.path.exists(marks) and victim.poll() is None:
+            if time.monotonic() > deadline:
+                victim.kill()
+                victim.wait()
+                print(f"[crash-resume] FAIL: no checkpoint appeared in "
+                      f"{TIMEOUT_S}s")
+                return 1
+            time.sleep(0.05)
         if victim.poll() is None:
             victim.send_signal(signal.SIGKILL)
             victim.wait()
-            print(f"[crash-resume] SIGKILLed victim after {found.name}")
+            print("[crash-resume] SIGKILLed victim after its first "
+                  "committed checkpoint")
+        elif victim.returncode != 0:
+            print(f"[crash-resume] FAIL: victim exited with "
+                  f"{victim.returncode} before any checkpoint")
+            return 1
         else:
             # lost the race — the campaign is tiny; resume is then a
             # restore-only pass, which the comparison still validates
@@ -99,37 +151,35 @@ def main() -> int:
                   "resume degenerates to restore-only")
 
         print("[crash-resume] resuming ...")
-        res = resilience.resume_campaign(
-            FRAMEWORK, cfg, sp, clients, checkpoint_dir=ckpt_dir,
-            checkpoint_every=CHECKPOINT_EVERY, **kw)
+        if not _finish(_child("resume", ckpt_dir, res_out), "resume"):
+            return 1
 
-    ok = True
-    for g, w in zip(jax.tree.leaves(res.params), jax.tree.leaves(ref.params)):
-        if not np.array_equal(np.asarray(g), np.asarray(w)):
-            ok = False
-    if not np.array_equal(res.losses, ref.losses, equal_nan=True):
-        ok = False
-    for mr, mf in zip(res.metrics, ref.metrics):
-        if repr(mr) != repr(mf):
-            ok = False
-    if res.skipped_rounds != ref.skipped_rounds:
-        ok = False
+        with np.load(ref_out) as ref, np.load(res_out) as res:
+            if sorted(ref.files) != sorted(res.files):
+                ok = False
+            else:
+                ok = all(np.array_equal(ref[k], res[k], equal_nan=k == "losses")
+                         for k in ref.files)
+            skipped, crashed = int(res["skipped_rounds"]), int(
+                res["crashed_rounds"])
     if not ok:
         print("[crash-resume] FAIL: resumed campaign diverged from the "
               "uninterrupted reference")
         return 1
     print(f"[crash-resume] OK: resumed == uninterrupted "
           f"(byte-identical params/losses/metrics; "
-          f"skipped_rounds={res.skipped_rounds}, "
-          f"crashed_rounds={res.crashed_rounds})")
+          f"skipped_rounds={skipped}, crashed_rounds={crashed})")
     return 0
 
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
-    ap.add_argument("--victim", metavar="CKPT_DIR", default=None)
+    ap.add_argument("--role", choices=("reference", "victim", "resume"),
+                    default=None)
+    ap.add_argument("--ckpt-dir", default=None)
+    ap.add_argument("--out", default=None)
     ns = ap.parse_args()
-    if ns.victim:
-        run_victim(ns.victim)
+    if ns.role:
+        run_role(ns.role, ns.ckpt_dir, ns.out)
         sys.exit(0)
     sys.exit(main())

@@ -19,6 +19,7 @@ path, and moved to ``repro.launch.fl_dryrun``.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -79,7 +80,6 @@ def make_distributed_inversion(cfg: DNNConfig, mesh: Mesh,
         return invert_inverse_model(w_s_inv, flat_s, flat_y, cfg,
                                     gamma=gamma, axis_name=axes)
 
-    from jax.experimental.shard_map import shard_map
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(), P(axes), P(axes)),
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(), P(axes), P(axes)),
+                         out_specs=P(), check_vma=False)

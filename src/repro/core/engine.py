@@ -629,7 +629,6 @@ def build_sharded_round_fn(spec: FrameworkSpec, cfg: DNNConfig, mesh, *,
     mixed-precision policy each device's client-data slab is cast to the
     compute dtype before the shard_map so the cast is sharded too.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     pol = _bound_policy(spec, policy)
@@ -680,8 +679,8 @@ def build_sharded_round_fn(spec: FrameworkSpec, cfg: DNNConfig, mesh, *,
         in_specs = in_specs + (c_spec,)       # faults dict (per-client)
     if guards is not None:
         out_specs = out_specs + (P(),)        # flags (replicated scalars)
-    sharded = shard_map(local_round, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    sharded = jax.shard_map(local_round, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
 
     ones_faults = {"poison": jnp.zeros((M,), jnp.float32),
                    "wire_gain": jnp.ones((M,), jnp.float32)}
@@ -1119,12 +1118,15 @@ def make_spec(name: str, cfg: DNNConfig, *,
 def build_eval_fn(spec: FrameworkSpec, cfg: DNNConfig, x_test, y_test, *,
                   client_data: Optional[Dict[str, Any]] = None,
                   gamma: float = 1e-3, jit: bool = True,
-                  policy: Optional[KernelPolicy] = None):
+                  policy: Optional[KernelPolicy] = None,
+                  axis_name=None):
     """Build ``accuracy(params_tuple) -> scalar`` for `spec`.
 
     Full-model frameworks evaluate the aggregated MLP directly.  SplitMe
     first recovers the server model via the one-shot analytic inversion
-    (Step 4), which needs `client_data` for the Gram sums.  The function is
+    (Step 4), which needs `client_data` for the Gram sums; inside a
+    ``shard_map`` whose clients are sharded over ``axis_name``, the Gram
+    sums are psum'd over it (``invert_inverse_model``).  The function is
     pure (jit/vmap/cond-safe), so trainers call it jitted, the campaign
     runner vmaps it over the seed axis, and the scanned campaign embeds it
     behind a per-round ``do_eval`` mask without leaving the device.
@@ -1132,7 +1134,8 @@ def build_eval_fn(spec: FrameworkSpec, cfg: DNNConfig, x_test, y_test, *,
     The kernel/precision policy rides on the spec (``policy`` overrides):
     forwards run in the compute dtype and the Step-4 Gram products dispatch
     to the ridge_gram kernel per the policy; the Gram accumulation, ridge
-    solve and the accuracy reduction itself stay pinned f32.
+    solve, the recovered server model's forward (f32 at HIGHEST matmul
+    precision) and the accuracy reduction itself stay pinned f32.
     """
     pol = _spec_policy(spec, policy)
     prec = pol.precision
@@ -1153,8 +1156,13 @@ def build_eval_fn(spec: FrameworkSpec, cfg: DNNConfig, x_test, y_test, *,
             )(x)
             w_s = invert_inverse_model(
                 w_s_inv, smashed.reshape(-1, smashed.shape[-1]), flat_y, cfg,
-                gamma=gamma, policy=pol)
-            logits = dnn.full_forward(w_c, w_s, x_test, cfg, precision=prec)
+                gamma=gamma, axis_name=axis_name, policy=pol)
+            h = dnn.client_forward(w_c, x_test, cfg, precision=prec)
+            # the ridge-recovered server model is ill-conditioned: apply it
+            # in f32 at HIGHEST, as the inversion fitted it.  A TPU's
+            # default f32 dot (one bf16 pass) takes accuracy to chance.
+            with jax.default_matmul_precision("highest"):
+                logits = dnn.server_forward(w_s, h.astype(jnp.float32), cfg)
             return jnp.mean((jnp.argmax(logits, -1) == y_test)
                             .astype(jnp.float32))
     else:

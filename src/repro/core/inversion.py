@@ -65,7 +65,10 @@ def invert_inverse_model(inverse_params: List[dict],
     policy: kernel dispatch policy for the Gram products (None → auto by
       backend); ``use_kernel`` (legacy) force-overrides its gram bit.
     The ridge solve itself always runs f32 — the Grams accumulate f32 even
-    when the smashed activations arrive in the policy's compute dtype.
+    when the smashed activations arrive in the policy's compute dtype, and
+    both the reference Gram and the layer-to-layer ``o @ w`` run at HIGHEST
+    precision (a TPU's default f32 dot is one bf16 pass, which the
+    ill-conditioned solve would amplify).
     """
     pol = dispatch.get_policy(policy)
     if use_kernel is not None:
@@ -93,7 +96,7 @@ def invert_inverse_model(inverse_params: List[dict],
         w_aug = jnp.linalg.solve(a0 + gamma * jnp.eye(d, dtype=a0.dtype), a1)
         w, b = w_aug[:-1], w_aug[-1]
         server_params.append({"w": w, "b": b})
-        o = o @ w + b
+        o = jnp.matmul(o, w, precision=jax.lax.Precision.HIGHEST) + b
         if l < len(targets) - 1:
             o = act(o)
     return server_params

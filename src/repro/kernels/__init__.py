@@ -20,11 +20,11 @@ through ``repro.kernels.dispatch``:
 * ``KernelPolicy`` holds per-op on/off bits (``None`` = auto), kernel
   block sizes, and a ``Precision`` (compute/accum dtypes).
 * Auto dispatch rule: Pallas kernels on TPU; reference jnp on every other
-  backend, where kernels could only run in the (slow, Python-traced)
-  interpret mode.  Set ``REPRO_PALLAS_INTERPRET=1`` to force the kernel
-  bodies through the interpreter on CPU — that is how the parity suite
-  (``pytest -m kernels``, the ``scripts/ci.sh`` kernel stage) validates
-  them bit-for-bit without a TPU.
+  backend.  Interpret mode is never chosen by backend: only
+  ``REPRO_PALLAS_INTERPRET=1`` runs the kernel bodies through the Pallas
+  interpreter (and turns the auto bits on off-TPU) — that is how the
+  parity suite (``pytest -m kernels``, the ``scripts/ci.sh`` kernel stage)
+  validates them bit-for-bit without a TPU.
 * Presets: ``"reference"`` (force kernels off, f32 — the escape hatch
   that reproduces pre-kernel numerics exactly), ``"kernel"`` (auto, f32),
   ``"kernel_bf16"`` (auto + bf16 activations / f32 accumulators and

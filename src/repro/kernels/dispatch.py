@@ -6,12 +6,15 @@ called THROUGH this module (``kl_loss``, ``gram``), selected by a
 ``KernelPolicy``:
 
 * per-op on/off bits (``kl_mutual`` / ``ridge_gram``) — ``None`` means
-  *auto*: resolve by backend.  On TPU the Pallas kernels compile natively,
-  so auto enables them; on CPU they can only run in (slow, Python-traced)
-  interpret mode, so auto falls back to the reference path UNLESS
-  ``REPRO_PALLAS_INTERPRET=1`` is set, which forces the kernel bodies
-  through the Pallas interpreter for bit-level parity testing without a
-  TPU (``scripts/ci.sh`` kernel-parity stage, ``pytest -m kernels``),
+  *auto*: on TPU the Pallas kernels compile natively, so auto enables
+  them; elsewhere auto picks the reference path UNLESS
+  ``REPRO_PALLAS_INTERPRET=1`` is set.  That variable is also the ONLY
+  thing that runs a kernel through the Pallas interpreter
+  (``repro.kernels.common.use_interpret``): a kernel the policy turns on
+  compiles natively, or runs interpreted because the variable asked for
+  it — never because the backend is not a TPU.  The parity suite uses it
+  to check the kernel bodies without a TPU (``scripts/ci.sh``
+  kernel-parity stage, ``pytest -m kernels``),
 * block sizes forwarded to the kernels' BlockSpecs (``kl_block_rows``,
   ``gram_block_{m,n,k}``),
 * a ``Precision`` policy: ``compute`` dtype for activations / matmul
@@ -37,13 +40,13 @@ runs pick up the kernels with no caller changes.
 """
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass, replace
 from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import use_interpret
 from repro.kernels.kl_mutual import ops as _kl_ops
 from repro.kernels.kl_mutual import ref as _kl_ref
 from repro.kernels.ridge_gram import ops as _rg_ops
@@ -90,9 +93,7 @@ def kernels_supported() -> bool:
     other backend only when ``REPRO_PALLAS_INTERPRET=1`` opts into the
     Pallas interpreter (parity testing, not speed).  Read dynamically so
     tests can flip the env var without re-importing."""
-    if jax.default_backend() == "tpu":
-        return True
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "") == "1"
+    return jax.default_backend() == "tpu" or use_interpret()
 
 
 def mixed_precision_supported() -> bool:

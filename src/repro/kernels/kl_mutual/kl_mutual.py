@@ -3,11 +3,15 @@
 Computes the per-row D_KL(x ‖ y) = Σ p_y (log p_y − log p_x) with p = softmax
 of temperature-scaled logits, in ONE VMEM-resident pass per row block:
 both stable log-softmaxes (max + logsumexp) and the KL contraction are fused,
-so HBM traffic is exactly one read of each logits block + one (bq,)-vector
+so HBM traffic is exactly one read of each logits block + one (bq, 1) column
 write — versus 5 materialised intermediates on the unfused path.
 
 BlockSpec: rows tiled (bq, d) with the full feature dim resident in VMEM
-(split-layer widths here are ≤ a few thousand — trivially fits).
+(split-layer widths here are ≤ a few thousand — trivially fits).  The
+output is a 2-D ``(n, 1)`` column, not a 1-D ``(n,)`` vector: its blocks
+keep the row axis on sublanes (the layout the row reduction produces), and
+under ``vmap`` over clients/seeds the added leading axis stays out of the
+last two block dims, so the TPU tiling rule holds at every batching depth.
 """
 from __future__ import annotations
 
@@ -26,21 +30,22 @@ def _kl_kernel(x_ref, y_ref, o_ref, *, inv_temp: float):
     logp_x = x - jnp.log(jnp.sum(jnp.exp(x), axis=-1, keepdims=True))
     logp_y = y - jnp.log(jnp.sum(jnp.exp(y), axis=-1, keepdims=True))
     p_y = jnp.exp(logp_y)
-    o_ref[...] = jnp.sum(p_y * (logp_y - logp_x), axis=-1)
+    o_ref[...] = jnp.sum(p_y * (logp_y - logp_x), axis=-1, keepdims=True)
 
 
 def kl_rows_pallas(x_logits: jax.Array, y_logits: jax.Array, *,
                    temperature: float = 1.0, bq: int = 256,
                    interpret: bool = False) -> jax.Array:
-    """Per-row KL; (n, d) -> (n,).  n must be a multiple of bq (ops pads)."""
+    """Per-row KL; (n, d) -> (n,).  n must be a multiple of bq, and bq a
+    multiple of 16 (the bf16 sublane tile) unless bq == n (ops pads)."""
     n, d = x_logits.shape
-    grid = (n // bq,)
-    return pl.pallas_call(
+    rows = pl.pallas_call(
         functools.partial(_kl_kernel, inv_temp=1.0 / temperature),
-        grid=grid,
+        grid=(n // bq,),
         in_specs=[pl.BlockSpec((bq, d), lambda i: (i, 0)),
                   pl.BlockSpec((bq, d), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bq,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        out_specs=pl.BlockSpec((bq, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
         interpret=interpret,
     )(x_logits, y_logits)
+    return rows[:, 0]

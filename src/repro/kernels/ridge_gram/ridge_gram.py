@@ -27,6 +27,7 @@ def _gram_kernel(x_ref, y_ref, o_ref):
     o_ref[...] += jax.lax.dot_general(
         x_ref[...], y_ref[...],
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 

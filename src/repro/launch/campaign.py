@@ -71,6 +71,7 @@ the whole population as the cohort reproduces the materialized
 from __future__ import annotations
 
 import contextlib
+import functools
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -440,12 +441,8 @@ def run_campaign(framework: str, cfg: DNNConfig, sp: SystemParams,
                 gamma=eval_gamma, policy=spec.policy)
         return result
 
-    eval_fn = None
     do_eval = np.zeros(rounds, np.float32)
     if test_data is not None:
-        eval_fn = engine.build_eval_fn(
-            spec, cfg, *test_data, gamma=eval_gamma, jit=False,
-            client_data={"x": x, "y": y} if framework == "splitme" else None)
         if eval_every:
             do_eval[eval_every - 1::eval_every] = 1.0
         do_eval[rounds - 1] = 1.0
@@ -475,8 +472,8 @@ def run_campaign(framework: str, cfg: DNNConfig, sp: SystemParams,
              if strict_transfers else contextlib.nullcontext())
     with guard:
         params, buffers = _run_rounds_scan(
-            spec, cfg, sp, sched, x, y, seeds, do_eval, eval_fn, mesh,
-            guards=guards, ckpt=ckpt)
+            spec, cfg, sp, sched, _scan_data(x, y, test_data), seeds,
+            do_eval, eval_gamma, mesh, guards=guards, ckpt=ckpt)
     host = _host_fetch(buffers)            # THE per-campaign transfer
 
     live = host["live"] > 0
@@ -552,7 +549,47 @@ def _run_rounds_loop(spec, cfg, sp, sched, x, y, seeds):
     return losses, params
 
 
-def _run_rounds_scan(spec, cfg, sp, sched, x, y, seeds, do_eval, eval_fn,
+def _scan_data(x, y, test_data):
+    """Every array a campaign's compiled scan reads, as one pytree passed
+    to it as an ARGUMENT.  Closed over instead, a device array is embedded
+    in the program as a constant, which reads it back to the host first:
+    on an accelerator that is a device-to-host transfer per compile (and
+    ``strict_transfers`` refuses it)."""
+    data = {"x": x, "y": y}
+    if test_data is not None:
+        data["x_test"], data["y_test"] = map(jnp.asarray, test_data)
+    return data
+
+
+def _fused_eval(spec, cfg, data, gamma, mesh=None):
+    """The per-round eval over the scan's traced ``data`` (``_scan_data``);
+    None without a test set.  SplitMe's Step-4 Grams read ``x``/``y``;
+    with the clients sharded over ``mesh`` each shard builds its own Grams
+    and one psum per layer sums them (the paper's all-reduce).  That is
+    also the only place a Pallas Gram kernel can run on a multi-chip
+    program: Mosaic kernels are never partitioned automatically."""
+    if "x_test" not in data:
+        return None
+    if spec.name != "splitme" or mesh is None:
+        return engine.build_eval_fn(
+            spec, cfg, data["x_test"], data["y_test"], gamma=gamma,
+            jit=False, client_data=data if spec.name == "splitme" else None)
+    from jax.sharding import PartitionSpec as P
+    axes = engine.client_axes(mesh)
+
+    def local(params, x, y, x_test, y_test):
+        return engine.build_eval_fn(
+            spec, cfg, x_test, y_test, client_data={"x": x, "y": y},
+            gamma=gamma, jit=False, axis_name=axes)(params)
+
+    sharded = jax.shard_map(local, mesh=mesh,
+                            in_specs=(P(), P(axes), P(axes), P(), P()),
+                            out_specs=P(), check_vma=False)
+    return lambda params: sharded(params, data["x"], data["y"],
+                                  data["x_test"], data["y_test"])
+
+
+def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
                      mesh, guards=None, ckpt=None):
     """Scan all rounds on-device; returns (params, device metric buffers).
 
@@ -610,9 +647,7 @@ def _run_rounds_scan(spec, cfg, sp, sched, x, y, seeds, do_eval, eval_fn,
     n_ph = len(spec.phases)
     fns: Dict[Tuple[int, int, int], Any] = {}
 
-    def seg_exec(kb: int, eb: int, lb: int):
-        if (kb, eb, lb) in fns:
-            return fns[kb, eb, lb]
+    def round_caller(eb: int, x, y):
         if mesh is None:
             raw = engine.build_round_fn(spec, cfg, x, y, e_max=max(1, eb),
                                         jit=False, gather=True,
@@ -645,7 +680,20 @@ def _run_rounds_scan(spec, cfg, sp, sched, x, y, seeds, do_eval, eval_fn,
                     raw, in_axes=(0, None, None, None, None, 0, 0, None))(
                     params, x, y, xr["mask"], xr["e"], subs, qstate,
                     faults)
+        return call_round
 
+    def seg_exec(kb: int, eb: int, lb: int):
+        if (kb, eb, lb) in fns:
+            return fns[kb, eb, lb]
+        fns[kb, eb, lb] = jax.jit(functools.partial(seg, eb),
+                                  donate_argnums=(0, 1, 2))
+        return fns[kb, eb, lb]
+
+    def seg(eb, params, key_arr, qstate, xs, data):
+        # the round and eval close over the traced data, never over
+        # device arrays (see _scan_data)
+        call_round = round_caller(eb, data["x"], data["y"])
+        eval_fn = _fused_eval(spec, cfg, data, eval_gamma, mesh)
         nan_row = jnp.full((n_seeds,), jnp.nan, jnp.float32)
 
         def body(carry, xr):
@@ -681,11 +729,7 @@ def _run_rounds_scan(spec, cfg, sp, sched, x, y, seeds, do_eval, eval_fn,
                 ys["quorum"] = jnp.where(ran, flags["quorum"], 0.0)
             return (params, keys, qstate), ys
 
-        def seg(params, key_arr, qstate, xs):
-            return jax.lax.scan(body, (params, key_arr, qstate), xs)
-
-        fns[kb, eb, lb] = jax.jit(seg, donate_argnums=(0, 1, 2))
-        return fns[kb, eb, lb]
+        return jax.lax.scan(body, (params, key_arr, qstate), xs)
 
     init_keys = jnp.stack([jax.random.PRNGKey(s + spec.init_key_offset)
                            for s in seeds])
@@ -758,7 +802,7 @@ def _run_rounds_scan(spec, cfg, sp, sched, x, y, seeds, do_eval, eval_fn,
                 wg[:length] = w_arr[start:start + length]
                 xs["poison"], xs["wire"] = pz, wg
         (params, key_arr, qstate), ys = seg_exec(kb, eb, lb)(
-            params, key_arr, qstate, xs)
+            params, key_arr, qstate, xs, data)
         ys_all.append(ys)
         end = start + length
         if ckpt is not None and (end % ckpt["every"] == 0 or end == rounds):
@@ -972,16 +1016,8 @@ def run_population_campaign(framework: str, cfg: DNNConfig,
                              "strict_transfers: each segment save is an "
                              "explicit device→host pull")
 
-    eval_fn = None
     do_eval = np.zeros(rounds, np.float32)
     if test_data is not None:
-        client_data = None
-        if framework == "splitme":
-            client_data = {"x": jnp.asarray(xc_all[-1]),
-                           "y": jnp.asarray(yc_all[-1])}
-        eval_fn = engine.build_eval_fn(spec, cfg, *test_data,
-                                       gamma=eval_gamma, jit=False,
-                                       client_data=client_data)
         if eval_every:
             do_eval[eval_every - 1::eval_every] = 1.0
         do_eval[rounds - 1] = 1.0
@@ -1012,8 +1048,9 @@ def run_population_campaign(framework: str, cfg: DNNConfig,
              if strict_transfers else contextlib.nullcontext())
     with guard:
         params, buffers = _run_population_scan(
-            spec, cfg, sp, sched, xc_all, yc_all, seeds, do_eval, eval_fn,
-            guards=guards, ckpt=ckpt)
+            spec, cfg, sp, sched, xc_all, yc_all, seeds, do_eval,
+            _scan_data(jnp.asarray(xc_all[-1]), jnp.asarray(yc_all[-1]),
+                       test_data), eval_gamma, guards=guards, ckpt=ckpt)
     host = _host_fetch(buffers)            # THE per-campaign transfer
 
     live = host["live"] > 0
@@ -1037,8 +1074,8 @@ def run_population_campaign(framework: str, cfg: DNNConfig,
 
 
 def _run_population_scan(spec, cfg, sp, sched: PopulationSchedule, xc_all,
-                         yc_all, seeds, do_eval, eval_fn, guards=None,
-                         ckpt=None):
+                         yc_all, seeds, do_eval, data, eval_gamma,
+                         guards=None, ckpt=None):
     """Scan all rounds of a population campaign on-device.
 
     The structure mirrors ``_run_rounds_scan`` with one inversion: instead
@@ -1066,6 +1103,14 @@ def _run_population_scan(spec, cfg, sp, sched: PopulationSchedule, xc_all,
             return fns[eb, lb]
         raw = engine.build_cohort_round_fn(spec, cfg, e_max=max(1, eb),
                                            jit=False, guards=guards)
+        fns[eb, lb] = jax.jit(functools.partial(seg, raw),
+                              donate_argnums=(0, 1, 2))
+        return fns[eb, lb]
+
+    def seg(raw, params, key_arr, qstate, xs, data):
+        # the SplitMe eval's client data and the test set arrive traced
+        # (see _scan_data)
+        eval_fn = _fused_eval(spec, cfg, data, eval_gamma)
         nan_row = jnp.full((n_seeds,), jnp.nan, jnp.float32)
 
         def body(carry, xr):
@@ -1099,11 +1144,7 @@ def _run_population_scan(spec, cfg, sp, sched: PopulationSchedule, xc_all,
                 ys["quorum"] = jnp.where(live, flags["quorum"], 0.0)
             return (params, keys, qstate), ys
 
-        def seg(params, key_arr, qstate, xs):
-            return jax.lax.scan(body, (params, key_arr, qstate), xs)
-
-        fns[eb, lb] = jax.jit(seg, donate_argnums=(0, 1, 2))
-        return fns[eb, lb]
+        return jax.lax.scan(body, (params, key_arr, qstate), xs)
 
     init_keys = jnp.stack([jax.random.PRNGKey(s + spec.init_key_offset)
                            for s in seeds])
@@ -1144,7 +1185,7 @@ def _run_population_scan(spec, cfg, sp, sched: PopulationSchedule, xc_all,
         xs["xc"][:length] = xc_all[start:end]
         xs["yc"][:length] = yc_all[start:end]
         (params, key_arr, qstate), ys = seg_exec(eb, lb)(
-            params, key_arr, qstate, xs)
+            params, key_arr, qstate, xs, data)
         ys_all.append(ys)
         if ckpt is not None and (end % ckpt["every"] == 0 or end == rounds):
             from repro.launch import resilience
@@ -1248,19 +1289,17 @@ def run_config_sweep(framework: str, cfg: DNNConfig,
 
     spec = engine.make_spec(framework, cfg, masked_loss_metric=True,
                             policy=policy, quant=quant, **hyper)
-    raw = engine.build_round_fn(spec, cfg, x, y, e_max=e_max, jit=False,
-                                gather=False)
-    eval_fn = None
     do_eval = np.zeros(rounds, np.float32)
     if test_data is not None:
-        eval_fn = engine.build_eval_fn(
-            spec, cfg, *test_data, gamma=eval_gamma, jit=False,
-            client_data={"x": x, "y": y} if framework == "splitme" else None)
         if eval_every:
             do_eval[eval_every - 1::eval_every] = 1.0
         do_eval[rounds - 1] = 1.0
 
-    def sweep(init_keys, key_arr, xs):
+    def sweep(init_keys, key_arr, xs, data):
+        # round and eval over the traced data (see _scan_data)
+        raw = engine.build_round_fn(spec, cfg, data["x"], data["y"],
+                                    e_max=e_max, jit=False, gather=False)
+        eval_fn = _fused_eval(spec, cfg, data, eval_gamma)
         params_s = jax.vmap(spec.init_fn)(init_keys)          # (S, …)
         params = jax.tree.map(
             lambda p: jnp.broadcast_to(p[None], (V,) + p.shape), params_s)
@@ -1298,7 +1337,8 @@ def run_config_sweep(framework: str, cfg: DNNConfig,
         key0 = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
         xs = {"a": a_all.transpose(1, 0, 2), "e": e_all.T,
               "do_eval": do_eval}
-        params, ys = jax.jit(sweep)(init_keys, key0, xs)
+        params, ys = jax.jit(sweep)(init_keys, key0, xs,
+                                    _scan_data(x, y, test_data))
     host = _host_fetch(ys)                 # ONE transfer for the sweep
 
     results = []

@@ -122,13 +122,12 @@ def make_sfl_round(cfg: DNNConfig, mesh, *, n_clients: int,
         ws_agg = jax.tree.map(lambda a: jax.lax.psum(a * scale, axes), ws_new)
         return wc_agg, ws_agg
 
-    from jax.experimental.shard_map import shard_map
     spec_clients = P(axes)
     spec_rep = P()
-    return shard_map(local_round, mesh=mesh,
-                     in_specs=(spec_rep, spec_rep, spec_clients,
-                               spec_clients, spec_rep),
-                     out_specs=(spec_rep, spec_rep), check_rep=False)
+    return jax.shard_map(local_round, mesh=mesh,
+                         in_specs=(spec_rep, spec_rep, spec_clients,
+                                   spec_clients, spec_rep),
+                         out_specs=(spec_rep, spec_rep), check_vma=False)
 
 
 # ---------------------------------------------------------------------------

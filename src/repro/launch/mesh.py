@@ -9,16 +9,11 @@ jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist on
-    # newer JAX; Auto is the default there, so older JAX just omits it.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,8 +27,9 @@ def make_host_mesh():
     return _make_mesh((1, 1), ("data", "model"))
 
 
-def make_cpu_mesh(data: int):
-    """data×1 CPU mesh (multi-device parity tests under
+def make_client_mesh(data: int):
+    """data×1 mesh over the first ``data`` devices: clients shard over
+    ``data`` (the chips of a four-chip host, or N forced CPU devices under
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``)."""
     return _make_mesh((data, 1), ("data", "model"))
 

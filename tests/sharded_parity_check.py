@@ -17,12 +17,12 @@ ATOL = 1e-5
 def run_check(data_shards: int) -> None:
     from repro.configs.splitme_dnn import DNN10
     from repro.core import engine
-    from repro.launch.mesh import make_cpu_mesh
+    from repro.launch.mesh import make_client_mesh
 
     if jax.device_count() < data_shards:
         raise RuntimeError(f"need {data_shards} devices, "
                            f"have {jax.device_count()}")
-    mesh = make_cpu_mesh(data_shards)
+    mesh = make_client_mesh(data_shards)
     rng = np.random.default_rng(0)
     M, n, e_max, e_steps = 8, 16, 4, 3
     x = jnp.asarray(rng.normal(size=(M, n, DNN10.n_features)), jnp.float32)

@@ -4,6 +4,8 @@ The campaign runner batches independent seeds through one compiled
 scan-over-rounds; each seed's trajectory must match the serial engine
 trainer with the same seed (same schedule, same RNG chain).
 """
+import warnings
+
 import jax
 import numpy as np
 import pytest
@@ -134,6 +136,34 @@ def test_scanned_campaign_single_host_transfer(small_data, monkeypatch):
     campaign.run_campaign("oranfed", DNN10, SystemParams(M=12, seed=0), cd,
                           rounds=ROUNDS, seeds=(0, 1), E=5, scan=False)
     assert len(calls) == ROUNDS
+
+
+@pytest.mark.parametrize("runner", ["campaign", "sweep"])
+def test_scanned_campaign_captures_no_constants(small_data, runner):
+    """The client and test data reach the compiled scan as ARGUMENTS.  A
+    device array closed over instead is baked into the program as a
+    constant, read back to the host at lowering: on a TPU that transfer
+    breaks ``strict_transfers`` (the CPU, whose arrays are host memory,
+    never shows it), so JAX's own captured-constants warning stands guard."""
+    cd, test = small_data
+    prev = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", 1)
+    try:
+        with warnings.catch_warnings():
+            warnings.filterwarnings("error", message=".*constants were "
+                                    "captured")
+            if runner == "campaign":
+                campaign.run_campaign(
+                    "splitme", DNN10, SystemParams(M=12, seed=0), cd,
+                    rounds=ROUNDS, seeds=(0, 1), test_data=test,
+                    eval_every=2, strict_transfers=True)
+            else:
+                campaign.run_config_sweep(
+                    "splitme", DNN10, [SystemParams(M=12, seed=0)] * 2, cd,
+                    rounds=2, seeds=(0,), test_data=test,
+                    strict_transfers=True)
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", prev)
 
 
 def test_scanned_campaign_matches_python_loop(small_data):

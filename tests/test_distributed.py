@@ -4,7 +4,6 @@
 in ``repro.core.engine.build_sharded_round_fn``); the hand-written vanilla
 SFL boundary-exchange round moved to ``repro.launch.fl_dryrun`` (dry-run
 collective accounting only)."""
-# (mesh construction feature-detects jax.sharding.AxisType; see launch/mesh)
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -283,6 +283,8 @@ def test_sharded_round_matches_engine_multidevice():
 
     root = Path(__file__).resolve().parents[1]
     env = dict(os.environ)
+    # the child needs the CPU's forced devices, never the parent's chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = f"{root / 'src'}:{env.get('PYTHONPATH', '')}"
     out = subprocess.run(

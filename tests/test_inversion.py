@@ -60,6 +60,34 @@ def test_inversion_classifies_after_mutual_training():
     assert acc > 0.7, acc
 
 
+def test_splitme_eval_applies_recovered_server_at_highest():
+    """The Step-4 eval applies the ridge-recovered server model at HIGHEST
+    matmul precision.  Those weights are ill-conditioned: on a TPU v5e,
+    whose default f32 dot is one bf16 pass, the default-precision forward
+    took the full-width DNN10 campaign's accuracy from ~0.81 (as on the
+    CPU) to 0.29-0.35, chance for 3 classes.  The CPU computes f32 either
+    way, so the traced program is checked: the eval's last dots are the
+    server layers on the test set, and each must carry HIGHEST."""
+    import re
+
+    from repro.configs.splitme_dnn import DNN10
+    from repro.core import engine
+
+    spec = engine.make_spec("splitme", DNN10)
+    rng = np.random.default_rng(0)
+    clients = {"x": rng.normal(size=(4, 8, 30)).astype(np.float32),
+               "y": rng.integers(0, 3, (4, 8))}
+    ev = engine.build_eval_fn(spec, DNN10,
+                              rng.normal(size=(10, 30)).astype(np.float32),
+                              rng.integers(0, 3, 10), client_data=clients)
+    text = str(jax.make_jaxpr(ev)(spec.init_fn(jax.random.PRNGKey(0))))
+    dots = [re.split(r"\n\s*\]", s, maxsplit=1)[0]
+            for s in text.split("dot_general[")[1:]]
+    n_server = len(dnn.server_dims(DNN10)) - 1
+    assert len(dots) > n_server
+    assert all("Precision.HIGHEST" in d for d in dots[-n_server:])
+
+
 def test_inversion_allreduce_equivalence():
     """Sum-of-client Grams == single-shot Gram on concatenated data (the
     all-reduce in eq. 9 is exact, not an approximation)."""

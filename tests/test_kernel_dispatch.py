@@ -1,10 +1,10 @@
 """Kernel-vs-reference parity for the dispatch layer (`repro.kernels.dispatch`)
 and the kernelized training stack built on it.
 
-Everything here runs the Pallas kernel BODIES through interpret mode on CPU
-(forced per-op via explicit ``KernelPolicy`` bits, or via
-``REPRO_PALLAS_INTERPRET=1`` for the auto-resolution test), so the suite
-stays green without a TPU.  ``scripts/ci.sh`` runs this module as its
+Everything here runs the Pallas kernel BODIES through the Pallas
+interpreter: the module opts into it with ``REPRO_PALLAS_INTERPRET=1``
+(kernels are forced on per-op via explicit ``KernelPolicy`` bits), so the
+suite stays green without a TPU.  ``scripts/ci.sh`` runs this module as its
 kernel-parity stage: ``REPRO_PALLAS_INTERPRET=1 pytest -m kernels``.
 """
 import jax
@@ -19,6 +19,14 @@ from repro.kernels import dispatch
 from repro.kernels.dispatch import BF16, KernelPolicy
 
 pytestmark = pytest.mark.kernels
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _pallas_interpreter():
+    """Kernels never pick the interpreter by backend; this module asks."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("REPRO_PALLAS_INTERPRET", "1")
+        yield
 
 KERNEL_ON = KernelPolicy(kl_mutual=True, ridge_gram=True)
 KERNEL_BF16_ON = KernelPolicy(kl_mutual=True, ridge_gram=True,
@@ -119,17 +127,16 @@ def test_gram_kernel_under_vmap():
 def test_gram_kernel_under_shard_map_psum():
     """Per-shard kernel Grams + psum == single-shot OᵀZ (the Step-4
     all-reduce is exact with the kernel in the shard body)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     o = jax.random.normal(jax.random.PRNGKey(0), (128, 18))
     z = jax.random.normal(jax.random.PRNGKey(1), (128, 3))
-    f = shard_map(
+    f = jax.shard_map(
         lambda a, b: jax.lax.psum(
             dispatch.gram(a, b, policy=KERNEL_ON), "data"),
         mesh=mesh, in_specs=(P("data"), P("data")), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     np.testing.assert_allclose(jax.jit(f)(o, z), o.T @ z,
                                rtol=1e-5, atol=1e-4)
 
@@ -137,8 +144,15 @@ def test_gram_kernel_under_shard_map_psum():
 def test_inversion_kernel_matches_reference_incl_shard_map():
     """invert_inverse_model with the gram kernel == reference, plain and
     under the 1-device shard_map bundled-psum path (per-layer Gram psum
-    preserved with the kernel in the body)."""
-    from jax.experimental.shard_map import shard_map
+    preserved with the kernel in the body).
+
+    Compared is what the inversion determines: the recovered server
+    model's layer outputs on the smashed data.  Its weights are not
+    determined that tightly: layer 2's ridge Gram here has condition
+    number ~3.5e5 at gamma=1e-3, so the ~2e-7 relative difference between
+    the kernel's blocked accumulation and one reference matmul moves its
+    weights by ~2.5e-2 along directions the data barely excites, while
+    every layer output stays within ~2e-6."""
     from jax.sharding import Mesh, PartitionSpec as P
 
     cfg = DNNConfig(n_features=6, hidden=(12, 8), split_index=1, n_classes=3)
@@ -147,19 +161,21 @@ def test_inversion_kernel_matches_reference_incl_shard_map():
     y1 = jax.nn.one_hot(
         jax.random.randint(jax.random.PRNGKey(2), (120,), 0, 3), 3)
 
-    w_ref = invert_inverse_model(inv, o, y1, cfg, policy="reference")
-    w_ker = invert_inverse_model(inv, o, y1, cfg, policy=KERNEL_ON)
-    for a, b in zip(jax.tree.leaves(w_ref), jax.tree.leaves(w_ker)):
+    def outputs(server):
+        return dnn.mlp_activations(server, o, cfg.activation)
+
+    want = outputs(invert_inverse_model(inv, o, y1, cfg, policy="reference"))
+    got = outputs(invert_inverse_model(inv, o, y1, cfg, policy=KERNEL_ON))
+    for a, b in zip(want, got):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda w, s, y: invert_inverse_model(w, s, y, cfg, axis_name="data",
                                              policy=KERNEL_ON),
         mesh=mesh, in_specs=(P(), P("data"), P("data")), out_specs=P(),
-        check_rep=False)
-    w_sm = jax.jit(sharded)(inv, o, y1)
-    for a, b in zip(jax.tree.leaves(w_ref), jax.tree.leaves(w_sm)):
+        check_vma=False)
+    for a, b in zip(want, outputs(jax.jit(sharded)(inv, o, y1))):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 

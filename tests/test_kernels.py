@@ -1,9 +1,18 @@
 """Per-kernel allclose vs pure-jnp oracles, swept over shapes & dtypes
-(interpret=True executes the Pallas kernel bodies on CPU)."""
+(the module opts into the Pallas interpreter, which executes the kernel
+bodies on CPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _pallas_interpreter():
+    """Kernels never pick the interpreter by backend; this module asks."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("REPRO_PALLAS_INTERPRET", "1")
+        yield
 
 
 @pytest.mark.parametrize("n,d1,d2", [(64, 128, 128), (100, 257, 3),

@@ -159,8 +159,8 @@ def test_error_feedback_reduces_multiround_error(small_data):
 # ---------------------------------------------------------------------------
 
 def _one_device_mesh():
-    from repro.launch.mesh import make_cpu_mesh
-    return make_cpu_mesh(1)
+    from repro.launch.mesh import make_client_mesh
+    return make_client_mesh(1)
 
 
 @pytest.mark.parametrize("quant", ["bf16", "int8"])
