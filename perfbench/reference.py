@@ -1,0 +1,436 @@
+"""Plain float32 reference of the campaigns the benchmark times.
+
+Written from the paper (arXiv 2508.02534: Alg. 1, problem P2, eq. 5 and
+eq. 8-9, Table III) and the semantics the program documents, with no import
+from the program.  Given the same fleet, client data and training seeds it
+computes what one ``run_campaign`` call returns: the per-round schedule
+(selected set A_t, local updates E_t), each round's phase losses, the
+final test accuracy, and the final parameters of every seed.
+
+Semantics (shared with the program, by its documented contract):
+
+* initialization: ``PRNGKey(seed + offset)`` (offset 0 for SplitMe, whose
+  key splits into the client and inverse-server stacks; 1 for FedAvg), He
+  normal weights, zero biases;
+* round t of seed s: the round key comes off ``PRNGKey(seed)`` by one split
+  per round; client m of phase p takes key ``p * M + m`` of its
+  ``2M``-way (``M``-way for FedAvg) split; each local step splits that key
+  and draws a batch of ``batch_size`` row indices with replacement;
+* SplitMe round (eq. 5): the client phase trains c(.) towards the fixed
+  targets s^-1(Y_m) of the round-start inverse model, then the server phase
+  trains s^-1(.) towards c(X_m) of that client's updated weights; both
+  losses are the temperature-softmax D_KL(x || y), y the target; the
+  masked FedAvg average over A_t aggregates both stacks;
+* FedAvg round: E local SGD steps of cross-entropy on K random clients;
+* a round's phase loss is the mean over A_t of each client's mean loss
+  over its E executed steps;
+* eval: FedAvg's test accuracy of the aggregated model; SplitMe's Step 4
+  (eq. 8-9) ridge-inverts s^-1 layer by layer over every client's data,
+  then classifies the test set through c(.) and the recovered s(.).
+
+Every matmul runs in float32 at HIGHEST precision (``run_campaign`` sets
+it).  ``compute_dtype`` casts the inputs of the model's matmuls to a
+narrower type with float32 accumulation: the control the comparison must
+fail.
+"""
+from __future__ import annotations
+
+import functools
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+# ---------------------------------------------------------------------------
+# The fleet (Table III) and the host-side schedule (Alg. 1, P2, fixed K)
+# ---------------------------------------------------------------------------
+
+B = 1e9            # total uplink bandwidth (bit/s)
+P_C = 1.0          # per-unit communication cost
+P_TR = 1.0         # per-unit computation cost
+B_MIN = 1.0 / 50   # minimum bandwidth share
+RHO = 0.8          # Pareto trade-off of eq. 20
+ALPHA = 0.7        # Alg. 1 heuristic factor
+EPS = 0.1          # target accuracy level of K_eps
+E_MAX = 20         # largest admissible number of local updates
+GENERIC_S_M = 1e6  # bits of smashed data before the split is known
+GENERIC_OMEGA = 0.2
+GENERIC_D = 8e6    # bits of the whole model before it is known
+
+
+def fleet(M: int, seed: int) -> Dict[str, np.ndarray]:
+    """Per-RIC compute times per local update and slice deadlines:
+    Q_C ~ U(0.34, 0.46) ms, Q_S ~ U(1.2, 1.6) ms, t_round ~ U(50, 100) ms."""
+    rng = np.random.default_rng(seed)
+    return {"Q_C": rng.uniform(0.34e-3, 0.46e-3, M),
+            "Q_S": rng.uniform(1.2e-3, 1.6e-3, M),
+            "t_round": rng.uniform(50e-3, 100e-3, M)}
+
+
+def param_count(dims) -> int:
+    return sum(dims[i] * dims[i + 1] + dims[i + 1]
+               for i in range(len(dims) - 1))
+
+
+def _uplink(a, b, size):
+    """eq. 19 with unit channel gain."""
+    t = size / np.maximum(b * B, 1e-12)
+    return np.where(a > 0, t, 0.0)
+
+
+def _bandwidth(a, E, fl, size):
+    """Exact min-max bandwidth split of P2 for a fixed E: the shares that
+    equalize E*Q_C + T_co over the selected set, found by bisection on the
+    common finish time, then the b_min box by clip and renormalise."""
+    sel = np.where(a > 0)[0]
+    b = np.zeros(len(a))
+    if len(sel) == 0:
+        return b
+    s = size[sel]
+    offs = E * fl["Q_C"][sel]
+
+    def excess(tau):
+        return float(np.sum(s / (B * np.maximum(tau - offs, 1e-12))) - 1.0)
+
+    lo = float(np.max(offs)) + 1e-9
+    hi = lo + float(np.sum(s)) / B + 1.0
+    while excess(hi) > 0:
+        hi *= 2.0
+    for _ in range(200):
+        mid = 0.5 * (lo + hi)
+        if excess(mid) > 0:
+            lo = mid
+        else:
+            hi = mid
+    bs = s / (B * np.maximum(hi - offs, 1e-12))
+    for _ in range(len(sel)):
+        low = bs < B_MIN
+        if not low.any():
+            break
+        fixed = np.sum(np.where(low, B_MIN, 0.0))
+        free = ~low
+        if fixed >= 1.0 or not free.any():
+            bs = np.full(len(sel), 1.0 / len(sel))
+            break
+        bs = np.where(low, B_MIN, bs * (1.0 - fixed) / np.sum(bs[free]))
+    b[sel] = bs / bs.sum()
+    return b
+
+
+def _objective(a, b, E, fl, size):
+    """eq. 22: K_eps(E) * cost, cost of eq. 20 (eq. 16-18)."""
+    k_eps = (E + 1) ** 2 / (E ** 2 * EPS ** 2)
+    r_co = float(np.sum(a * b) * B * P_C)
+    r_cp = float(np.sum(a * E * (fl["Q_C"] + fl["Q_S"])) * P_TR)
+    t_co = _uplink(a, b, size)
+    t1 = np.max(np.where(a > 0, E * fl["Q_C"] + t_co, -np.inf))
+    t2 = np.max(np.where(a > 0, E * fl["Q_S"], -np.inf))
+    latency = float(t1 + t2) if a.sum() else 0.0
+    return k_eps * (RHO * (r_co / B + r_cp) + (1 - RHO) * latency)
+
+
+def plan_splitme(M: int, fleet_seed: int, rounds: int, model: dict,
+                 n_per_client: int, e_initial: int):
+    """Alg. 1 selection plus P2's bandwidth and adaptive E (E never
+    increases), round by round.  Returns a (R, M) and E (R,)."""
+    fl = fleet(M, fleet_seed)
+    # Alg. 1's pessimistic first estimate, from the generic payload
+    t0 = float(np.max(M * (GENERIC_S_M + GENERIC_OMEGA * GENERIC_D) / B))
+    dims = (model["n_features"], *model["hidden"], model["n_classes"])
+    split = model["split_index"]
+    pc_c = param_count(dims[:split + 1])
+    pc_i = param_count(tuple(reversed(dims[split:])))
+    d_bits = 32.0 * (pc_c + pc_i)
+    omega = pc_c / (pc_c + pc_i)
+    size = np.full(M, n_per_client * dims[split] * 32.0) + omega * d_bits
+    t_k = t_km1 = t0
+    E = e_initial
+    a_l, e_l = [], []
+    for _ in range(rounds):
+        t_est = ALPHA * t_k + (1 - ALPHA) * t_km1
+        a = (E * (fl["Q_C"] + fl["Q_S"]) + t_est
+             <= fl["t_round"]).astype(np.float64)
+        if a.sum() == 0:
+            a[np.argmin(E * (fl["Q_C"] + fl["Q_S"]) - fl["t_round"])] = 1.0
+        best = None
+        for e in range(1, E_MAX + 1):
+            b = _bandwidth(a, e, fl, size)
+            val = _objective(a, b, e, fl, size)
+            if best is None or val < best[2]:
+                best = (b, e, val)
+        b, e_hat, _ = best
+        if e_hat > E:
+            e_hat = E
+            b = _bandwidth(a, e_hat, fl, size)
+        E = e_hat
+        realized = float(np.max(_uplink(a, b, size))) if a.sum() else t_k
+        t_k, t_km1 = ALPHA * t_k + (1 - ALPHA) * realized, t_k
+        a_l.append(a)
+        e_l.append(E)
+    return np.stack(a_l), np.asarray(e_l, np.int32)
+
+
+def plan_fixed_k(M: int, rounds: int, K: int, E: int, policy_seed: int):
+    """FedAvg: K clients drawn uniformly without replacement each round."""
+    rng = np.random.default_rng(policy_seed)
+    a = np.zeros((rounds, M))
+    for t in range(rounds):
+        a[t, rng.choice(M, K, replace=False)] = 1.0
+    return a, np.full(rounds, E, np.int32)
+
+
+# ---------------------------------------------------------------------------
+# The model: an MLP split after layer `split_index`
+# ---------------------------------------------------------------------------
+
+def init_mlp(key, dims):
+    layers = []
+    for i, k in enumerate(jax.random.split(key, len(dims) - 1)):
+        w = jax.random.normal(k, (dims[i], dims[i + 1]), jnp.float32)
+        layers.append({"w": w * jnp.sqrt(2.0 / dims[i]),
+                       "b": jnp.zeros((dims[i + 1],), jnp.float32)})
+    return layers
+
+
+def mlp(layers, x, final_relu: bool, dt=None):
+    """Dense layers with ReLU between them (and after the last one when
+    ``final_relu``).  ``dt`` narrows the matmul inputs, f32 accumulate."""
+    for i, p in enumerate(layers):
+        if dt is None:
+            x = x @ p["w"] + p["b"]
+        else:
+            x = jnp.dot(x.astype(dt), p["w"].astype(dt),
+                        preferred_element_type=jnp.float32) + p["b"]
+        if i < len(layers) - 1 or final_relu:
+            x = jax.nn.relu(x)
+    return x
+
+
+def kl(x, y, tau):
+    """Mean over rows of D_KL(x || y) = sum p_y (log p_y - log p_x), y the
+    fixed target, both through a softmax at temperature tau."""
+    logp_x = jax.nn.log_softmax(x / tau, -1)
+    logp_y = jax.nn.log_softmax(jax.lax.stop_gradient(y) / tau, -1)
+    return jnp.mean(jnp.sum(jnp.exp(logp_y) * (logp_y - logp_x), -1))
+
+
+def cross_entropy(logits, y):
+    logp = jax.nn.log_softmax(logits, -1)
+    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
+
+
+def _local_sgd(w, data, target, key, E, loss_fn, lr, batch):
+    """E SGD steps on batches drawn with replacement; returns the weights
+    and the mean loss over the E steps."""
+    n = data.shape[0]
+
+    def step(i, carry):
+        w, k, total = carry
+        k, sk = jax.random.split(k)
+        idx = jax.random.randint(sk, (batch,), 0, n)
+        loss, g = jax.value_and_grad(loss_fn)(w, data[idx], target[idx])
+        w = jax.tree.map(lambda p, gp: p - lr * gp, w, g)
+        return w, k, total + loss
+
+    w, _, total = jax.lax.fori_loop(0, E, step, (w, key, jnp.float32(0.0)))
+    return w, total / E
+
+
+def _average(stacked, a):
+    """Masked FedAvg over the client axis."""
+    wsum = jnp.maximum(jnp.sum(a), 1.0)
+    return jax.tree.map(lambda p: jnp.tensordot(a, p, axes=1) / wsum,
+                        stacked)
+
+
+def splitme_round(params, x, y1, a, E, key, hp, dt=None):
+    """One SplitMe round of one seed over all M clients (a masks A_t)."""
+    wc, wi = params
+    M = x.shape[0]
+    keys = jax.random.split(key, 2 * M).reshape(2, M, -1)
+    tau, batch = hp["temperature"], hp["batch_size"]
+
+    def client_loss(w, xb, tb):
+        return kl(mlp(w, xb, True, dt), tb, tau)
+
+    def server_loss(w, yb, tb):
+        return kl(mlp(w, yb, False, dt), tb, tau)
+
+    def per_client(xm, y1m, kc, ks):
+        tgt = mlp(wi, y1m, False, dt)                 # s^-1(Y_m), fixed
+        wc_m, lc = _local_sgd(wc, xm, tgt, kc, E, client_loss, hp["lr_c"],
+                              batch)
+        smashed = jax.lax.stop_gradient(mlp(wc_m, xm, True, dt))
+        wi_m, ls = _local_sgd(wi, y1m, smashed, ks, E, server_loss,
+                              hp["lr_s"], batch)
+        return wc_m, wi_m, lc, ls
+
+    wc_all, wi_all, lc, ls = jax.vmap(per_client)(x, y1, keys[0], keys[1])
+    wsum = jnp.maximum(jnp.sum(a), 1.0)
+    losses = jnp.stack([jnp.sum(a * lc), jnp.sum(a * ls)]) / wsum
+    return (_average(wc_all, a), _average(wi_all, a)), losses
+
+
+def fedavg_round(params, x, y, a, E, key, hp, dt=None):
+    """One FedAvg round of one seed over all M clients (a masks A_t)."""
+    (w,) = params
+    M = x.shape[0]
+    keys = jax.random.split(key, M)
+
+    def loss_fn(w, xb, yb):
+        return cross_entropy(mlp(w, xb, False, dt), yb)
+
+    w_all, l = jax.vmap(
+        lambda xm, ym, k: _local_sgd(w, xm, ym, k, E, loss_fn, hp["lr"],
+                                     hp["batch_size"]))(x, y, keys)
+    return (_average(w_all, a),), jnp.sum(a * l)[None] / jnp.maximum(
+        jnp.sum(a), 1.0)
+
+
+def ridge_invert(wi, smashed, y1, gamma):
+    """Step 4 (eq. 8-9): recover s(.) layer by layer from s^-1(.)."""
+    acts, h = [], y1
+    for i, p in enumerate(wi):
+        h = h @ p["w"] + p["b"]
+        if i < len(wi) - 1:
+            h = jax.nn.relu(h)
+        acts.append(h)
+    L = len(wi)
+    targets = [acts[L - 1 - l] for l in range(1, L)] + [y1]
+    server, o = [], smashed
+    for l, z in enumerate(targets):
+        o_aug = jnp.concatenate([o, jnp.ones((o.shape[0], 1), o.dtype)], -1)
+        a0 = o_aug.T @ o_aug
+        a1 = o_aug.T @ z
+        w_aug = jnp.linalg.solve(a0 + gamma * jnp.eye(a0.shape[0]), a1)
+        server.append({"w": w_aug[:-1], "b": w_aug[-1]})
+        o = o @ w_aug[:-1] + w_aug[-1]
+        if l < len(targets) - 1:
+            o = jax.nn.relu(o)
+    return server
+
+
+def splitme_accuracy(params, x, y1, x_test, y_test, gamma, dt=None):
+    wc, wi = params
+    smashed = mlp(wc, x.reshape(-1, x.shape[-1]), True, dt)
+    server = ridge_invert(wi, smashed, y1.reshape(-1, y1.shape[-1]), gamma)
+    logits = mlp(server, mlp(wc, x_test, True, dt), False)
+    return jnp.mean((jnp.argmax(logits, -1) == y_test).astype(jnp.float32))
+
+
+def fedavg_accuracy(params, x_test, y_test, dt=None):
+    logits = mlp(params[0], x_test, False, dt)
+    return jnp.mean((jnp.argmax(logits, -1) == y_test).astype(jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# A whole campaign
+# ---------------------------------------------------------------------------
+
+def _dims(model):
+    return (model["n_features"], *model["hidden"], model["n_classes"])
+
+
+def init_params(framework: str, model: dict, seed: int):
+    dims = _dims(model)
+    if framework == "splitme":
+        split = model["split_index"]
+        k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
+        return (init_mlp(k1, dims[:split + 1]),
+                init_mlp(k2, tuple(reversed(dims[split:]))))
+    if framework == "fedavg":
+        return (init_mlp(jax.random.PRNGKey(seed + 1), dims),)
+    raise KeyError(f"the reference has no framework {framework!r}")
+
+
+@functools.partial(jax.jit, static_argnames=("framework", "hp_items",
+                                             "n_classes", "dt"))
+def _round(params, a, E, keys, data, framework, hp_items, n_classes, dt):
+    hp = dict(hp_items)
+    ks = jax.vmap(jax.random.split)(keys)
+    nkeys, subs = ks[:, 0], ks[:, 1]
+    if framework == "splitme":
+        y1 = jax.nn.one_hot(data["y"], n_classes)
+        fn = lambda p, k: splitme_round(p, data["x"], y1, a, E, k, hp, dt)
+    else:
+        fn = lambda p, k: fedavg_round(p, data["x"], data["y"], a, E, k, hp,
+                                       dt)
+    params, losses = jax.vmap(fn)(params, subs)
+    return params, losses, nkeys
+
+
+@functools.partial(jax.jit, static_argnames=("framework", "gamma",
+                                             "n_classes", "dt"))
+def _accuracy(params, data, framework, gamma, n_classes, dt):
+    if framework == "splitme":
+        y1 = jax.nn.one_hot(data["y"], n_classes)
+        fn = lambda p: splitme_accuracy(p, data["x"], y1, data["x_test"],
+                                        data["y_test"], gamma, dt)
+    else:
+        fn = lambda p: fedavg_accuracy(p, data["x_test"], data["y_test"], dt)
+    return jax.vmap(fn)(params)
+
+
+def accuracy(config: dict, clients, test, params) -> np.ndarray:
+    """Test accuracy per seed of seed-stacked final parameters, by the
+    reference's own eval (Step 4 for SplitMe), in float32 at HIGHEST."""
+    data = {"x": jnp.asarray(clients["x"]), "y": jnp.asarray(clients["y"]),
+            "x_test": jnp.asarray(test[0]), "y_test": jnp.asarray(test[1])}
+    params = jax.tree.map(lambda p: jnp.asarray(p, jnp.float32), params)
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(_accuracy(params, data, config["framework"],
+                                    config["eval_gamma"],
+                                    config["model"]["n_classes"], None))
+
+
+def plan(config: dict, rounds: int, seeds) -> tuple:
+    """The reference schedule of one campaign: a (R, M), E (R,)."""
+    fw, hp = config["framework"], config["hyper"]
+    M = config["fleet"]["M"]
+    if fw == "splitme":
+        return plan_splitme(M, config["fleet"]["seed"], rounds,
+                            config["model"],
+                            config["fleet"]["samples_per_client"],
+                            hp["e_initial"])
+    return plan_fixed_k(M, rounds, hp["K"], hp["E"], int(min(seeds)))
+
+
+def run_campaign(config: dict, clients, test, *, rounds: int, seeds,
+                 compute_dtype=None, train_mask=None) -> dict:
+    """The reference of one ``run_campaign`` call.  Returns the schedule,
+    the initial and final parameters (stacked over seeds), losses
+    (S, R, phases) and the final accuracy per seed (the last row of an
+    (R, S) array, as the program reports it; the comparison reads no
+    other eval round).
+    ``train_mask(a)``, if given, replaces the selected sets the rounds
+    train on (the schedule returned stays the planned one): a planted
+    fault for the calibration."""
+    fw = config["framework"]
+    a, E = plan(config, rounds, seeds)
+    a_train = a if train_mask is None else train_mask(a)
+    hp = {k: v for k, v in config["hyper"].items()
+          if k in ("lr", "lr_c", "lr_s", "temperature", "batch_size")}
+    data = {"x": jnp.asarray(clients["x"]), "y": jnp.asarray(clients["y"]),
+            "x_test": jnp.asarray(test[0]), "y_test": jnp.asarray(test[1])}
+    n_classes = config["model"]["n_classes"]
+    with jax.default_matmul_precision("highest"):
+        init = jax.tree.map(
+            lambda *l: jnp.stack(l),
+            *[init_params(fw, config["model"], int(s)) for s in seeds])
+        params = init
+        keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
+        losses, acc = [], np.full((rounds, len(seeds)), np.nan)
+        for t in range(rounds):
+            params, l, keys = _round(
+                params, jnp.asarray(a_train[t], jnp.float32),
+                jnp.int32(E[t]), keys, data, fw, tuple(sorted(hp.items())),
+                n_classes, compute_dtype)
+            losses.append(l)
+        acc[-1] = np.asarray(_accuracy(params, data, fw,
+                                       config["eval_gamma"], n_classes,
+                                       compute_dtype))
+        losses = np.stack([np.asarray(l) for l in losses], axis=1)
+    return {"a": a, "E": E, "init": jax.device_get(init),
+            "params": jax.device_get(params), "losses": losses,
+            "acc": acc}
