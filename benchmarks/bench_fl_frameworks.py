@@ -126,7 +126,7 @@ def run(fast: bool = False):
     # ------------------------------------------------------------------
     import jax
 
-    from repro.launch import campaign as camp
+    from repro.launch import campaign as camp, spans
     from repro.launch.mesh import make_host_mesh
 
     n_seeds = 4
@@ -153,7 +153,7 @@ def run(fast: bool = False):
         mode_stats = {}
         res = None
         for mode, mkw in modes.items():
-            before = camp.HOST_TRANSFERS
+            before = spans.counts["host_transfers"]
             t0 = time.perf_counter()
             res = camp.run_campaign(name, DNN10, SystemParams(seed=0), cd,
                                     rounds=camp_rounds,
@@ -163,7 +163,7 @@ def run(fast: bool = False):
             mode_stats[mode] = {
                 "s": dt,
                 "rounds_per_sec": run_rounds / dt,
-                "host_transfers": camp.HOST_TRANSFERS - before,
+                "host_transfers": spans.counts["host_transfers"] - before,
             }
         scanned_speedup = serial_s / mode_stats["scanned"]["s"]
         summary[f"campaign_{name}"] = {

@@ -63,32 +63,6 @@ MESH_ACC_ATOL = 2e-2        # 4.2e-3
 MIN_ACCURACY = 0.5          # three classes: chance is 1/3
 
 
-class CompileCounter:
-    """Counts the XLA executables JAX builds (compiled, or loaded from the
-    persistent cache) and their seconds, through ``jax.monitoring``."""
-
-    BUILD = "/jax/core/compile/backend_compile_duration"
-    CACHE_HIT = "/jax/compilation_cache/cache_hits"
-
-    def __init__(self):
-        import jax
-        self.reset()
-        jax.monitoring.register_event_duration_secs_listener(self._on_time)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def reset(self):
-        self.executables, self.seconds, self.cache_hits = 0, 0.0, 0
-
-    def _on_time(self, event, duration, **_):
-        if event == self.BUILD:
-            self.executables += 1
-            self.seconds += duration
-
-    def _on_event(self, event, **_):
-        if event == self.CACHE_HIT:
-            self.cache_hits += 1
-
-
 class Checks:
     def __init__(self):
         self.failed = []
@@ -108,29 +82,31 @@ def _data(n_clients: int):
     return clients, test
 
 
-def _campaign(counter, framework, clients, test, *, device=None, **kw):
+def _campaign(framework, clients, test, *, device=None, **kw):
     """One ``run_campaign`` under strict transfers, with its transfer
-    count, executables built and wall time."""
+    count, the executables JAX compiled and loaded from the persistent
+    cache and their seconds (``repro.launch.spans``), and wall time."""
     import jax
     from repro.configs.splitme_dnn import DNN10
     from repro.core.cost import SystemParams
-    from repro.launch import campaign
+    from repro.launch import campaign, spans
 
-    before = campaign.HOST_TRANSFERS
-    counter.reset()
+    before = spans.counts.copy()
     where = (jax.default_device(device) if device is not None
              else contextlib.nullcontext())
     t0 = time.perf_counter()
-    with where:
+    with where, spans.record() as recorded:
         res = campaign.run_campaign(
             framework, DNN10, SystemParams(M=len(clients["y"]), seed=0),
             clients, seeds=SEEDS, test_data=test, strict_transfers=True,
             **kw)
+    counted = spans.counts - before
     stats = {"wall_s": time.perf_counter() - t0,
-             "transfers": campaign.HOST_TRANSFERS - before,
-             "executables": counter.executables,
-             "compile_s": counter.seconds,
-             "cache_hits": counter.cache_hits}
+             "transfers": counted["host_transfers"],
+             "compiled": counted["executables_compiled"],
+             "loaded": counted["executables_loaded"],
+             "compile_s": sum(s.counts.get("compile_s", 0.0)
+                              for s in recorded)}
     return res, stats
 
 
@@ -139,9 +115,9 @@ def _report(label, res, stats):
     last = res.losses[:, -1, :]
     print(f"{label}: accuracy per seed {res.accuracy.tolist()}; "
           f"last-round loss per seed/phase {last.tolist()}; "
-          f"host transfers {stats['transfers']}; executables built "
-          f"{stats['executables']} ({stats['cache_hits']} from the "
-          f"persistent cache) in {stats['compile_s']:.2f} s; wall "
+          f"host transfers {stats['transfers']}; executables compiled "
+          f"{stats['compiled']}, loaded from the persistent cache "
+          f"{stats['loaded']}, in {stats['compile_s']:.2f} s; wall "
           f"{stats['wall_s']:.2f} s (one run, compile included); "
           f"finite losses {bool(np.isfinite(res.losses).all())}",
           flush=True)
@@ -200,7 +176,7 @@ def _kernels_in_compiled_splitme(clients, test):
     return "tpu_custom_call" in round_text, "tpu_custom_call" in eval_text
 
 
-def one_chip(check, counter, cpu) -> None:
+def one_chip(check, cpu) -> None:
     from repro.kernels import dispatch
 
     pol = dispatch.get_policy(None)
@@ -221,7 +197,7 @@ def one_chip(check, counter, cpu) -> None:
                       ("splitme reference/chip", {"policy": "reference"}),
                       ("splitme reference/cpu", {"policy": "reference",
                                                  "device": cpu})]:
-        res, stats = _campaign(counter, "splitme", clients, test, **sm, **kw)
+        res, stats = _campaign("splitme", clients, test, **sm, **kw)
         _report(label, res, stats)
         _sane(check, label, res, stats)
         runs[label] = res
@@ -234,10 +210,10 @@ def one_chip(check, counter, cpu) -> None:
             CPU_LOSS_ATOL, CPU_ACC_ATOL)
 
     fa = dict(rounds=FEDAVG_ROUNDS, K=10, E=10)
-    chip, stats = _campaign(counter, "fedavg", clients, test, **fa)
+    chip, stats = _campaign("fedavg", clients, test, **fa)
     _report("fedavg default/chip", chip, stats)
     _sane(check, "fedavg default/chip", chip, stats, learns=False)
-    host, stats = _campaign(counter, "fedavg", clients, test, device=cpu,
+    host, stats = _campaign("fedavg", clients, test, device=cpu,
                             policy="reference", **fa)
     _report("fedavg reference/cpu", host, stats)
     _sane(check, "fedavg reference/cpu", host, stats, learns=False)
@@ -245,7 +221,7 @@ def one_chip(check, counter, cpu) -> None:
             CPU_LOSS_ATOL, CPU_ACC_ATOL)
 
 
-def four_chips(check, counter) -> None:
+def four_chips(check) -> None:
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core import engine
@@ -261,11 +237,11 @@ def four_chips(check, counter) -> None:
           f"{sharded['x'].sharding}")
 
     sm = dict(rounds=SPLITME_ROUNDS, eval_every=EVAL_EVERY)
-    mesh_res, stats = _campaign(counter, "splitme", sharded, test,
+    mesh_res, stats = _campaign("splitme", sharded, test,
                                 mesh=mesh, **sm)
     _report("splitme mesh data=4", mesh_res, stats)
     _sane(check, "splitme mesh data=4", mesh_res, stats)
-    one, stats = _campaign(counter, "splitme", clients, test, **sm)
+    one, stats = _campaign("splitme", clients, test, **sm)
     _report("splitme one chip", one, stats)
     _sane(check, "splitme one chip", one, stats)
     _parity(check, "splitme mesh vs one chip", mesh_res, one,
@@ -304,12 +280,12 @@ def main(argv=None) -> int:
     print(f"cache: {enable_compile_cache()}", flush=True)
     print(f"device: {devices[0].device_kind} x {len(devices)}", flush=True)
 
-    check, counter = Checks(), CompileCounter()
+    check = Checks()
     t0 = time.perf_counter()
     if args.chips == 4:
-        four_chips(check, counter)
+        four_chips(check)
     else:
-        one_chip(check, counter, jax.devices("cpu")[0])
+        one_chip(check, jax.devices("cpu")[0])
     print(f"total wall {time.perf_counter() - t0:.2f} s", flush=True)
     if check.failed:
         print(f"chip_smoke: FAILED: {', '.join(check.failed)}",

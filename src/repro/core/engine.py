@@ -327,10 +327,12 @@ def _round_core(spec: FrameworkSpec, runners, params: ParamsTuple, ctx_c,
     updated: Dict[int, Params] = {}
     phase_losses = []
     for pi, ph in enumerate(spec.phases):
-        tgt = ph.target_fn(params, updated, ctx_c)
-        w_rep = replicate(params[ph.param_idx], m)
-        w_new, loss_m = jax.vmap(runners[pi], in_axes=(0, 0, 0, None, 0))(
-            w_rep, ctx_c[ph.data_key], tgt, e_steps, keys[pi])
+        with jax.named_scope(f"phase_{ph.name}"):
+            tgt = ph.target_fn(params, updated, ctx_c)
+            w_rep = replicate(params[ph.param_idx], m)
+            w_new, loss_m = jax.vmap(runners[pi],
+                                     in_axes=(0, 0, 0, None, 0))(
+                w_rep, ctx_c[ph.data_key], tgt, e_steps, keys[pi])
         updated[ph.param_idx] = w_new
         phase_losses.append(loss_m)
     # Fault injection + robust aggregation act on the per-client UPDATE
@@ -357,30 +359,32 @@ def _round_core(spec: FrameworkSpec, runners, params: ParamsTuple, ctx_c,
     # Masked-FedAvg numerators, the |A_t| count and the loss sums all cross
     # the mesh in ONE fused psum — the paper's "one communication per round"
     # is literally one all-reduce in the lowered HLO (fl_dryrun pins this).
-    weighted = {i: jax.tree.map(lambda p: jnp.tensordot(a_mask, p, axes=1), u)
-                for i, u in updated.items()}
-    msum = jnp.sum(a_mask)
-    loss_sums = tuple(jnp.sum(l * a_mask) for l in phase_losses)
     quant = spec.quant
     old_qstate = qstate
-    if quant.stochastic:
-        weighted, qstate = quantcomm.fake_quant_int8(
-            weighted, qstate, qkey, quant)
-    if axis_names is not None:
-        weighted, msum, loss_sums = psum_bundle(
-            (weighted, msum, loss_sums), axis_names,
-            wire_dtype=jnp.bfloat16 if quant.mode == "bf16" else None)
-    elif quant.mode == "bf16":
-        # no psum to carry the narrow format — simulate the identical
-        # rounding so the single-device round matches the sharded wire
-        weighted, msum, loss_sums = quantcomm.simulate_cast(
-            (weighted, msum, loss_sums), jnp.bfloat16)
-    wsum = jnp.maximum(msum, 1.0)
-    new_params = tuple(
-        jax.tree.map(lambda p: p / wsum, weighted[i]) if i in weighted
-        else params[i]
-        for i in range(len(params)))
-    losses = tuple(s / wsum for s in loss_sums)
+    with jax.named_scope("aggregate"):
+        weighted = {i: jax.tree.map(
+            lambda p: jnp.tensordot(a_mask, p, axes=1), u)
+            for i, u in updated.items()}
+        msum = jnp.sum(a_mask)
+        loss_sums = tuple(jnp.sum(l * a_mask) for l in phase_losses)
+        if quant.stochastic:
+            weighted, qstate = quantcomm.fake_quant_int8(
+                weighted, qstate, qkey, quant)
+        if axis_names is not None:
+            weighted, msum, loss_sums = psum_bundle(
+                (weighted, msum, loss_sums), axis_names,
+                wire_dtype=jnp.bfloat16 if quant.mode == "bf16" else None)
+        elif quant.mode == "bf16":
+            # no psum to carry the narrow format — simulate the identical
+            # rounding so the single-device round matches the sharded wire
+            weighted, msum, loss_sums = quantcomm.simulate_cast(
+                (weighted, msum, loss_sums), jnp.bfloat16)
+        wsum = jnp.maximum(msum, 1.0)
+        new_params = tuple(
+            jax.tree.map(lambda p: p / wsum, weighted[i]) if i in weighted
+            else params[i]
+            for i in range(len(params)))
+        losses = tuple(s / wsum for s in loss_sums)
     if guards is None:
         return new_params, losses, qstate
     # In-scan guards on the AGGREGATED update (post-psum, so every shard
@@ -1149,7 +1153,7 @@ def build_eval_fn(spec: FrameworkSpec, cfg: DNNConfig, x_test, y_test, *,
         y1 = jax.nn.one_hot(jnp.asarray(client_data["y"]), cfg.n_classes)
         flat_y = y1.reshape(-1, cfg.n_classes)
 
-        def accuracy(params: ParamsTuple) -> jax.Array:
+        def _accuracy(params: ParamsTuple) -> jax.Array:
             w_c, w_s_inv = params
             smashed = jax.vmap(
                 lambda xm: dnn.client_forward(w_c, xm, cfg, precision=prec)
@@ -1166,11 +1170,15 @@ def build_eval_fn(spec: FrameworkSpec, cfg: DNNConfig, x_test, y_test, *,
             return jnp.mean((jnp.argmax(logits, -1) == y_test)
                             .astype(jnp.float32))
     else:
-        def accuracy(params: ParamsTuple) -> jax.Array:
+        def _accuracy(params: ParamsTuple) -> jax.Array:
             (w,) = params
             logits = dnn.mlp_forward(w, x_test, cfg.activation,
                                      precision=prec)
             return jnp.mean((jnp.argmax(logits, -1) == y_test)
                             .astype(jnp.float32))
+
+    def accuracy(params: ParamsTuple) -> jax.Array:
+        with jax.named_scope("eval"):
+            return _accuracy(params)
 
     return jax.jit(accuracy) if jit else accuracy

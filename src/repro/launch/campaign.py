@@ -84,18 +84,28 @@ from repro.configs.splitme_dnn import DNNConfig
 from repro.core import engine, population as popn, scenario as scen
 from repro.core.cost import SystemParams, schedule_metrics
 from repro.core.engine import RoundMetrics
-
-# Device→host transfer accounting: every metrics pull in this module goes
-# through _host_fetch, so tests/benchmarks can count transfers per campaign
-# (scanned: exactly 1; python loop: 1 per round).
-HOST_TRANSFERS = 0
+from repro.launch import spans
 
 
 def _host_fetch(tree):
-    """The single device→host transfer point for campaign metrics."""
-    global HOST_TRANSFERS
-    HOST_TRANSFERS += 1
-    return jax.device_get(tree)
+    """The single device→host transfer point for campaign metrics.  Every
+    metrics pull in this module comes here, so the counter
+    ``spans.counts["host_transfers"]`` counts them (scanned campaign:
+    exactly 1; python loop: 1 per round)."""
+    with spans.span("host_fetch"):
+        spans.count("host_transfers")
+        return jax.device_get(tree)
+
+
+def _init_state(spec, seeds, mesh=None):
+    """Each seed's initial params, round-key chain and error-feedback
+    state, stacked over seeds; the init keys mirror the serial trainers."""
+    with spans.span("init_state"):
+        init_keys = jnp.stack([jax.random.PRNGKey(s + spec.init_key_offset)
+                               for s in seeds])
+        key_arr = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
+        params = jax.vmap(spec.init_fn)(init_keys)
+        return params, key_arr, _init_qstate(spec, params, mesh)
 
 
 def _init_qstate(spec, params, mesh=None):
@@ -373,131 +383,157 @@ def run_campaign(framework: str, cfg: DNNConfig, sp: SystemParams,
     given, runs after each committed save (crash-injection drivers and
     tests hang their abort/kill timing on it).
     """
-    x = jnp.asarray(client_data["x"])
-    y = jnp.asarray(client_data["y"])
-    if x.shape[0] != sp.M:
-        # the gathered round would silently clamp out-of-range client
-        # indices under jit; fail loudly instead
-        raise ValueError(f"client_data has {x.shape[0]} clients but "
-                         f"SystemParams.M={sp.M}")
-    n_m = int(x.shape[1])
-    if policy_seed is None:
-        policy_seed = min(seeds)
-    sp, sched = plan_schedule(framework, sp, cfg, rounds, K=K, E=E,
-                              e_initial=e_initial, policy_seed=policy_seed,
-                              n_samples_per_client=n_m, quant=quant,
-                              scenario=scenario, scenario_seed=scenario_seed)
-    # masked_loss_metric: average losses over the executed steps only, so a
-    # round's scan can be exactly E_t steps long.  Trained params are
-    # identical to the serial trainers (masked updates are exact no-ops);
-    # only SplitMe's *loss metric* differs from the seed quirk of averaging
-    # over the full E_max scan.
-    spec = engine.make_spec(framework, cfg, masked_loss_metric=True,
-                            policy=policy, quant=quant, **hyper)
-    comm, nsel, sim, cost, energy = _schedule_system_metrics(spec, sched, sp)
+    with spans.span("run_campaign", framework=framework, rounds=rounds,
+                    seeds=len(seeds)):
+        spans.count("campaigns")
+        x = jnp.asarray(client_data["x"])
+        y = jnp.asarray(client_data["y"])
+        if x.shape[0] != sp.M:
+            # the gathered round would silently clamp out-of-range client
+            # indices under jit; fail loudly instead
+            raise ValueError(f"client_data has {x.shape[0]} clients but "
+                             f"SystemParams.M={sp.M}")
+        n_m = int(x.shape[1])
+        if policy_seed is None:
+            policy_seed = min(seeds)
+        with spans.span("plan_schedule", rounds=rounds):
+            sp, sched = plan_schedule(
+                framework, sp, cfg, rounds, K=K, E=E, e_initial=e_initial,
+                policy_seed=policy_seed, n_samples_per_client=n_m, quant=quant,
+                scenario=scenario, scenario_seed=scenario_seed)
+        # masked_loss_metric: average losses over the executed steps only, so a
+        # round's scan can be exactly E_t steps long.  Trained params are
+        # identical to the serial trainers (masked updates are exact no-ops);
+        # only SplitMe's *loss metric* differs from the seed quirk of averaging
+        # over the full E_max scan.
+        spec = engine.make_spec(framework, cfg, masked_loss_metric=True,
+                                policy=policy, quant=quant, **hyper)
+        comm, nsel, sim, cost, energy = _schedule_system_metrics(
+            spec, sched, sp)
 
-    trace = sched.trace
-    has_faults = trace is not None and trace.has_faults()
-    if guards is None and has_faults:
-        guards = engine.RoundGuards()       # faults auto-arm the defaults
-    elif guards is False or guards is None:
-        guards = None
-    if checkpoint_every or checkpoint_dir or resume:
-        if not (checkpoint_every and checkpoint_dir is not None):
-            raise ValueError("checkpointing needs BOTH checkpoint_every "
-                             "and checkpoint_dir (resume implies both)")
-        if not scan:
-            raise ValueError("checkpoint/resume requires scan=True (the "
-                             "python loop has no segment boundaries)")
-        if strict_transfers:
-            raise ValueError("checkpoint_every is incompatible with "
-                             "strict_transfers: each segment save is an "
-                             "explicit device→host pull")
+        trace = sched.trace
+        has_faults = trace is not None and trace.has_faults()
+        if guards is None and has_faults:
+            guards = engine.RoundGuards()       # faults auto-arm the defaults
+        elif guards is False or guards is None:
+            guards = None
+        if checkpoint_every or checkpoint_dir or resume:
+            if not (checkpoint_every and checkpoint_dir is not None):
+                raise ValueError("checkpointing needs BOTH checkpoint_every "
+                                 "and checkpoint_dir (resume implies both)")
+            if not scan:
+                raise ValueError("checkpoint/resume requires scan=True (the "
+                                 "python loop has no segment boundaries)")
+            if strict_transfers:
+                raise ValueError("checkpoint_every is incompatible with "
+                                 "strict_transfers: each segment save is an "
+                                 "explicit device→host pull")
 
-    if mesh is not None:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        csh = NamedSharding(mesh, P(engine.client_axes(mesh)))
-        x, y = jax.device_put(x, csh), jax.device_put(y, csh)
-
-    if not scan:
         if mesh is not None:
-            raise ValueError("mesh (sharded rounds) requires scan=True")
-        if eval_every:
-            raise ValueError("eval_every (fused per-round eval) requires "
-                             "scan=True; the python loop only evaluates "
-                             "post-hoc")
-        if has_faults or guards is not None:
-            raise ValueError("fault injection / RoundGuards require "
-                             "scan=True (the guards live inside the scan)")
-        losses, params = _run_rounds_loop(spec, cfg, sp, sched, x, y, seeds)
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            csh = NamedSharding(mesh, P(engine.client_axes(mesh)))
+            x, y = jax.device_put(x, csh), jax.device_put(y, csh)
+
+        if not scan:
+            if mesh is not None:
+                raise ValueError("mesh (sharded rounds) requires scan=True")
+            if eval_every:
+                raise ValueError("eval_every (fused per-round eval) requires "
+                                 "scan=True; the python loop only evaluates "
+                                 "post-hoc")
+            if has_faults or guards is not None:
+                raise ValueError("fault injection / RoundGuards require "
+                                 "scan=True (the guards live inside the scan)")
+            losses, params = _run_rounds_loop(spec, cfg, sp, sched, x, y,
+                                              seeds)
+            result = CampaignResult(
+                framework=framework, seeds=tuple(seeds), schedule=sched,
+                params=params, losses=losses,
+                metrics=_make_metrics(sched, comm, nsel, sim, cost, energy,
+                                      losses, None))
+            if test_data is not None:
+                result.accuracy = evaluate_campaign(
+                    result, cfg, test_data, client_data=client_data,
+                    gamma=eval_gamma, policy=spec.policy)
+            return result
+
+        do_eval = np.zeros(rounds, np.float32)
+        if test_data is not None:
+            if eval_every:
+                do_eval[eval_every - 1::eval_every] = 1.0
+            do_eval[rounds - 1] = 1.0
+
+        ckpt = None
+        if checkpoint_every:
+            from repro.launch import resilience
+            fp = resilience.schedule_fingerprint(
+                framework, seeds, sched, do_eval=do_eval,
+                quant_mode=spec.quant.mode, checkpoint_every=checkpoint_every)
+            resume_from = None
+            if resume:
+                resume_from = resilience.latest_checkpoint(checkpoint_dir)
+                if resume_from is not None:
+                    meta = resilience.load_checkpoint_meta(resume_from)
+                    if meta.get("fingerprint") != fp:
+                        raise ValueError(
+                            f"checkpoint {resume_from} was written by a "
+                            f"different campaign plan (schedule fingerprint "
+                            f"mismatch); refusing to resume")
+            ckpt = {"dir": checkpoint_dir, "every": int(checkpoint_every),
+                    "fingerprint": fp, "resume_from": resume_from,
+                    "hook": _checkpoint_hook, "framework": framework,
+                    "n_seeds": len(seeds)}
+
+        guard = (jax.transfer_guard_device_to_host("disallow")
+                 if strict_transfers else contextlib.nullcontext())
+        with guard:
+            params, buffers = _run_rounds_scan(
+                spec, cfg, sp, sched, _scan_data(x, y, test_data), seeds,
+                do_eval, eval_gamma, mesh, guards=guards, ckpt=ckpt)
+        host = _host_fetch(buffers)            # THE per-campaign transfer
+
+        live = host["live"] > 0
+        losses = np.transpose(host["loss"][live], (1, 0, 2))   # (S, R, n_ph)
+        acc_rounds = np.asarray(host["acc"][live])             # (R, S)
+        skipped = quorum = crashed = None
+        if guards is not None:
+            skipped = np.asarray(host["skipped"][live])        # (R, S)
+            quorum = np.asarray(host["quorum"][live])          # (R, S)
+        if trace is not None and trace.crash is not None:
+            crashed = (np.asarray(trace.crash[:rounds]) > 0).astype(np.float64)
         result = CampaignResult(
             framework=framework, seeds=tuple(seeds), schedule=sched,
             params=params, losses=losses,
-            metrics=_make_metrics(sched, comm, nsel, sim, cost, energy,
-                                  losses, None))
+            metrics=_make_metrics(sched, comm, nsel, sim, cost, energy, losses,
+                                  acc_rounds if test_data is not None
+                                  else None,
+                                  skipped=skipped, quorum=quorum,
+                                  crashed=crashed),
+            accuracy_per_round=acc_rounds if test_data is not None else None,
+            skipped_per_round=skipped, quorum_per_round=quorum,
+            crashed_per_round=crashed)
         if test_data is not None:
-            result.accuracy = evaluate_campaign(
-                result, cfg, test_data, client_data=client_data,
-                gamma=eval_gamma, policy=spec.policy)
+            result.accuracy = acc_rounds[rounds - 1]
         return result
 
-    do_eval = np.zeros(rounds, np.float32)
-    if test_data is not None:
-        if eval_every:
-            do_eval[eval_every - 1::eval_every] = 1.0
-        do_eval[rounds - 1] = 1.0
 
-    ckpt = None
-    if checkpoint_every:
-        from repro.launch import resilience
-        fp = resilience.schedule_fingerprint(
-            framework, seeds, sched, do_eval=do_eval,
-            quant_mode=spec.quant.mode, checkpoint_every=checkpoint_every)
-        resume_from = None
-        if resume:
-            resume_from = resilience.latest_checkpoint(checkpoint_dir)
-            if resume_from is not None:
-                meta = resilience.load_checkpoint_meta(resume_from)
-                if meta.get("fingerprint") != fp:
-                    raise ValueError(
-                        f"checkpoint {resume_from} was written by a "
-                        f"different campaign plan (schedule fingerprint "
-                        f"mismatch); refusing to resume")
-        ckpt = {"dir": checkpoint_dir, "every": int(checkpoint_every),
-                "fingerprint": fp, "resume_from": resume_from,
-                "hook": _checkpoint_hook, "framework": framework,
-                "n_seeds": len(seeds)}
+def _concat(ys_all):
+    """The scan segments' metric buffers, joined along the round axis."""
+    return {k: (jnp.concatenate([ys[k] for ys in ys_all], axis=0)
+                if len(ys_all) > 1 else ys_all[0][k])
+            for k in ys_all[0]}
 
-    guard = (jax.transfer_guard_device_to_host("disallow")
-             if strict_transfers else contextlib.nullcontext())
-    with guard:
-        params, buffers = _run_rounds_scan(
-            spec, cfg, sp, sched, _scan_data(x, y, test_data), seeds,
-            do_eval, eval_gamma, mesh, guards=guards, ckpt=ckpt)
-    host = _host_fetch(buffers)            # THE per-campaign transfer
 
-    live = host["live"] > 0
-    losses = np.transpose(host["loss"][live], (1, 0, 2))   # (S, R, n_ph)
-    acc_rounds = np.asarray(host["acc"][live])             # (R, S)
-    skipped = quorum = crashed = None
-    if guards is not None:
-        skipped = np.asarray(host["skipped"][live])        # (R, S)
-        quorum = np.asarray(host["quorum"][live])          # (R, S)
-    if trace is not None and trace.crash is not None:
-        crashed = (np.asarray(trace.crash[:rounds]) > 0).astype(np.float64)
-    result = CampaignResult(
-        framework=framework, seeds=tuple(seeds), schedule=sched,
-        params=params, losses=losses,
-        metrics=_make_metrics(sched, comm, nsel, sim, cost, energy, losses,
-                              acc_rounds if test_data is not None else None,
-                              skipped=skipped, quorum=quorum,
-                              crashed=crashed),
-        accuracy_per_round=acc_rounds if test_data is not None else None,
-        skipped_per_round=skipped, quorum_per_round=quorum,
-        crashed_per_round=crashed)
-    if test_data is not None:
-        result.accuracy = acc_rounds[rounds - 1]
-    return result
+def _save_checkpoint(ckpt, end: int, rounds: int, carry, ys_all) -> None:
+    """Persist the campaign carry and the buffers of rounds [0, end)."""
+    from repro.launch import resilience
+    with spans.span("checkpoint_save", round=end):
+        resilience.save_checkpoint(
+            ckpt["dir"], end, carry, _concat(ys_all),
+            fingerprint=ckpt["fingerprint"], rounds=rounds,
+            framework=ckpt["framework"], n_seeds=ckpt["n_seeds"])
+    if ckpt["hook"] is not None:
+        ckpt["hook"](end)
 
 
 def _run_rounds_loop(spec, cfg, sp, sched, x, y, seeds):
@@ -520,13 +556,7 @@ def _run_rounds_loop(spec, cfg, sp, sched, x, y, seeds):
                 donate_argnums=(0, 5))
         return fns[k_bucket, e_bucket]
 
-    init_keys = jnp.stack([jax.random.PRNGKey(s + spec.init_key_offset)
-                           for s in seeds])
-    key_arr = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
-    params = jax.vmap(spec.init_fn)(init_keys)
-    # per-seed error-feedback accumulator (zeros_like on the seed-stacked
-    # params gives the stacked state directly; () when stateless)
-    qstate = engine.init_quant_state(spec, params)
+    params, key_arr, qstate = _init_state(spec, seeds)
     loss_rows = []
     for r in range(rounds):
         k_r, e_r = int(counts[r]), int(sched.E[r])
@@ -685,6 +715,7 @@ def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
     def seg_exec(kb: int, eb: int, lb: int):
         if (kb, eb, lb) in fns:
             return fns[kb, eb, lb]
+        spans.count("segment_builds")
         fns[kb, eb, lb] = jax.jit(functools.partial(seg, eb),
                                   donate_argnums=(0, 1, 2))
         return fns[kb, eb, lb]
@@ -731,11 +762,7 @@ def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
 
         return jax.lax.scan(body, (params, key_arr, qstate), xs)
 
-    init_keys = jnp.stack([jax.random.PRNGKey(s + spec.init_key_offset)
-                           for s in seeds])
-    key_arr = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
-    params = jax.vmap(spec.init_fn)(init_keys)
-    qstate = _init_qstate(spec, params, mesh)
+    params, key_arr, qstate = _init_state(spec, seeds, mesh)
     ys_all = []
     start_round = 0
     if ckpt is not None and ckpt["resume_from"] is not None:
@@ -761,67 +788,57 @@ def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
         if start + length <= start_round:
             continue                       # restored from the checkpoint
         lb = len_of[length]
-        xs = {
-            "e": np.zeros(lb, np.int32),
-            "live": np.zeros(lb, np.float32),
-            "do_eval": np.zeros(lb, np.float32),
-        }
-        xs["e"][:length] = sched.E[start:start + length]
-        xs["live"][:length] = 1.0
-        xs["do_eval"][:length] = do_eval[start:start + length]
-        if robust:
-            xs["crash"] = np.zeros(lb, np.float32)
-            if has_crash:
-                xs["crash"][:length] = crash[start:start + length]
-        if mesh is None:
-            idx = np.zeros((lb, kb), np.int32)
-            mask = np.zeros((lb, kb), np.float32)
-            for i, r in enumerate(range(start, start + length)):
-                k_r = int(counts[r])
-                idx[i, :k_r] = np.nonzero(sched.a[r])[0]  # pads: client 0,
-                mask[i, :k_r] = 1.0                       # mask weight 0
-            xs["idx"], xs["mask"] = idx, mask
-            if with_faults:
-                # gather the fault channels by the same cohort index;
-                # pads stay neutral (poison 0, gain 1 — and carry mask 0)
-                pz = np.zeros((lb, kb), np.float32)
-                wg = np.ones((lb, kb), np.float32)
+        with spans.span("segment", kb=kb, eb=eb, lb=lb, start=start,
+                        length=length, built=(kb, eb, lb) not in fns):
+            xs = {
+                "e": np.zeros(lb, np.int32),
+                "live": np.zeros(lb, np.float32),
+                "do_eval": np.zeros(lb, np.float32),
+            }
+            xs["e"][:length] = sched.E[start:start + length]
+            xs["live"][:length] = 1.0
+            xs["do_eval"][:length] = do_eval[start:start + length]
+            if robust:
+                xs["crash"] = np.zeros(lb, np.float32)
+                if has_crash:
+                    xs["crash"][:length] = crash[start:start + length]
+            if mesh is None:
+                idx = np.zeros((lb, kb), np.int32)
+                mask = np.zeros((lb, kb), np.float32)
                 for i, r in enumerate(range(start, start + length)):
                     k_r = int(counts[r])
-                    pz[i, :k_r] = p_arr[r, idx[i, :k_r]]
-                    wg[i, :k_r] = w_arr[r, idx[i, :k_r]]
-                xs["poison"], xs["wire"] = pz, wg
-        else:
-            mask = np.zeros((lb, M), np.float32)
-            mask[:length] = sched.a[start:start + length]
-            xs["mask"] = mask
-            if with_faults:
-                pz = np.zeros((lb, M), np.float32)
-                wg = np.ones((lb, M), np.float32)
-                pz[:length] = p_arr[start:start + length]
-                wg[:length] = w_arr[start:start + length]
-                xs["poison"], xs["wire"] = pz, wg
-        (params, key_arr, qstate), ys = seg_exec(kb, eb, lb)(
-            params, key_arr, qstate, xs, data)
+                    idx[i, :k_r] = np.nonzero(sched.a[r])[0]  # pads: client
+                    mask[i, :k_r] = 1.0                       # 0, weight 0
+                xs["idx"], xs["mask"] = idx, mask
+                if with_faults:
+                    # gather the fault channels by the same cohort index;
+                    # pads stay neutral (poison 0, gain 1 — and carry mask 0)
+                    pz = np.zeros((lb, kb), np.float32)
+                    wg = np.ones((lb, kb), np.float32)
+                    for i, r in enumerate(range(start, start + length)):
+                        k_r = int(counts[r])
+                        pz[i, :k_r] = p_arr[r, idx[i, :k_r]]
+                        wg[i, :k_r] = w_arr[r, idx[i, :k_r]]
+                    xs["poison"], xs["wire"] = pz, wg
+            else:
+                mask = np.zeros((lb, M), np.float32)
+                mask[:length] = sched.a[start:start + length]
+                xs["mask"] = mask
+                if with_faults:
+                    pz = np.zeros((lb, M), np.float32)
+                    wg = np.ones((lb, M), np.float32)
+                    pz[:length] = p_arr[start:start + length]
+                    wg[:length] = w_arr[start:start + length]
+                    xs["poison"], xs["wire"] = pz, wg
+            (params, key_arr, qstate), ys = seg_exec(kb, eb, lb)(
+                params, key_arr, qstate, xs, data)
         ys_all.append(ys)
         end = start + length
         if ckpt is not None and (end % ckpt["every"] == 0 or end == rounds):
-            from repro.launch import resilience
-            done = {k: (jnp.concatenate([ys[k] for ys in ys_all], axis=0)
-                        if len(ys_all) > 1 else ys_all[0][k])
-                    for k in ys_all[0]}
-            resilience.save_checkpoint(
-                ckpt["dir"], end,
-                {"params": params, "keys": key_arr, "qstate": qstate},
-                done, fingerprint=ckpt["fingerprint"], rounds=rounds,
-                framework=ckpt["framework"], n_seeds=ckpt["n_seeds"])
-            if ckpt["hook"] is not None:
-                ckpt["hook"](end)
-
-    buffers = {k: (jnp.concatenate([ys[k] for ys in ys_all], axis=0)
-                   if len(ys_all) > 1 else ys_all[0][k])
-               for k in ys_all[0]}
-    return params, buffers
+            _save_checkpoint(ckpt, end, rounds, {"params": params,
+                                                 "keys": key_arr,
+                                                 "qstate": qstate}, ys_all)
+    return params, _concat(ys_all)
 
 
 # ---------------------------------------------------------------------------
@@ -974,103 +991,108 @@ def run_population_campaign(framework: str, cfg: DNNConfig,
     Gram sums; population campaigns use the FINAL round's cohort shards —
     with ``cohort >= population.size`` that is the full materialized
     dataset, keeping the parity contract exact."""
-    X = np.asarray(data[0])
-    y = np.asarray(data[1])
-    if policy_seed is None:
-        policy_seed = min(seeds)
-    sp, sched = plan_population_schedule(
-        framework, population, cfg, rounds, cohort=cohort,
-        policy_seed=policy_seed, K=K, E=E, e_initial=e_initial,
-        n_samples_per_client=samples_per_client, quant=quant,
-        scenario=scenario, scenario_seed=scenario_seed,
-        stratified=stratified)
-    spec = engine.make_spec(framework, cfg, masked_loss_metric=True,
-                            policy=policy, quant=quant, **hyper)
-    comm = np.atleast_1d(np.asarray(
-        spec.comm_model(sched.a, sched.E, sp), np.float64))
-    nsel = sched.a.sum(axis=1).astype(int)
-    sim, cost, energy = schedule_metrics(sched.a, sched.b, sched.E, sp,
-                                         rows=sched.rows)
+    with spans.span("run_population_campaign", framework=framework,
+                    rounds=rounds, seeds=len(seeds)):
+        spans.count("campaigns")
+        X = np.asarray(data[0])
+        y = np.asarray(data[1])
+        if policy_seed is None:
+            policy_seed = min(seeds)
+        with spans.span("plan_schedule", rounds=rounds):
+            sp, sched = plan_population_schedule(
+                framework, population, cfg, rounds, cohort=cohort,
+                policy_seed=policy_seed, K=K, E=E, e_initial=e_initial,
+                n_samples_per_client=samples_per_client, quant=quant,
+                scenario=scenario, scenario_seed=scenario_seed,
+                stratified=stratified)
+        spec = engine.make_spec(framework, cfg, masked_loss_metric=True,
+                                policy=policy, quant=quant, **hyper)
+        comm = np.atleast_1d(np.asarray(
+            spec.comm_model(sched.a, sched.E, sp), np.float64))
+        nsel = sched.a.sum(axis=1).astype(int)
+        sim, cost, energy = schedule_metrics(sched.a, sched.b, sched.E, sp,
+                                             rows=sched.rows)
 
-    # per-round cohort shards, drawn lazily for the sampled ids only
-    alpha = "population"
-    if sched.trace is not None and sched.trace.data_alpha is not None:
-        alpha = sched.trace.data_alpha
-    C = sched.ids.shape[1]
-    xc_all = np.zeros((rounds, C, samples_per_client, X.shape[1]),
-                      np.float32)
-    yc_all = np.zeros((rounds, C, samples_per_client), np.int32)
-    for t in range(rounds):
-        sh = population.sample_shards(X, y, sched.ids[t],
-                                      samples_per_client, alpha=alpha)
-        xc_all[t], yc_all[t] = sh["x"], sh["y"]
+        # per-round cohort shards, drawn lazily for the sampled ids only
+        alpha = "population"
+        if sched.trace is not None and sched.trace.data_alpha is not None:
+            alpha = sched.trace.data_alpha
+        C = sched.ids.shape[1]
+        xc_all = np.zeros((rounds, C, samples_per_client, X.shape[1]),
+                          np.float32)
+        yc_all = np.zeros((rounds, C, samples_per_client), np.int32)
+        for t in range(rounds):
+            sh = population.sample_shards(X, y, sched.ids[t],
+                                          samples_per_client, alpha=alpha)
+            xc_all[t], yc_all[t] = sh["x"], sh["y"]
 
-    if guards is False:
-        guards = None
-    if checkpoint_every or checkpoint_dir or resume:
-        if not (checkpoint_every and checkpoint_dir is not None):
-            raise ValueError("checkpointing needs BOTH checkpoint_every "
-                             "and checkpoint_dir (resume implies both)")
-        if strict_transfers:
-            raise ValueError("checkpoint_every is incompatible with "
-                             "strict_transfers: each segment save is an "
-                             "explicit device→host pull")
+        if guards is False:
+            guards = None
+        if checkpoint_every or checkpoint_dir or resume:
+            if not (checkpoint_every and checkpoint_dir is not None):
+                raise ValueError("checkpointing needs BOTH checkpoint_every "
+                                 "and checkpoint_dir (resume implies both)")
+            if strict_transfers:
+                raise ValueError("checkpoint_every is incompatible with "
+                                 "strict_transfers: each segment save is an "
+                                 "explicit device→host pull")
 
-    do_eval = np.zeros(rounds, np.float32)
-    if test_data is not None:
-        if eval_every:
-            do_eval[eval_every - 1::eval_every] = 1.0
-        do_eval[rounds - 1] = 1.0
+        do_eval = np.zeros(rounds, np.float32)
+        if test_data is not None:
+            if eval_every:
+                do_eval[eval_every - 1::eval_every] = 1.0
+            do_eval[rounds - 1] = 1.0
 
-    ckpt = None
-    if checkpoint_every:
-        from repro.launch import resilience
-        fp = resilience.schedule_fingerprint(
-            framework, seeds, sched, do_eval=do_eval,
-            quant_mode=spec.quant.mode, checkpoint_every=checkpoint_every,
-            extra=(sched.ids, sched.m_t))
-        resume_from = None
-        if resume:
-            resume_from = resilience.latest_checkpoint(checkpoint_dir)
-            if resume_from is not None:
-                meta = resilience.load_checkpoint_meta(resume_from)
-                if meta.get("fingerprint") != fp:
-                    raise ValueError(
-                        f"checkpoint {resume_from} was written by a "
-                        f"different campaign plan (schedule fingerprint "
-                        f"mismatch); refusing to resume")
-        ckpt = {"dir": checkpoint_dir, "every": int(checkpoint_every),
-                "fingerprint": fp, "resume_from": resume_from,
-                "hook": _checkpoint_hook, "framework": framework,
-                "n_seeds": len(seeds)}
+        ckpt = None
+        if checkpoint_every:
+            from repro.launch import resilience
+            fp = resilience.schedule_fingerprint(
+                framework, seeds, sched, do_eval=do_eval,
+                quant_mode=spec.quant.mode, checkpoint_every=checkpoint_every,
+                extra=(sched.ids, sched.m_t))
+            resume_from = None
+            if resume:
+                resume_from = resilience.latest_checkpoint(checkpoint_dir)
+                if resume_from is not None:
+                    meta = resilience.load_checkpoint_meta(resume_from)
+                    if meta.get("fingerprint") != fp:
+                        raise ValueError(
+                            f"checkpoint {resume_from} was written by a "
+                            f"different campaign plan (schedule fingerprint "
+                            f"mismatch); refusing to resume")
+            ckpt = {"dir": checkpoint_dir, "every": int(checkpoint_every),
+                    "fingerprint": fp, "resume_from": resume_from,
+                    "hook": _checkpoint_hook, "framework": framework,
+                    "n_seeds": len(seeds)}
 
-    guard = (jax.transfer_guard_device_to_host("disallow")
-             if strict_transfers else contextlib.nullcontext())
-    with guard:
-        params, buffers = _run_population_scan(
-            spec, cfg, sp, sched, xc_all, yc_all, seeds, do_eval,
-            _scan_data(jnp.asarray(xc_all[-1]), jnp.asarray(yc_all[-1]),
-                       test_data), eval_gamma, guards=guards, ckpt=ckpt)
-    host = _host_fetch(buffers)            # THE per-campaign transfer
+        guard = (jax.transfer_guard_device_to_host("disallow")
+                 if strict_transfers else contextlib.nullcontext())
+        with guard:
+            params, buffers = _run_population_scan(
+                spec, cfg, sp, sched, xc_all, yc_all, seeds, do_eval,
+                _scan_data(jnp.asarray(xc_all[-1]), jnp.asarray(yc_all[-1]),
+                           test_data), eval_gamma, guards=guards, ckpt=ckpt)
+        host = _host_fetch(buffers)            # THE per-campaign transfer
 
-    live = host["live"] > 0
-    losses = np.transpose(host["loss"][live], (1, 0, 2))   # (S, R, n_ph)
-    acc_rounds = np.asarray(host["acc"][live])             # (R, S)
-    skipped = quorum = None
-    if guards is not None:
-        skipped = np.asarray(host["skipped"][live])
-        quorum = np.asarray(host["quorum"][live])
-    result = CampaignResult(
-        framework=framework, seeds=tuple(seeds), schedule=sched,
-        params=params, losses=losses,
-        metrics=_make_metrics(sched, comm, nsel, sim, cost, energy, losses,
-                              acc_rounds if test_data is not None else None,
-                              skipped=skipped, quorum=quorum),
-        accuracy_per_round=acc_rounds if test_data is not None else None,
-        skipped_per_round=skipped, quorum_per_round=quorum)
-    if test_data is not None:
-        result.accuracy = acc_rounds[rounds - 1]
-    return result
+        live = host["live"] > 0
+        losses = np.transpose(host["loss"][live], (1, 0, 2))   # (S, R, n_ph)
+        acc_rounds = np.asarray(host["acc"][live])             # (R, S)
+        skipped = quorum = None
+        if guards is not None:
+            skipped = np.asarray(host["skipped"][live])
+            quorum = np.asarray(host["quorum"][live])
+        result = CampaignResult(
+            framework=framework, seeds=tuple(seeds), schedule=sched,
+            params=params, losses=losses,
+            metrics=_make_metrics(sched, comm, nsel, sim, cost, energy, losses,
+                                  acc_rounds if test_data is not None
+                                  else None,
+                                  skipped=skipped, quorum=quorum),
+            accuracy_per_round=acc_rounds if test_data is not None else None,
+            skipped_per_round=skipped, quorum_per_round=quorum)
+        if test_data is not None:
+            result.accuracy = acc_rounds[rounds - 1]
+        return result
 
 
 def _run_population_scan(spec, cfg, sp, sched: PopulationSchedule, xc_all,
@@ -1101,6 +1123,7 @@ def _run_population_scan(spec, cfg, sp, sched: PopulationSchedule, xc_all,
     def seg_exec(eb: int, lb: int):
         if (eb, lb) in fns:
             return fns[eb, lb]
+        spans.count("segment_builds")
         raw = engine.build_cohort_round_fn(spec, cfg, e_max=max(1, eb),
                                            jit=False, guards=guards)
         fns[eb, lb] = jax.jit(functools.partial(seg, raw),
@@ -1146,11 +1169,7 @@ def _run_population_scan(spec, cfg, sp, sched: PopulationSchedule, xc_all,
 
         return jax.lax.scan(body, (params, key_arr, qstate), xs)
 
-    init_keys = jnp.stack([jax.random.PRNGKey(s + spec.init_key_offset)
-                           for s in seeds])
-    key_arr = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
-    params = jax.vmap(spec.init_fn)(init_keys)
-    qstate = _init_qstate(spec, params)
+    params, key_arr, qstate = _init_state(spec, seeds)
     ys_all = []
     start_round = 0
     if ckpt is not None and ckpt["resume_from"] is not None:
@@ -1169,41 +1188,32 @@ def _run_population_scan(spec, cfg, sp, sched: PopulationSchedule, xc_all,
         if start + length <= start_round:
             continue                       # restored from the checkpoint
         lb = len_of[length]
-        xs = {
-            "e": np.zeros(lb, np.int32),
-            "live": np.zeros(lb, np.float32),
-            "do_eval": np.zeros(lb, np.float32),
-            "mask": np.zeros((lb, C), np.float32),
-            "xc": np.zeros((lb, C, n_samples, xc_all.shape[3]), np.float32),
-            "yc": np.zeros((lb, C, n_samples), np.int32),
-        }
-        end = start + length
-        xs["e"][:length] = sched.E[start:end]
-        xs["live"][:length] = 1.0
-        xs["do_eval"][:length] = do_eval[start:end]
-        xs["mask"][:length] = sched.a[start:end]
-        xs["xc"][:length] = xc_all[start:end]
-        xs["yc"][:length] = yc_all[start:end]
-        (params, key_arr, qstate), ys = seg_exec(eb, lb)(
-            params, key_arr, qstate, xs, data)
+        with spans.span("segment", kb=C, eb=eb, lb=lb, start=start,
+                        length=length, built=(eb, lb) not in fns):
+            xs = {
+                "e": np.zeros(lb, np.int32),
+                "live": np.zeros(lb, np.float32),
+                "do_eval": np.zeros(lb, np.float32),
+                "mask": np.zeros((lb, C), np.float32),
+                "xc": np.zeros((lb, C, n_samples, xc_all.shape[3]),
+                               np.float32),
+                "yc": np.zeros((lb, C, n_samples), np.int32),
+            }
+            end = start + length
+            xs["e"][:length] = sched.E[start:end]
+            xs["live"][:length] = 1.0
+            xs["do_eval"][:length] = do_eval[start:end]
+            xs["mask"][:length] = sched.a[start:end]
+            xs["xc"][:length] = xc_all[start:end]
+            xs["yc"][:length] = yc_all[start:end]
+            (params, key_arr, qstate), ys = seg_exec(eb, lb)(
+                params, key_arr, qstate, xs, data)
         ys_all.append(ys)
         if ckpt is not None and (end % ckpt["every"] == 0 or end == rounds):
-            from repro.launch import resilience
-            done = {k: (jnp.concatenate([ys[k] for ys in ys_all], axis=0)
-                        if len(ys_all) > 1 else ys_all[0][k])
-                    for k in ys_all[0]}
-            resilience.save_checkpoint(
-                ckpt["dir"], end,
-                {"params": params, "keys": key_arr, "qstate": qstate},
-                done, fingerprint=ckpt["fingerprint"], rounds=rounds,
-                framework=ckpt["framework"], n_seeds=ckpt["n_seeds"])
-            if ckpt["hook"] is not None:
-                ckpt["hook"](end)
-
-    buffers = {k: (jnp.concatenate([ys[k] for ys in ys_all], axis=0)
-                   if len(ys_all) > 1 else ys_all[0][k])
-               for k in ys_all[0]}
-    return params, buffers
+            _save_checkpoint(ckpt, end, rounds, {"params": params,
+                                                 "keys": key_arr,
+                                                 "qstate": qstate}, ys_all)
+    return params, _concat(ys_all)
 
 
 def evaluate_campaign(result: CampaignResult, cfg: DNNConfig, test_data,

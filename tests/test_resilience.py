@@ -22,7 +22,7 @@ from repro.configs.splitme_dnn import DNNConfig
 from repro.core import scenario as scen
 from repro.core.cost import SystemParams
 from repro.core.engine import RoundGuards
-from repro.launch import campaign, resilience
+from repro.launch import campaign, resilience, spans
 
 CFG = DNNConfig(name="resilience-dnn", n_features=30, n_classes=3,
                 hidden=(16, 16, 8), split_index=1)
@@ -116,10 +116,10 @@ def test_faults_campaign_guarded_one_transfer(clients):
     """The faults:p smoke: guards auto-arm, the campaign survives NaN
     poisoning / crashes / wire corruption with finite params, counts its
     skipped rounds, and still performs exactly ONE host transfer."""
-    before = campaign.HOST_TRANSFERS
+    before = spans.counts["host_transfers"]
     res = _run(clients=clients, scenario="faults:0.3", scenario_seed=1,
                rounds=8, strict_transfers=True)
-    assert campaign.HOST_TRANSFERS - before == 1
+    assert spans.counts["host_transfers"] - before == 1
     for leaf in jax.tree.leaves(res.params):
         assert np.isfinite(np.asarray(leaf)).all()
     assert res.skipped_rounds > 0
