@@ -24,8 +24,11 @@ scanned round behind a per-round ``do_eval`` mask (``lax.cond``), so
 training never leaves the device between rounds.  Rounds sharing a
 (cohort-bucket, E-bucket) shape form contiguous scan segments that share
 one compiled scan (segment lengths are bucketed too; padded rounds carry a
-``live=0`` flag and are exact no-ops).  Trained parameters are numerically
-identical to serial engine-trainer runs (tests/test_campaign.py).
+``live=0`` flag and are exact no-ops), and a compiled scan outlives its
+campaign: a later ``run_campaign`` in the process whose segment program is
+the same calls it again without tracing or lowering (``_segment_exec``).
+Trained parameters are numerically identical to serial engine-trainer runs
+(tests/test_campaign.py).
 
 Execution modes:
 
@@ -70,11 +73,12 @@ the whole population as the cohort reproduces the materialized
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -84,6 +88,7 @@ from repro.configs.splitme_dnn import DNNConfig
 from repro.core import engine, population as popn, scenario as scen
 from repro.core.cost import SystemParams, schedule_metrics
 from repro.core.engine import RoundMetrics
+from repro.kernels.common import use_interpret
 from repro.launch import spans
 
 
@@ -406,8 +411,9 @@ def run_campaign(framework: str, cfg: DNNConfig, sp: SystemParams,
         # identical to the serial trainers (masked updates are exact no-ops);
         # only SplitMe's *loss metric* differs from the seed quirk of averaging
         # over the full E_max scan.
-        spec = engine.make_spec(framework, cfg, masked_loss_metric=True,
-                                policy=policy, quant=quant, **hyper)
+        spec_kw = dict(hyper, masked_loss_metric=True)
+        spec = engine.make_spec(framework, cfg, policy=policy, quant=quant,
+                                **spec_kw)
         comm, nsel, sim, cost, energy = _schedule_system_metrics(
             spec, sched, sp)
 
@@ -489,7 +495,8 @@ def run_campaign(framework: str, cfg: DNNConfig, sp: SystemParams,
         with guard:
             params, buffers = _run_rounds_scan(
                 spec, cfg, sp, sched, _scan_data(x, y, test_data), seeds,
-                do_eval, eval_gamma, mesh, guards=guards, ckpt=ckpt)
+                do_eval, eval_gamma, mesh, guards=guards, ckpt=ckpt,
+                spec_kw=spec_kw)
         host = _host_fetch(buffers)            # THE per-campaign transfer
 
         live = host["live"] > 0
@@ -619,8 +626,168 @@ def _fused_eval(spec, cfg, data, gamma, mesh=None):
                                   data["x_test"], data["y_test"])
 
 
+# Segment scans compiled in this process, least recently used first.  A
+# later campaign whose segment program is the same calls the jitted scan it
+# already holds: no trace, no lowering, no load from the persistent cache.
+SEGMENT_CACHE_SIZE = 64
+_segments: "collections.OrderedDict[_SegmentKey, Any]" = \
+    collections.OrderedDict()
+
+
+class _SegmentKey(NamedTuple):
+    """Everything the trace of one segment scan reads, so one entry is one
+    executable.  ``code`` holds the engine functions the trace looks up
+    (strong references): rebinding one, by a reload or a test's fault,
+    misses."""
+    framework: str
+    cfg: DNNConfig
+    spec_kw: tuple        # make_spec's keywords (resolved), sorted, typed
+    eval_gamma: float
+    mesh: Any
+    guards: Optional[engine.RoundGuards]
+    with_faults: bool
+    robust: bool
+    data: tuple           # (name, shape, dtype) of each scan-data leaf
+    n_seeds: int
+    # what the trace reads of the environment: the default matmul
+    # precision and device, and whether Pallas interprets
+    settings: tuple
+    code: tuple
+    kb: int = 0
+    eb: int = 0
+    lb: int = 0
+
+
+def clear_segment_cache() -> None:
+    """Forget every compiled segment scan of this process."""
+    _segments.clear()
+
+
+def _segment_key(spec, cfg, spec_kw, data, n_seeds, eval_gamma, mesh,
+                 guards, with_faults, robust) -> _SegmentKey:
+    """The key of a campaign's segments, their (kb, eb, lb) left at 0."""
+    kw = dict(spec_kw, policy=spec.policy, quant=spec.quant)
+    return _SegmentKey(
+        framework=spec.name, cfg=cfg,
+        spec_kw=tuple(sorted((k, type(v), v) for k, v in kw.items())),
+        eval_gamma=eval_gamma, mesh=mesh, guards=guards,
+        with_faults=with_faults, robust=robust,
+        data=tuple((k, v.shape, str(v.dtype))
+                   for k, v in sorted(data.items())),
+        n_seeds=n_seeds,
+        settings=(jax.config.jax_default_matmul_precision,
+                  jax.config.jax_default_device, use_interpret()),
+        code=(engine._round_core, engine.build_round_fn,
+              engine.build_sharded_round_fn, engine.build_eval_fn))
+
+
+def _segment_exec(key: _SegmentKey):
+    """The jitted scan of ``key``: the cached one (a ``segment_hits``), or
+    a new one (a ``segment_builds``; it traces at its first call)."""
+    fn = _segments.get(key)
+    if fn is not None:
+        spans.count("segment_hits")
+        _segments.move_to_end(key)
+        return fn
+    spans.count("segment_builds")
+    fn = _segments[key] = _build_segment(key)
+    if len(_segments) > SEGMENT_CACHE_SIZE:
+        _segments.popitem(last=False)
+    return fn
+
+
+def _round_caller(spec, cfg, mesh, eb: int, guards, with_faults: bool,
+                  x, y):
+    """One seed-vmapped round over the scan row ``xr``: the gathered
+    cohort round, or the shard_map round over ``mesh``."""
+    if mesh is None:
+        raw = engine.build_round_fn(spec, cfg, x, y, e_max=max(1, eb),
+                                    jit=False, gather=True, guards=guards,
+                                    with_faults=with_faults)
+
+        def call_round(params, xr, subs, qstate):
+            if not with_faults:
+                return jax.vmap(
+                    raw, in_axes=(0, None, None, None, 0, 0))(
+                    params, xr["idx"], xr["mask"], xr["e"], subs, qstate)
+            faults = {"poison": xr["poison"], "wire_gain": xr["wire"]}
+            return jax.vmap(
+                raw, in_axes=(0, None, None, None, 0, 0, None))(
+                params, xr["idx"], xr["mask"], xr["e"], subs, qstate,
+                faults)
+        return call_round
+
+    raw = engine.build_sharded_round_fn(
+        spec, cfg, mesh, n_clients=x.shape[0], e_max=max(1, eb), jit=False,
+        guards=guards, with_faults=with_faults)
+
+    def call_round(params, xr, subs, qstate):
+        if not with_faults:
+            return jax.vmap(
+                raw, in_axes=(0, None, None, None, None, 0, 0))(
+                params, x, y, xr["mask"], xr["e"], subs, qstate)
+        faults = {"poison": xr["poison"], "wire_gain": xr["wire"]}
+        return jax.vmap(
+            raw, in_axes=(0, None, None, None, None, 0, 0, None))(
+            params, x, y, xr["mask"], xr["e"], subs, qstate, faults)
+    return call_round
+
+
+def _build_segment(key: _SegmentKey):
+    """The jitted scan of one segment shape.  It closes over parts of
+    ``key`` only: the carry, the scan rows and the data are arguments."""
+    cfg, mesh, guards, robust = key.cfg, key.mesh, key.guards, key.robust
+    spec = engine.make_spec(key.framework, cfg,
+                            **{k: v for k, _, v in key.spec_kw})
+
+    def seg(params, key_arr, qstate, xs, data):
+        # the round and eval close over the traced data, never over
+        # device arrays (see _scan_data)
+        call_round = _round_caller(spec, cfg, mesh, key.eb, guards,
+                                   key.with_faults, data["x"], data["y"])
+        eval_fn = _fused_eval(spec, cfg, data, key.eval_gamma, mesh)
+        nan_row = jnp.full((key_arr.shape[0],), jnp.nan, jnp.float32)
+
+        def body(carry, xr):
+            params, keys, qstate = carry
+            ks = jax.vmap(jax.random.split)(keys)
+            nkeys, subs = ks[:, 0], ks[:, 1]
+            out = call_round(params, xr, subs, qstate)
+            if guards is not None:
+                nparams, phase_losses, nqstate, flags = out
+            else:
+                nparams, phase_losses, nqstate = out
+                flags = None
+            live = xr["live"] > 0
+            # a crash round is lost server-side: params/EF hold, clients
+            # still advanced their RNG (they did train), losses are NaN
+            ran = (jnp.logical_and(live, xr["crash"] <= 0) if robust
+                   else live)
+            params = jax.tree.map(lambda n, o: jnp.where(ran, n, o),
+                                  nparams, params)
+            qstate = jax.tree.map(lambda n, o: jnp.where(ran, n, o),
+                                  nqstate, qstate)
+            keys = jnp.where(live, nkeys, keys)
+            loss_row = jnp.where(ran, jnp.stack(phase_losses, -1), jnp.nan)
+            if eval_fn is None:
+                acc = nan_row
+            else:
+                acc = jax.lax.cond(
+                    jnp.logical_and(xr["do_eval"] > 0, live),
+                    jax.vmap(eval_fn), lambda p: nan_row, params)
+            ys = {"loss": loss_row, "acc": acc, "live": xr["live"]}
+            if guards is not None:
+                ys["skipped"] = jnp.where(ran, flags["skipped"], 0.0)
+                ys["quorum"] = jnp.where(ran, flags["quorum"], 0.0)
+            return (params, keys, qstate), ys
+
+        return jax.lax.scan(body, (params, key_arr, qstate), xs)
+
+    return jax.jit(seg, donate_argnums=(0, 1, 2))
+
+
 def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
-                     mesh, guards=None, ckpt=None):
+                     mesh, *, spec_kw, guards=None, ckpt=None):
     """Scan all rounds on-device; returns (params, device metric buffers).
 
     The buffers carry everything that EXISTS on the device — per-round
@@ -633,7 +800,9 @@ def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
     Rounds sharing a (cohort-bucket, E-bucket) shape form contiguous scan
     segments; segment lengths are bucketed as well, padded with ``live=0``
     no-op rounds, so the number of compiled scans is bounded even for
-    adaptive-E / varying-cohort schedules.
+    adaptive-E / varying-cohort schedules.  The compiled scans outlive the
+    call (``_segment_exec``): ``spec_kw``, the keywords ``spec`` was made
+    with, keys them with everything else their trace reads.
 
     ``guards`` (engine.RoundGuards) and the schedule trace's fault
     channels arm the robust scan body: poison/wire-corruption rows become
@@ -674,94 +843,8 @@ def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
     w_arr = (np.ones((rounds, M), np.float32) if wire is None
              else np.asarray(wire, np.float32))
 
-    n_ph = len(spec.phases)
-    fns: Dict[Tuple[int, int, int], Any] = {}
-
-    def round_caller(eb: int, x, y):
-        if mesh is None:
-            raw = engine.build_round_fn(spec, cfg, x, y, e_max=max(1, eb),
-                                        jit=False, gather=True,
-                                        guards=guards,
-                                        with_faults=with_faults)
-
-            def call_round(params, xr, subs, qstate):
-                if not with_faults:
-                    return jax.vmap(
-                        raw, in_axes=(0, None, None, None, 0, 0))(
-                        params, xr["idx"], xr["mask"], xr["e"], subs,
-                        qstate)
-                faults = {"poison": xr["poison"], "wire_gain": xr["wire"]}
-                return jax.vmap(
-                    raw, in_axes=(0, None, None, None, 0, 0, None))(
-                    params, xr["idx"], xr["mask"], xr["e"], subs, qstate,
-                    faults)
-        else:
-            raw = engine.build_sharded_round_fn(
-                spec, cfg, mesh, n_clients=M, e_max=max(1, eb),
-                jit=False, guards=guards, with_faults=with_faults)
-
-            def call_round(params, xr, subs, qstate):
-                if not with_faults:
-                    return jax.vmap(
-                        raw, in_axes=(0, None, None, None, None, 0, 0))(
-                        params, x, y, xr["mask"], xr["e"], subs, qstate)
-                faults = {"poison": xr["poison"], "wire_gain": xr["wire"]}
-                return jax.vmap(
-                    raw, in_axes=(0, None, None, None, None, 0, 0, None))(
-                    params, x, y, xr["mask"], xr["e"], subs, qstate,
-                    faults)
-        return call_round
-
-    def seg_exec(kb: int, eb: int, lb: int):
-        if (kb, eb, lb) in fns:
-            return fns[kb, eb, lb]
-        spans.count("segment_builds")
-        fns[kb, eb, lb] = jax.jit(functools.partial(seg, eb),
-                                  donate_argnums=(0, 1, 2))
-        return fns[kb, eb, lb]
-
-    def seg(eb, params, key_arr, qstate, xs, data):
-        # the round and eval close over the traced data, never over
-        # device arrays (see _scan_data)
-        call_round = round_caller(eb, data["x"], data["y"])
-        eval_fn = _fused_eval(spec, cfg, data, eval_gamma, mesh)
-        nan_row = jnp.full((n_seeds,), jnp.nan, jnp.float32)
-
-        def body(carry, xr):
-            params, keys, qstate = carry
-            ks = jax.vmap(jax.random.split)(keys)
-            nkeys, subs = ks[:, 0], ks[:, 1]
-            out = call_round(params, xr, subs, qstate)
-            if guards is not None:
-                nparams, phase_losses, nqstate, flags = out
-            else:
-                nparams, phase_losses, nqstate = out
-                flags = None
-            live = xr["live"] > 0
-            # a crash round is lost server-side: params/EF hold, clients
-            # still advanced their RNG (they did train), losses are NaN
-            ran = (jnp.logical_and(live, xr["crash"] <= 0) if robust
-                   else live)
-            params = jax.tree.map(lambda n, o: jnp.where(ran, n, o),
-                                  nparams, params)
-            qstate = jax.tree.map(lambda n, o: jnp.where(ran, n, o),
-                                  nqstate, qstate)
-            keys = jnp.where(live, nkeys, keys)
-            loss_row = jnp.where(ran, jnp.stack(phase_losses, -1), jnp.nan)
-            if eval_fn is None:
-                acc = nan_row
-            else:
-                acc = jax.lax.cond(
-                    jnp.logical_and(xr["do_eval"] > 0, live),
-                    jax.vmap(eval_fn), lambda p: nan_row, params)
-            ys = {"loss": loss_row, "acc": acc, "live": xr["live"]}
-            if guards is not None:
-                ys["skipped"] = jnp.where(ran, flags["skipped"], 0.0)
-                ys["quorum"] = jnp.where(ran, flags["quorum"], 0.0)
-            return (params, keys, qstate), ys
-
-        return jax.lax.scan(body, (params, key_arr, qstate), xs)
-
+    base = _segment_key(spec, cfg, spec_kw, data, n_seeds, eval_gamma, mesh,
+                        guards, with_faults, robust)
     params, key_arr, qstate = _init_state(spec, seeds, mesh)
     ys_all = []
     start_round = 0
@@ -788,8 +871,9 @@ def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
         if start + length <= start_round:
             continue                       # restored from the checkpoint
         lb = len_of[length]
+        key = base._replace(kb=kb, eb=eb, lb=lb)
         with spans.span("segment", kb=kb, eb=eb, lb=lb, start=start,
-                        length=length, built=(kb, eb, lb) not in fns):
+                        length=length, built=key not in _segments):
             xs = {
                 "e": np.zeros(lb, np.int32),
                 "live": np.zeros(lb, np.float32),
@@ -830,7 +914,7 @@ def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
                     pz[:length] = p_arr[start:start + length]
                     wg[:length] = w_arr[start:start + length]
                     xs["poison"], xs["wire"] = pz, wg
-            (params, key_arr, qstate), ys = seg_exec(kb, eb, lb)(
+            (params, key_arr, qstate), ys = _segment_exec(key)(
                 params, key_arr, qstate, xs, data)
         ys_all.append(ys)
         end = start + length

@@ -144,7 +144,9 @@ def test_scanned_campaign_captures_no_constants(small_data, runner):
     device array closed over instead is baked into the program as a
     constant, read back to the host at lowering: on a TPU that transfer
     breaks ``strict_transfers`` (the CPU, whose arrays are host memory,
-    never shows it), so JAX's own captured-constants warning stands guard."""
+    never shows it), so JAX's own captured-constants warning stands guard.
+    The segment cache is cleared first, so the campaign's scans trace."""
+    campaign.clear_segment_cache()
     cd, test = small_data
     prev = jax.config.jax_captured_constants_warn_bytes
     jax.config.update("jax_captured_constants_warn_bytes", 1)

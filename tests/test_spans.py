@@ -3,9 +3,10 @@
 A tiny scanned campaign under ``spans.record()`` must give a span tree
 rooted at ``run_campaign``; its counters must count with recording off;
 the spans must reach a profiler trace with their attrs; and the lowered
-segment must carry the round's named scopes.
+segment must carry the round's named scopes.  Every campaign test starts
+from a cleared segment cache (``campaign.clear_segment_cache``), so its
+first campaign builds its segments.
 """
-import functools
 import glob
 
 import jax
@@ -25,6 +26,11 @@ def small_data():
     (Xtr, ytr), (Xte, yte) = oran.train_test_split(X, y)
     cd = oran.partition_non_iid(Xtr, ytr, M, samples_per_client=32, seed=0)
     return cd, (Xte, yte)
+
+
+@pytest.fixture(autouse=True)
+def cold_segment_cache():
+    campaign.clear_segment_cache()
 
 
 def _run(small_data, framework="splitme", **kw):
@@ -69,14 +75,20 @@ def test_counters_attribute_builds_and_transfers(small_data):
         for _ in range(2):
             _run(small_data)
     counted = spans.counts - before
+    roots = [s for s in recorded if s.name == "run_campaign"]
     segs = [s for s in recorded if s.name == "segment"]
-    keys = {(s.parent, s.attrs["kb"], s.attrs["eb"], s.attrs["lb"])
-            for s in segs}
+    # one build per segment shape over both campaigns: the second hits
+    keys = {(s.attrs["kb"], s.attrs["eb"], s.attrs["lb"]) for s in segs}
     assert counted["segment_builds"] == len(keys)
     assert counted["segment_builds"] == sum(s.attrs["built"] for s in segs)
+    assert counted["segment_hits"] == len(segs) - len(keys)
+    second = [s for s in segs if s.parent == roots[1].id]
+    assert second and not any(s.attrs["built"] for s in second)
+    assert [s.counts for s in second] == [{"segment_hits": 1}] * len(second)
     assert counted["host_transfers"] == counted["campaigns"] == 2
     for s in segs:
         assert s.counts.get("segment_builds", 0) == int(s.attrs["built"])
+        assert s.counts.get("segment_hits", 0) == int(not s.attrs["built"])
         if s.attrs["built"]:
             # the build traces, lowers and compiles inside the segment
             assert s.counts["trace_s"] > 0 and s.counts["rebuild_s"] > 0
@@ -121,8 +133,7 @@ def test_lowered_segment_carries_the_round_scopes(small_data, monkeypatch):
 
     def spy(fun, *a, **k):
         jitted = real_jit(fun, *a, **k)
-        if not (isinstance(fun, functools.partial)
-                and fun.func.__name__ == "seg"):
+        if getattr(fun, "__name__", None) != "seg":
             return jitted
 
         def call(*args):
