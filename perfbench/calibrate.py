@@ -16,8 +16,9 @@ of
 * ``half_cohort``: the reference put in the program's place with a
   planted fault: each round trains and averages the first half of its
   selected set only;
-* ``state_unchanged``: the program with a planted fault: every round
-  returns the parameters it was given.
+* ``state_unchanged``, ``answer_altered`` and, on a cell with a mesh,
+  ``exchange_left_out``: the program with that fault of ``faults.py``
+  planted.
 
 One JSON line per seed and variant.  The benchmark's own runs do not run
 this; it needs a TPU like they do.  ``--cpu`` reads the same on the host's
@@ -38,7 +39,8 @@ import numpy as np
 import run as harness
 
 VARIANTS = ("program", "program_highest", "reference_fp8", "half_cohort",
-            "state_unchanged")
+            "state_unchanged", "answer_altered")
+MESH_VARIANTS = VARIANTS + ("exchange_left_out",)
 
 
 def control_dtype():
@@ -102,12 +104,13 @@ def readings(system, seed: int, variants=VARIANTS, emulate=False):
     ``tpu_default_precision``."""
     import jax
     import compare
+    import faults
     import reference
     mix = system.mix
     seeds = system.next_seeds()
     kw = dict(rounds=mix["rounds"], seeds=seeds)
-    ref = reference.run_campaign(system.config, system.clients, system.test,
-                                 **kw)
+    ref = reference.run_campaign(system.kind, system.config, system.clients,
+                                 system.test, **kw)
     chip = tpu_default_precision if emulate else contextlib.nullcontext
     out = {}
     for v in variants:
@@ -117,29 +120,20 @@ def readings(system, seed: int, variants=VARIANTS, emulate=False):
         elif v == "program_highest":
             with jax.default_matmul_precision("highest"):
                 got = system.host_view(system.run(seeds, policy="reference"))
-        elif v == "state_unchanged":
-            from repro.core import engine
-            orig = engine._round_core
-
-            def held(spec, runners, params, *a, **k):
-                return (params,) + tuple(orig(spec, runners, params, *a,
-                                              **k)[1:])
-            engine._round_core = held
-            try:
-                with chip():
-                    got = system.host_view(system.run(seeds))
-            finally:
-                engine._round_core = orig
+        elif v in ("state_unchanged", "answer_altered", "exchange_left_out"):
+            with faults.planted(v), chip():
+                got = system.host_view(system.run(seeds))
         elif v == "half_cohort":
             got = reference.run_campaign(
-                system.config, system.clients, system.test, **kw,
-                train_mask=first_half)
+                system.kind, system.config, system.clients, system.test,
+                **kw, train_mask=first_half)
         else:
             got = reference.run_campaign(
-                system.config, system.clients, system.test, **kw,
-                compute_dtype=control_dtype())
+                system.kind, system.config, system.clients, system.test,
+                **kw, compute_dtype=control_dtype())
         out[v] = compare.readings(got, ref, reference.accuracy(
-            system.config, system.clients, system.test, got["params"]))
+            system.kind, system.config, system.clients, system.test,
+            got["params"]))
     return out
 
 
@@ -147,13 +141,16 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
-    ap.add_argument("--variants", nargs="+", default=list(VARIANTS))
+    ap.add_argument("--variants", nargs="+", default=None,
+                    help="default: every variant the cell can have")
     ap.add_argument("--cpu", action="store_true",
                     help="read on the CPU, the program's matmuls as a "
                          "TPU's default precision computes them")
     args = ap.parse_args(argv)
     harness.configure_jax()
     c = harness.load_cell(harness.ROOT, args.workload)
+    variants = args.variants or (MESH_VARIANTS if c["config"].get("mesh")
+                                 else VARIANTS)
     if args.cpu:
         import jax
         devices = jax.devices("cpu")
@@ -165,7 +162,7 @@ def main(argv=None) -> int:
             return 1
     for seed in args.seeds:
         system = harness.System(harness.ROOT, c, seed, devices)
-        for v, r in readings(system, seed, args.variants,
+        for v, r in readings(system, seed, variants,
                              emulate=args.cpu).items():
             print(json.dumps({"workload": args.workload, "seed": seed,
                               "variant": v, "device": devices[0].platform,

@@ -5,6 +5,11 @@ their E_t executed local steps.  Padding of the cohort to a compile bucket,
 masked steps past E_t and unselected clients are work the program may do
 but the schedule does not need, so a roofline or utilization built on these
 counts falls when the program does such work and can never pass 100%.
+
+The client and whole model's operations per sample come from the
+configuration's model kind (``perfbench/models/<kind>.py``, passed in as
+``kind``); SplitMe's inverse model and the Step-4 Grams are dense stacks
+over the kind's ``server_dims``, counted here.
 """
 from __future__ import annotations
 
@@ -16,20 +21,9 @@ KL_FLOPS_PER_ELEMENT = 16   # per element of x and y: scale, max, shift,
 # exp, sum and log of both softmaxes, then p_y * (log p_y - log p_x), sum
 
 
-def dims(model: dict) -> tuple:
-    return (model["n_features"], *model["hidden"], model["n_classes"])
-
-
-def client_dims(model: dict) -> tuple:
-    return dims(model)[:model["split_index"] + 1]
-
-
-def server_dims(model: dict) -> tuple:
-    return dims(model)[model["split_index"]:]
-
-
-def inverse_dims(model: dict) -> tuple:
-    return tuple(reversed(server_dims(model)))
+def inverse_dims(kind, model: dict) -> tuple:
+    """SplitMe's inverse model s^-1(.): classes back to the cut."""
+    return tuple(reversed(kind.sizes(model)["server_dims"]))
 
 
 def weights(d: Sequence[int]) -> int:
@@ -48,7 +42,7 @@ def backward(d: Sequence[int]) -> int:
     return 2 * weights(d) + 2 * (weights(d) - d[0] * d[1])
 
 
-def train_flops(config: dict, a: np.ndarray, E: np.ndarray,
+def train_flops(kind, config: dict, a: np.ndarray, E: np.ndarray,
                 n_seeds: int) -> float:
     """Operations of the forward and backward passes of one campaign's
     schedule: a (R, M) selected sets, E (R,) local updates, all seeds."""
@@ -58,25 +52,27 @@ def train_flops(config: dict, a: np.ndarray, E: np.ndarray,
     sel = np.asarray(a).sum(axis=1)
     steps = float(np.sum(sel * np.asarray(E)))       # sum_t |A_t| E_t
     if config["framework"] == "splitme":
-        c, i = client_dims(model), inverse_dims(model)
-        per_step = batch * (forward(c) + backward(c)
+        c_fwd = kind.forward_flops(model, "client")
+        i = inverse_dims(kind, model)
+        per_step = batch * (c_fwd + kind.backward_flops(model, "client")
                             + forward(i) + backward(i))
         # each round's targets: s^-1(Y_m) before the client phase, c(X_m)
         # of the updated client weights before the server phase
-        targets = float(sel.sum()) * n * (forward(i) + forward(c))
+        targets = float(sel.sum()) * n * (forward(i) + c_fwd)
         total = steps * per_step + targets
     else:
-        d = dims(model)
-        total = steps * batch * (forward(d) + backward(d))
+        total = steps * batch * (kind.forward_flops(model, "full")
+                                 + kind.backward_flops(model, "full"))
     return float(total) * n_seeds
 
 
-def kl_work(config: dict, a: np.ndarray, E: np.ndarray, n_seeds: int):
+def kl_work(kind, config: dict, a: np.ndarray, E: np.ndarray,
+            n_seeds: int):
     """(operations, bytes) of the mutual-KL kernel's forward calls: one per
     phase per selected client per executed step, on (batch, d_split) logits
     read twice (x and the target y) and one (batch, 1) column written."""
     batch = config["hyper"]["batch_size"]
-    d = server_dims(config["model"])[0]
+    d = kind.sizes(config["model"])["split_width"]
     item = np.dtype(config["compute_dtype"]).itemsize
     calls = 2.0 * float(np.sum(np.asarray(a).sum(axis=1) * np.asarray(E)))
     calls *= n_seeds
@@ -85,13 +81,13 @@ def kl_work(config: dict, a: np.ndarray, E: np.ndarray, n_seeds: int):
     return ops, nbytes
 
 
-def gram_work(config: dict, n_evals: int):
+def gram_work(kind, config: dict, n_evals: int):
     """(operations, bytes) of the Step-4 Gram products: per eval and
     server layer l, O_aug^T O_aug and O_aug^T Z over every client's
     samples, O_aug the layer's input with a ones column; float32 inputs."""
     fl = config["fleet"]
     rows = fl["M"] * fl["samples_per_client"]
-    s = server_dims(config["model"])
+    s = kind.sizes(config["model"])["server_dims"]
     ops = nbytes = 0.0
     for l in range(len(s) - 1):
         d_in, d_out = s[l] + 1, s[l + 1]

@@ -17,7 +17,7 @@ def read(ctx):
         return None
     a, E = trace.schedule
     fl = ctx["flops"]
-    ops, nbytes = fl.kl_work(ctx["config"], a, E,
+    ops, nbytes = fl.kl_work(ctx["kind"], ctx["config"], a, E,
                              ctx["mix"]["seeds_per_campaign"])
     least, _ = fl.roofline_seconds(ops, nbytes, ctx["peaks"])
     return 100.0 * least / seconds

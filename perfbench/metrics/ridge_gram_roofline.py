@@ -19,7 +19,7 @@ def read(ctx):
     mix = ctx["mix"]
     evals = -(-mix["rounds"] // (mix["eval_every"] or mix["rounds"]))
     fl = ctx["flops"]
-    ops, nbytes = fl.gram_work(ctx["config"],
+    ops, nbytes = fl.gram_work(ctx["kind"], ctx["config"],
                                evals * mix["seeds_per_campaign"])
     least, _ = fl.roofline_seconds(ops, nbytes, ctx["peaks"])
     return 100.0 * least / seconds

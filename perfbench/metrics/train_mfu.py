@@ -9,7 +9,7 @@ def read(ctx):
         return None
     a, E = trace.schedule
     per_campaign = ctx["flops"].train_flops(
-        ctx["config"], a, E, ctx["mix"]["seeds_per_campaign"])
+        ctx["kind"], ctx["config"], a, E, ctx["mix"]["seeds_per_campaign"])
     peak = ctx["peaks"]["bf16_tflops"] * 1e12
     return (100.0 * per_campaign * ctx["campaigns"]
             / (ctx["window_s"] * ctx["chips"] * peak))

@@ -7,11 +7,16 @@ computes what one ``run_campaign`` call returns: the per-round schedule
 (selected set A_t, local updates E_t), each round's phase losses, the
 final test accuracy, and the final parameters of every seed.
 
+The client model c(.) and FedAvg's whole model are the configuration's
+model kind's (``perfbench/models/<kind>.py``, passed in as ``kind``); the
+inverse model s^-1(.), the local SGD, the KL, the masked averaging, the
+Step-4 ridge inversion and the planner are shared by every kind.
+
 Semantics (shared with the program, by its documented contract):
 
 * initialization: ``PRNGKey(seed + offset)`` (offset 0 for SplitMe, whose
-  key splits into the client and inverse-server stacks; 1 for FedAvg), He
-  normal weights, zero biases;
+  key splits into the client and inverse-server stacks; 1 for FedAvg); the
+  inverse stack He normal weights and zero biases;
 * round t of seed s: the round key comes off ``PRNGKey(seed)`` by one split
   per round; client m of phase p takes key ``p * M + m`` of its
   ``2M``-way (``M``-way for FedAvg) split; each local step splits that key
@@ -66,11 +71,6 @@ def fleet(M: int, seed: int) -> Dict[str, np.ndarray]:
     return {"Q_C": rng.uniform(0.34e-3, 0.46e-3, M),
             "Q_S": rng.uniform(1.2e-3, 1.6e-3, M),
             "t_round": rng.uniform(50e-3, 100e-3, M)}
-
-
-def param_count(dims) -> int:
-    return sum(dims[i] * dims[i + 1] + dims[i + 1]
-               for i in range(len(dims) - 1))
 
 
 def _uplink(a, b, size):
@@ -130,20 +130,20 @@ def _objective(a, b, E, fl, size):
     return k_eps * (RHO * (r_co / B + r_cp) + (1 - RHO) * latency)
 
 
-def plan_splitme(M: int, fleet_seed: int, rounds: int, model: dict,
+def plan_splitme(M: int, fleet_seed: int, rounds: int, sizes: dict,
                  n_per_client: int, e_initial: int):
     """Alg. 1 selection plus P2's bandwidth and adaptive E (E never
-    increases), round by round.  Returns a (R, M) and E (R,)."""
+    increases), round by round, for a model of the kind's ``sizes``:
+    S_m from the split width, d_model_bits and omega from the client and
+    inverse parameter counts.  Returns a (R, M) and E (R,)."""
     fl = fleet(M, fleet_seed)
     # Alg. 1's pessimistic first estimate, from the generic payload
     t0 = float(np.max(M * (GENERIC_S_M + GENERIC_OMEGA * GENERIC_D) / B))
-    dims = (model["n_features"], *model["hidden"], model["n_classes"])
-    split = model["split_index"]
-    pc_c = param_count(dims[:split + 1])
-    pc_i = param_count(tuple(reversed(dims[split:])))
+    pc_c, pc_i = sizes["client_params"], sizes["inverse_params"]
     d_bits = 32.0 * (pc_c + pc_i)
     omega = pc_c / (pc_c + pc_i)
-    size = np.full(M, n_per_client * dims[split] * 32.0) + omega * d_bits
+    size = (np.full(M, n_per_client * sizes["split_width"] * 32.0)
+            + omega * d_bits)
     t_k = t_km1 = t0
     E = e_initial
     a_l, e_l = [], []
@@ -181,7 +181,8 @@ def plan_fixed_k(M: int, rounds: int, K: int, E: int, policy_seed: int):
 
 
 # ---------------------------------------------------------------------------
-# The model: an MLP split after layer `split_index`
+# The dense stack: SplitMe's inverse model s^-1(.), Step 4's recovered
+# server s(.), and the layers of the ``mlp`` kind (``models/mlp.py``)
 # ---------------------------------------------------------------------------
 
 def init_mlp(key, dims):
@@ -244,15 +245,16 @@ def _average(stacked, a):
                         stacked)
 
 
-def splitme_round(params, x, y1, a, E, key, hp, dt=None):
-    """One SplitMe round of one seed over all M clients (a masks A_t)."""
+def splitme_round(kind, params, x, y1, a, E, key, hp, dt=None):
+    """One SplitMe round of one seed over all M clients (a masks A_t):
+    the client model is the kind's, the inverse model a dense stack."""
     wc, wi = params
     M = x.shape[0]
     keys = jax.random.split(key, 2 * M).reshape(2, M, -1)
     tau, batch = hp["temperature"], hp["batch_size"]
 
     def client_loss(w, xb, tb):
-        return kl(mlp(w, xb, True, dt), tb, tau)
+        return kl(kind.client_forward(w, xb, dt), tb, tau)
 
     def server_loss(w, yb, tb):
         return kl(mlp(w, yb, False, dt), tb, tau)
@@ -261,7 +263,7 @@ def splitme_round(params, x, y1, a, E, key, hp, dt=None):
         tgt = mlp(wi, y1m, False, dt)                 # s^-1(Y_m), fixed
         wc_m, lc = _local_sgd(wc, xm, tgt, kc, E, client_loss, hp["lr_c"],
                               batch)
-        smashed = jax.lax.stop_gradient(mlp(wc_m, xm, True, dt))
+        smashed = jax.lax.stop_gradient(kind.client_forward(wc_m, xm, dt))
         wi_m, ls = _local_sgd(wi, y1m, smashed, ks, E, server_loss,
                               hp["lr_s"], batch)
         return wc_m, wi_m, lc, ls
@@ -272,14 +274,14 @@ def splitme_round(params, x, y1, a, E, key, hp, dt=None):
     return (_average(wc_all, a), _average(wi_all, a)), losses
 
 
-def fedavg_round(params, x, y, a, E, key, hp, dt=None):
+def fedavg_round(kind, params, x, y, a, E, key, hp, dt=None):
     """One FedAvg round of one seed over all M clients (a masks A_t)."""
     (w,) = params
     M = x.shape[0]
     keys = jax.random.split(key, M)
 
     def loss_fn(w, xb, yb):
-        return cross_entropy(mlp(w, xb, False, dt), yb)
+        return cross_entropy(kind.full_forward(w, xb, dt), yb)
 
     w_all, l = jax.vmap(
         lambda xm, ym, k: _local_sgd(w, xm, ym, k, E, loss_fn, hp["lr"],
@@ -311,16 +313,16 @@ def ridge_invert(wi, smashed, y1, gamma):
     return server
 
 
-def splitme_accuracy(params, x, y1, x_test, y_test, gamma, dt=None):
+def splitme_accuracy(kind, params, x, y1, x_test, y_test, gamma, dt=None):
     wc, wi = params
-    smashed = mlp(wc, x.reshape(-1, x.shape[-1]), True, dt)
+    smashed = kind.client_forward(wc, x.reshape((-1,) + x.shape[2:]), dt)
     server = ridge_invert(wi, smashed, y1.reshape(-1, y1.shape[-1]), gamma)
-    logits = mlp(server, mlp(wc, x_test, True, dt), False)
+    logits = mlp(server, kind.client_forward(wc, x_test, dt), False)
     return jnp.mean((jnp.argmax(logits, -1) == y_test).astype(jnp.float32))
 
 
-def fedavg_accuracy(params, x_test, y_test, dt=None):
-    logits = mlp(params[0], x_test, False, dt)
+def fedavg_accuracy(kind, params, x_test, y_test, dt=None):
+    logits = kind.full_forward(params[0], x_test, dt)
     return jnp.mean((jnp.argmax(logits, -1) == y_test).astype(jnp.float32))
 
 
@@ -328,75 +330,75 @@ def fedavg_accuracy(params, x_test, y_test, dt=None):
 # A whole campaign
 # ---------------------------------------------------------------------------
 
-def _dims(model):
-    return (model["n_features"], *model["hidden"], model["n_classes"])
-
-
-def init_params(framework: str, model: dict, seed: int):
-    dims = _dims(model)
+def init_params(kind, framework: str, model: dict, seed: int):
     if framework == "splitme":
-        split = model["split_index"]
         k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
-        return (init_mlp(k1, dims[:split + 1]),
-                init_mlp(k2, tuple(reversed(dims[split:]))))
+        inverse = tuple(reversed(kind.sizes(model)["server_dims"]))
+        return (kind.init_params(framework, model, k1),
+                init_mlp(k2, inverse))
     if framework == "fedavg":
-        return (init_mlp(jax.random.PRNGKey(seed + 1), dims),)
+        return (kind.init_params(framework, model,
+                                 jax.random.PRNGKey(seed + 1)),)
     raise KeyError(f"the reference has no framework {framework!r}")
 
 
-@functools.partial(jax.jit, static_argnames=("framework", "hp_items",
-                                             "n_classes", "dt"))
-def _round(params, a, E, keys, data, framework, hp_items, n_classes, dt):
+@functools.partial(jax.jit, static_argnames=("kind", "framework",
+                                             "hp_items", "n_classes", "dt"))
+def _round(params, a, E, keys, data, kind, framework, hp_items, n_classes,
+           dt):
     hp = dict(hp_items)
     ks = jax.vmap(jax.random.split)(keys)
     nkeys, subs = ks[:, 0], ks[:, 1]
     if framework == "splitme":
         y1 = jax.nn.one_hot(data["y"], n_classes)
-        fn = lambda p, k: splitme_round(p, data["x"], y1, a, E, k, hp, dt)
+        fn = lambda p, k: splitme_round(kind, p, data["x"], y1, a, E, k, hp,
+                                        dt)
     else:
-        fn = lambda p, k: fedavg_round(p, data["x"], data["y"], a, E, k, hp,
-                                       dt)
+        fn = lambda p, k: fedavg_round(kind, p, data["x"], data["y"], a, E,
+                                       k, hp, dt)
     params, losses = jax.vmap(fn)(params, subs)
     return params, losses, nkeys
 
 
-@functools.partial(jax.jit, static_argnames=("framework", "gamma",
+@functools.partial(jax.jit, static_argnames=("kind", "framework", "gamma",
                                              "n_classes", "dt"))
-def _accuracy(params, data, framework, gamma, n_classes, dt):
+def _accuracy(params, data, kind, framework, gamma, n_classes, dt):
     if framework == "splitme":
         y1 = jax.nn.one_hot(data["y"], n_classes)
-        fn = lambda p: splitme_accuracy(p, data["x"], y1, data["x_test"],
-                                        data["y_test"], gamma, dt)
+        fn = lambda p: splitme_accuracy(kind, p, data["x"], y1,
+                                        data["x_test"], data["y_test"], gamma,
+                                        dt)
     else:
-        fn = lambda p: fedavg_accuracy(p, data["x_test"], data["y_test"], dt)
+        fn = lambda p: fedavg_accuracy(kind, p, data["x_test"],
+                                       data["y_test"], dt)
     return jax.vmap(fn)(params)
 
 
-def accuracy(config: dict, clients, test, params) -> np.ndarray:
+def accuracy(kind, config: dict, clients, test, params) -> np.ndarray:
     """Test accuracy per seed of seed-stacked final parameters, by the
     reference's own eval (Step 4 for SplitMe), in float32 at HIGHEST."""
     data = {"x": jnp.asarray(clients["x"]), "y": jnp.asarray(clients["y"]),
             "x_test": jnp.asarray(test[0]), "y_test": jnp.asarray(test[1])}
     params = jax.tree.map(lambda p: jnp.asarray(p, jnp.float32), params)
     with jax.default_matmul_precision("highest"):
-        return np.asarray(_accuracy(params, data, config["framework"],
-                                    config["eval_gamma"],
-                                    config["model"]["n_classes"], None))
+        return np.asarray(_accuracy(
+            params, data, kind, config["framework"], config["eval_gamma"],
+            kind.sizes(config["model"])["n_classes"], None))
 
 
-def plan(config: dict, rounds: int, seeds) -> tuple:
+def plan(kind, config: dict, rounds: int, seeds) -> tuple:
     """The reference schedule of one campaign: a (R, M), E (R,)."""
     fw, hp = config["framework"], config["hyper"]
     M = config["fleet"]["M"]
     if fw == "splitme":
         return plan_splitme(M, config["fleet"]["seed"], rounds,
-                            config["model"],
+                            kind.sizes(config["model"]),
                             config["fleet"]["samples_per_client"],
                             hp["e_initial"])
     return plan_fixed_k(M, rounds, hp["K"], hp["E"], int(min(seeds)))
 
 
-def run_campaign(config: dict, clients, test, *, rounds: int, seeds,
+def run_campaign(kind, config: dict, clients, test, *, rounds: int, seeds,
                  compute_dtype=None, train_mask=None) -> dict:
     """The reference of one ``run_campaign`` call.  Returns the schedule,
     the initial and final parameters (stacked over seeds), losses
@@ -407,27 +409,27 @@ def run_campaign(config: dict, clients, test, *, rounds: int, seeds,
     train on (the schedule returned stays the planned one): a planted
     fault for the calibration."""
     fw = config["framework"]
-    a, E = plan(config, rounds, seeds)
+    a, E = plan(kind, config, rounds, seeds)
     a_train = a if train_mask is None else train_mask(a)
     hp = {k: v for k, v in config["hyper"].items()
           if k in ("lr", "lr_c", "lr_s", "temperature", "batch_size")}
     data = {"x": jnp.asarray(clients["x"]), "y": jnp.asarray(clients["y"]),
             "x_test": jnp.asarray(test[0]), "y_test": jnp.asarray(test[1])}
-    n_classes = config["model"]["n_classes"]
+    n_classes = kind.sizes(config["model"])["n_classes"]
     with jax.default_matmul_precision("highest"):
         init = jax.tree.map(
             lambda *l: jnp.stack(l),
-            *[init_params(fw, config["model"], int(s)) for s in seeds])
+            *[init_params(kind, fw, config["model"], int(s)) for s in seeds])
         params = init
         keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
         losses, acc = [], np.full((rounds, len(seeds)), np.nan)
         for t in range(rounds):
             params, l, keys = _round(
                 params, jnp.asarray(a_train[t], jnp.float32),
-                jnp.int32(E[t]), keys, data, fw, tuple(sorted(hp.items())),
-                n_classes, compute_dtype)
+                jnp.int32(E[t]), keys, data, kind, fw,
+                tuple(sorted(hp.items())), n_classes, compute_dtype)
             losses.append(l)
-        acc[-1] = np.asarray(_accuracy(params, data, fw,
+        acc[-1] = np.asarray(_accuracy(params, data, kind, fw,
                                        config["eval_gamma"], n_classes,
                                        compute_dtype))
         losses = np.stack([np.asarray(l) for l in losses], axis=1)

@@ -7,7 +7,10 @@ A cell of ``BENCHMARK.json`` names a configuration
 (``perfbench/configs/<name>.json``: framework, hyper-parameters, kernel
 policy, wire format, model, fleet, entry, chips, limits) and a traffic mix
 (``perfbench/mixes/<name>.json``: rounds, seeds per campaign, eval cadence,
-scenario).  A run:
+scenario).  The configuration's model names its kind (``"kind"``, default
+``mlp``), and ``perfbench/models/<kind>.py`` gives everything that depends
+on the model: the program's model argument, the data, and the reference's
+forward passes, sizes and operation counts.  A run:
 
 1. set-up: makes the client and test data from ``--seed`` and runs one
    warm-up campaign of the mix, which compiles every shape the window uses
@@ -77,6 +80,25 @@ def load_cell(root: Path, name: str) -> dict:
     return {"bench": bench, "cell": cell, "config": config, "mix": mix,
             "end_to_end": [m for m in bench["end_to_end"] if mine(m)],
             "per_layer": [m for m in bench["per_layer"] if mine(m)]}
+
+
+_KINDS: dict = {}
+
+
+def load_kind(root: Path, name: str):
+    """The model kind ``perfbench/models/<name>.py``, loaded once per path:
+    the reference's jitted functions take it as a static argument."""
+    path = root / "perfbench" / "models" / f"{name}.py"
+    if str(path) not in _KINDS:
+        spec = importlib.util.spec_from_file_location(f"model_{name}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _KINDS[str(path)] = mod
+    return _KINDS[str(path)]
+
+
+def kind_of(config: dict) -> str:
+    return config["model"].get("kind", "mlp")
 
 
 def load_reader(root: Path, metric: str):
@@ -169,20 +191,15 @@ class System:
     def __init__(self, root: Path, c: dict, seed: int, devices):
         sys.path.insert(0, str(root / "src"))
         sys.path.insert(0, str(root / "perfbench"))
-        import oran_data
-        from repro.configs.splitme_dnn import DNNConfig
         from repro.core.cost import SystemParams
         from repro.launch import campaign
         self.campaign = campaign
         self.config, self.mix = c["config"], c["mix"]
-        cfg, fl, m = self.config, self.config["fleet"], self.config["model"]
-        self.dnn = DNNConfig(n_features=m["n_features"],
-                             n_classes=m["n_classes"],
-                             hidden=tuple(m["hidden"]),
-                             split_index=m["split_index"],
-                             activation=m["activation"])
+        cfg, fl = self.config, self.config["fleet"]
+        self.kind = load_kind(root, kind_of(cfg))
+        self.model = self.kind.program_model(cfg["model"])
         self.sp = lambda: SystemParams(M=fl["M"], seed=fl["seed"])
-        self.clients, self.test = oran_data.make(
+        self.clients, self.test = self.kind.make_data(
             cfg["data"], fl["M"], fl["samples_per_client"], seed)
         self.data = self.clients
         self.mesh = None
@@ -219,15 +236,16 @@ class System:
         entry = self.config["entry"]
         if entry == "run_campaign":
             return self.campaign.run_campaign(
-                self.config["framework"], self.dnn, self.sp(), self.data,
+                self.config["framework"], self.model, self.sp(), self.data,
                 mesh=self.mesh, **kw)
         if entry == "run_population_campaign":
             from repro.core.population import Population
             pop = Population(**self.config["population"])
             x = self.clients["x"]
-            X, y = x.reshape(-1, x.shape[-1]), self.clients["y"].reshape(-1)
+            X = x.reshape((-1,) + x.shape[2:])
+            y = self.clients["y"].reshape(-1)
             return self.campaign.run_population_campaign(
-                self.config["framework"], self.dnn, pop, (X, y),
+                self.config["framework"], self.model, pop, (X, y),
                 cohort=self.config["cohort"],
                 samples_per_client=x.shape[1], **kw)
         raise KeyError(f"unknown entry {entry!r}")
@@ -311,10 +329,11 @@ def run(args, root: Path = ROOT, require_chip: bool = True,
     del res
     import compare
     import reference
-    ref = reference.run_campaign(system.config, system.clients, system.test,
-                                 rounds=rounds, seeds=seeds)
+    ref = reference.run_campaign(system.kind, system.config, system.clients,
+                                 system.test, rounds=rounds, seeds=seeds)
     values = compare.readings(prog, ref, reference.accuracy(
-        system.config, system.clients, system.test, prog["params"]))
+        system.kind, system.config, system.clients, system.test,
+        prog["params"]))
     correct, rows = compare.judge(values, system.config["limits"])
 
     dev0 = system.devices[0]
@@ -378,9 +397,9 @@ def device_peaks(root: Path, kind: str) -> dict:
 
 def per_layer(root, c, system, host, trace, window_s, runs_n) -> dict:
     import flops
-    ctx = {"config": system.config, "mix": system.mix, "host": host,
-           "trace": trace, "window_s": window_s, "campaigns": runs_n,
-           "chips": len(system.devices), "flops": flops,
+    ctx = {"config": system.config, "kind": system.kind, "mix": system.mix,
+           "host": host, "trace": trace, "window_s": window_s,
+           "campaigns": runs_n, "chips": len(system.devices), "flops": flops,
            "peaks": device_peaks(root, system.devices[0].device_kind)}
     out = {}
     for m in c["per_layer"]:

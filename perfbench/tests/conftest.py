@@ -6,7 +6,8 @@
 ``BENCHMARK.json`` and ``perfbench/``, the program's ``src`` beside them,
 and, added as files and entries only, a small SplitMe and FedAvg
 configuration (6 RICs x 32 samples) under a 4-round mix.  The harness runs
-there in-process with its look for a chip skipped.
+there in-process with its look for a chip skipped; ``run_on_devices`` runs
+it in a child process that sees several CPU devices, for a mesh cell.
 """
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ import argparse
 import json
 import os
 import shutil
+import subprocess
 import sys
 import time
 from pathlib import Path
@@ -51,14 +53,15 @@ def add_config(root: Path, name: str, base: str, **changes) -> None:
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
 
 
-def add_cell(root: Path, config: str, traffic: str, mix=None) -> str:
+def add_cell(root: Path, config: str, traffic: str, mix=None,
+             chips: int = 1) -> str:
     if mix is not None:
         (root / "perfbench" / "mixes" / f"{traffic}.json").write_text(
             json.dumps(mix))
     bench = json.loads((root / "BENCHMARK.json").read_text())
     name = f"{config}.{traffic}"
     bench["workloads"].append({"name": name, "config": config,
-                               "traffic": traffic, "chips": 1,
+                               "traffic": traffic, "chips": chips,
                                "why": "test"})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return name
@@ -86,3 +89,41 @@ def run_tiny(root: Path, workload: str, seed: int = 5, trace: int = 0):
                               trace=trace, keep_trace=None)
     return harness.run(args, root=root, require_chip=False,
                        t_start=time.perf_counter())
+
+
+def add_mesh_cell(root: Path) -> str:
+    """A tiny copy of the committed mesh configuration: 8 RICs over a
+    data=4 mesh, 2 per device, under the 4-round mix."""
+    add_config(root, "mesh-tiny", "splitme-dnn10-m48-mesh4",
+               fleet={"M": 8, "seed": 0,
+                      "samples_per_client": TINY["samples_per_client"]})
+    return add_cell(root, "mesh-tiny", "mini", chips=4)
+
+
+CHILD = """
+import json, sys
+from pathlib import Path
+sys.path.insert(0, {tests!r})
+import contextlib
+from conftest import run_tiny
+from faults import planted
+fault = {fault!r}
+with planted(fault) if fault else contextlib.nullcontext():
+    result = run_tiny(Path({root!r}), {workload!r}, seed={seed!r})
+print(json.dumps(result))
+"""
+
+
+def run_on_devices(root: Path, workload: str, devices: int, seed: int = 5,
+                   fault=None) -> dict:
+    """One harness run in a child process on ``devices`` CPU devices, with
+    ``fault`` (a name of ``faults.MESH_FAULTS``) planted if given."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    code = CHILD.format(tests=str(Path(__file__).resolve().parent),
+                        root=str(root), workload=workload, seed=seed,
+                        fault=fault)
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-4000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])

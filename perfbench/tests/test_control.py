@@ -14,7 +14,8 @@ import compare
 import reference
 
 
-@pytest.mark.parametrize("base", ["splitme-dnn10-m50", "fedavg-dnn10-m50"])
+@pytest.mark.parametrize("base", ["splitme-dnn10-m50", "fedavg-dnn10-m50",
+                                  "splitme-dnn10-m48-mesh4"])
 def test_control_fails_the_limits(tiny_root, base):
     import run as harness
     limits = json.loads((BENCH / "configs" / f"{base}.json").read_text())[
@@ -23,13 +24,13 @@ def test_control_fails_the_limits(tiny_root, base):
     system = harness.System(tiny_root, c, 11, jax.devices())
     seeds = system.next_seeds()
     kw = dict(rounds=4, seeds=seeds)
-    ref = reference.run_campaign(system.config, system.clients, system.test,
-                                 **kw)
-    control = reference.run_campaign(system.config, system.clients,
-                                     system.test, **kw,
+    ref = reference.run_campaign(system.kind, system.config, system.clients,
+                                 system.test, **kw)
+    control = reference.run_campaign(system.kind, system.config,
+                                     system.clients, system.test, **kw,
                                      compute_dtype=calibrate.control_dtype())
-    ref_acc = reference.accuracy(system.config, system.clients, system.test,
-                                 control["params"])
+    ref_acc = reference.accuracy(system.kind, system.config, system.clients,
+                                 system.test, control["params"])
     correct, rows = compare.judge(compare.readings(control, ref, ref_acc),
                                   limits)
     assert not correct, rows

@@ -1,6 +1,6 @@
-"""A configuration, a traffic mix and a per-layer metric added as new
-files plus new BENCHMARK.json entries run without an edit to any file the
-benchmark already has."""
+"""A configuration, a traffic mix, a per-layer metric and a model kind
+added as new files plus new BENCHMARK.json entries run without an edit to
+any file the benchmark already has."""
 import json
 
 import pytest
@@ -64,3 +64,54 @@ def test_population_entry_runs(tiny_root):
     res = system.run(system.next_seeds())
     assert res.losses.shape == (2, 4, 1)
     assert res.accuracy_per_round.shape == (4, 2)
+
+
+# A model kind of its own: the mlp kind's functions, loaded from its file
+# apart from the harness's copy, with every call counted.
+PROBE_KIND = """
+import collections
+import importlib.util
+import pathlib
+
+_spec = importlib.util.spec_from_file_location(
+    "probe_base", pathlib.Path(__file__).with_name("mlp.py"))
+_base = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_base)
+CALLS = collections.Counter()
+
+
+def _counted(name):
+    fn = getattr(_base, name)
+
+    def call(*a, **k):
+        CALLS[name] += 1
+        return fn(*a, **k)
+    return call
+
+
+for _name in ("program_model", "make_data", "init_params", "client_forward",
+              "full_forward", "sizes", "forward_flops", "backward_flops"):
+    globals()[_name] = _counted(_name)
+"""
+
+
+def test_new_model_kind_by_name(tiny_root, cpu_peaks):
+    import run as harness
+    models = tiny_root / "perfbench" / "models"
+    (models / "probe.py").write_text(PROBE_KIND)
+    add_config(tiny_root, "splitme-probe", "splitme-dnn10-m50")
+    cfg_path = tiny_root / "perfbench" / "configs" / "splitme-probe.json"
+    cfg = json.loads(cfg_path.read_text())
+    cfg["model"]["kind"] = "probe"
+    cfg_path.write_text(json.dumps(cfg))
+    name = add_cell(tiny_root, "splitme-probe", "mini", MIX)
+
+    result = run_tiny(tiny_root, name, trace=1)
+    assert result["correct"] is True, result["checks"]
+    assert result["metrics"]["train_mfu"]["value"] > 0
+    calls = harness.load_kind(tiny_root, "probe").CALLS
+    for fn in ("program_model", "make_data", "init_params", "client_forward",
+               "sizes", "forward_flops", "backward_flops"):
+        assert calls[fn] > 0, fn
+    # the harness never loaded this checkout's mlp kind
+    assert str(models / "mlp.py") not in harness._KINDS

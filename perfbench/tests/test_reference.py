@@ -19,10 +19,11 @@ def test_reference_matches_run_campaign(tiny_root, base):
     system = harness.System(tiny_root, c, 3, jax.devices())
     seeds = system.next_seeds()
     got = system.host_view(system.run(seeds, policy="reference"))
-    ref = reference.run_campaign(system.config, system.clients, system.test,
-                                 rounds=4, seeds=seeds)
+    ref = reference.run_campaign(system.kind, system.config, system.clients,
+                                 system.test, rounds=4, seeds=seeds)
     r = compare.readings(got, ref, reference.accuracy(
-        system.config, system.clients, system.test, got["params"]))
+        system.kind, system.config, system.clients, system.test,
+        got["params"]))
     assert r["schedule_mismatch"] == 0
     assert r["loss_gap"] < 1e-5
     assert r["acc_gap"] == 0.0
@@ -35,10 +36,12 @@ def test_planner_matches_at_the_papers_fleet():
     from repro.configs.splitme_dnn import DNN10
     from repro.core.cost import SystemParams
     from repro.launch import campaign
-    from conftest import BENCH
+    import run as harness
+    from conftest import BENCH, ROOT
     cfg = json.loads((BENCH / "configs" / "splitme-dnn10-m50.json")
                      .read_text())
-    a, E = reference.plan(cfg, 30, [0])
+    kind = harness.load_kind(ROOT, harness.kind_of(cfg))
+    a, E = reference.plan(kind, cfg, 30, [0])
     _, sched = campaign.plan_schedule("splitme", SystemParams(M=50, seed=0),
                                       DNN10, 30, n_samples_per_client=96)
     assert (a == sched.a).all() and (E == sched.E).all()

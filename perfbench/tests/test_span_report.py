@@ -19,8 +19,13 @@ def test_span_report_on_a_tiny_cell(tiny_root, tmp_path):
     assert rows["run_campaign"]["n"] == 1
     assert cam["counted"]["host_transfers"] == rows["host_fetch"][
         "host_transfers"] == 1
-    assert cam["counted"]["segment_builds"] == rows["segment"][
-        "segment_builds"] == rows["segment"]["n"]
+    # a window campaign finds its segment scans in the driver's cache (a
+    # hit) or builds them (a build): one or the other per segment span
+    seg = rows["segment"]
+    assert (cam["counted"].get("segment_builds", 0)
+            + cam["counted"].get("segment_hits", 0)
+            == seg.get("segment_builds", 0) + seg.get("segment_hits", 0)
+            == seg["n"])
     # every JAX compile event of the campaign falls in some program span
     assert sum(r.get("rebuild_s", 0.0) for r in rows.values()) == \
         pytest.approx(cam["harness_rebuild_s"], rel=1e-6)

@@ -5,6 +5,7 @@ from pathlib import Path
 import pytest
 
 import tracereduce
+from conftest import BENCH
 from tracereduce import Trace
 
 RECORDED = Path(__file__).resolve().parent / "data" / "splitme-small.xplane.pb"
@@ -62,3 +63,25 @@ def test_recorded_chip_trace():
     assert n_kl > 0 and n_gram > 0 and 0 < kl + gram < busy
     gaps = t.idle_gaps()
     assert gaps and all(g[1] > 0 for g in gaps)
+
+
+def test_allreduce_reader_on_hand_made_events():
+    import run as harness
+    read = harness.load_reader(BENCH.parent, "allreduce_ms_per_round")
+    ops = {"/device:TPU:0": [("fusion.1", 0, 10), ("psum.3", 10, 4e6),
+                             ("all-reduce-start.1", 20, 1e6),
+                             ("all-reduce-done.1", 30, 3e6),
+                             ("psum.3", 60, 1e6)],
+           "/device:TPU:1": [("psum.3", 10, 6e6),
+                             ("reduce_sum.2", 40, 9e6),
+                             ("multiply_reduce_fusion.4", 41, 9e6)]}
+    trace = Trace(window=(0.0, 50.0), ops=ops)
+    ctx = {"trace": trace, "mix": {"rounds": 2}}
+    # in the window: device 0 4 + 1 + 3 ms, device 1 6 ms; the psum at 60
+    # lies outside it, and a reduction on one chip is no collective
+    assert read(ctx) == pytest.approx((8 + 6) / 2 / 2)
+    # no collective, or no trace: silent, never 0
+    one = Trace(window=(0.0, 50.0), ops={"/device:TPU:0": [("fusion.1", 0,
+                                                            10)]})
+    assert read({"trace": one, "mix": {"rounds": 2}}) is None
+    assert read({"trace": None, "mix": {"rounds": 2}}) is None
