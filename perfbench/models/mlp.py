@@ -21,11 +21,17 @@ everything model-specific from it:
   layer widths, from the cut to the classes: the inverse model and Step 4
   run over them);
 * ``forward_flops(model, part)`` and ``backward_flops(model, part)``:
-  one sample's operations through ``client`` or ``full``.
+  one sample's operations through ``client`` or ``full``;
+* optionally ``client_block`` and ``sample_block``, for a model too large
+  to copy per client: how many clients a reference round trains at once
+  (then only A_t's, never the whole fleet), and how many samples a
+  client forward of the reference takes at once.  Left out, a round
+  trains all M clients at once and a forward takes all its samples.
 
 Here: an MLP ``n_features -> hidden... -> n_classes`` with ReLU between
 layers, split after ``split_index`` layers; the client keeps a ReLU after
-its last layer.
+its last layer.  It is small enough for the defaults and sets neither
+block size.
 """
 from __future__ import annotations
 

@@ -37,6 +37,15 @@ Every matmul runs in float32 at HIGHEST precision (``run_campaign`` sets
 it).  ``compute_dtype`` casts the inputs of the model's matmuls to a
 narrower type with float32 accumulation: the control the comparison must
 fail.
+
+Memory: a kind may state ``client_block`` and ``sample_block`` (both
+optional).  Without them a round trains all M clients at once, masked, and
+every client forward takes all its samples at once.  With
+``client_block`` a round trains only A_t's clients, that many at a time,
+and carries the masked sums over the blocks; with ``sample_block`` every
+client forward (the round's smashed data, Step 4, the test set) runs that
+many samples at a time.  The reference's memory then grows with the
+blocks and not with M: a model too large to copy per client still fits.
 """
 from __future__ import annotations
 
@@ -245,13 +254,58 @@ def _average(stacked, a):
                         stacked)
 
 
-def splitme_round(kind, params, x, y1, a, E, key, hp, dt=None):
-    """One SplitMe round of one seed over all M clients (a masks A_t):
-    the client model is the kind's, the inverse model a dense stack."""
+def _in_blocks(fn, x, block):
+    """``fn`` over the rows of ``x``, ``block`` rows at a time (all at once
+    where ``block`` is None): a scan over the whole blocks, then one call
+    on the rest."""
+    if block is None or block >= x.shape[0]:
+        return fn(x)
+    n = x.shape[0] // block * block
+    out = jax.lax.map(fn, x[:n].reshape((-1, block) + x.shape[1:]))
+    out = out.reshape((n,) + out.shape[2:])
+    if n < x.shape[0]:
+        out = jnp.concatenate([out, fn(x[n:])])
+    return out
+
+
+def _blocked_average(train, params, n_losses, a, sel, n_sel, block):
+    """The masked FedAvg of a round that trains only A_t's clients,
+    ``block`` at a time: ``train(m)`` gives client m's trained parameters
+    and losses.  ``sel`` lists A_t's clients in its first ``n_sel`` slots
+    and pads them to whole blocks; blocks past the last client never run,
+    and the padding of the last block is selected away, not weighted by 0,
+    so nothing of a client that was not trained reaches the sums.  Carries
+    sum a_m w_m and sum a_m loss_m and divides once, as ``_average``."""
+    ids = sel.reshape(-1, block)
+
+    def body(i, acc):
+        m = ids[i]
+        live = i * block + jnp.arange(block) < n_sel
+
+        def add(total, out):
+            mask = live.reshape((block,) + (1,) * (out.ndim - 1))
+            wa = a[m].reshape(mask.shape)
+            return total + jnp.sum(jnp.where(mask, wa * out, 0.0), 0)
+
+        return jax.tree.map(add, acc, jax.vmap(train)(m))
+
+    zeros = jax.tree.map(jnp.zeros_like, (params, jnp.zeros(n_losses)))
+    sums = jax.lax.fori_loop(0, (n_sel + block - 1) // block, body, zeros)
+    wsum = jnp.maximum(jnp.sum(a), 1.0)
+    return jax.tree.map(lambda s: s / wsum, sums)
+
+
+def splitme_round(kind, params, x, y1, a, E, key, hp, dt=None, sel=None,
+                  n_sel=None):
+    """One SplitMe round of one seed over all M clients (a masks A_t), or,
+    given ``sel`` and ``n_sel``, over A_t's alone in the kind's
+    ``client_block``: the client model is the kind's, the inverse model a
+    dense stack."""
     wc, wi = params
     M = x.shape[0]
     keys = jax.random.split(key, 2 * M).reshape(2, M, -1)
     tau, batch = hp["temperature"], hp["batch_size"]
+    sample_block = getattr(kind, "sample_block", None)
 
     def client_loss(w, xb, tb):
         return kl(kind.client_forward(w, xb, dt), tb, tau)
@@ -263,19 +317,30 @@ def splitme_round(kind, params, x, y1, a, E, key, hp, dt=None):
         tgt = mlp(wi, y1m, False, dt)                 # s^-1(Y_m), fixed
         wc_m, lc = _local_sgd(wc, xm, tgt, kc, E, client_loss, hp["lr_c"],
                               batch)
-        smashed = jax.lax.stop_gradient(kind.client_forward(wc_m, xm, dt))
+        smashed = jax.lax.stop_gradient(_in_blocks(
+            lambda xb: kind.client_forward(wc_m, xb, dt), xm, sample_block))
         wi_m, ls = _local_sgd(wi, y1m, smashed, ks, E, server_loss,
                               hp["lr_s"], batch)
         return wc_m, wi_m, lc, ls
 
+    if sel is not None:
+        def train(m):
+            wc_m, wi_m, lc, ls = per_client(x[m], y1[m], keys[0][m],
+                                            keys[1][m])
+            return (wc_m, wi_m), jnp.stack([lc, ls])
+        return _blocked_average(train, params, 2, a, sel, n_sel,
+                                kind.client_block)
     wc_all, wi_all, lc, ls = jax.vmap(per_client)(x, y1, keys[0], keys[1])
     wsum = jnp.maximum(jnp.sum(a), 1.0)
     losses = jnp.stack([jnp.sum(a * lc), jnp.sum(a * ls)]) / wsum
     return (_average(wc_all, a), _average(wi_all, a)), losses
 
 
-def fedavg_round(kind, params, x, y, a, E, key, hp, dt=None):
-    """One FedAvg round of one seed over all M clients (a masks A_t)."""
+def fedavg_round(kind, params, x, y, a, E, key, hp, dt=None, sel=None,
+                 n_sel=None):
+    """One FedAvg round of one seed over all M clients (a masks A_t), or,
+    given ``sel`` and ``n_sel``, over A_t's alone in the kind's
+    ``client_block``."""
     (w,) = params
     M = x.shape[0]
     keys = jax.random.split(key, M)
@@ -283,9 +348,17 @@ def fedavg_round(kind, params, x, y, a, E, key, hp, dt=None):
     def loss_fn(w, xb, yb):
         return cross_entropy(kind.full_forward(w, xb, dt), yb)
 
-    w_all, l = jax.vmap(
-        lambda xm, ym, k: _local_sgd(w, xm, ym, k, E, loss_fn, hp["lr"],
-                                     hp["batch_size"]))(x, y, keys)
+    def local(xm, ym, k):
+        return _local_sgd(w, xm, ym, k, E, loss_fn, hp["lr"],
+                          hp["batch_size"])
+
+    if sel is not None:
+        def train(m):
+            w_m, l_m = local(x[m], y[m], keys[m])
+            return (w_m,), l_m[None]
+        return _blocked_average(train, params, 1, a, sel, n_sel,
+                                kind.client_block)
+    w_all, l = jax.vmap(local)(x, y, keys)
     return (_average(w_all, a),), jnp.sum(a * l)[None] / jnp.maximum(
         jnp.sum(a), 1.0)
 
@@ -315,14 +388,20 @@ def ridge_invert(wi, smashed, y1, gamma):
 
 def splitme_accuracy(kind, params, x, y1, x_test, y_test, gamma, dt=None):
     wc, wi = params
-    smashed = kind.client_forward(wc, x.reshape((-1,) + x.shape[2:]), dt)
+    block = getattr(kind, "sample_block", None)
+
+    def client(xb):
+        return kind.client_forward(wc, xb, dt)
+
+    smashed = _in_blocks(client, x.reshape((-1,) + x.shape[2:]), block)
     server = ridge_invert(wi, smashed, y1.reshape(-1, y1.shape[-1]), gamma)
-    logits = mlp(server, kind.client_forward(wc, x_test, dt), False)
+    logits = mlp(server, _in_blocks(client, x_test, block), False)
     return jnp.mean((jnp.argmax(logits, -1) == y_test).astype(jnp.float32))
 
 
 def fedavg_accuracy(kind, params, x_test, y_test, dt=None):
-    logits = kind.full_forward(params[0], x_test, dt)
+    logits = _in_blocks(lambda xb: kind.full_forward(params[0], xb, dt),
+                        x_test, getattr(kind, "sample_block", None))
     return jnp.mean((jnp.argmax(logits, -1) == y_test).astype(jnp.float32))
 
 
@@ -342,22 +421,44 @@ def init_params(kind, framework: str, model: dict, seed: int):
     raise KeyError(f"the reference has no framework {framework!r}")
 
 
-@functools.partial(jax.jit, static_argnames=("kind", "framework",
-                                             "hp_items", "n_classes", "dt"))
-def _round(params, a, E, keys, data, kind, framework, hp_items, n_classes,
-           dt):
+def _round_seeds(params, a, E, keys, data, kind, framework, hp_items,
+                 n_classes, dt, sel=None, n_sel=None):
+    """One round of every seed; ``sel``/``n_sel`` as ``splitme_round``."""
     hp = dict(hp_items)
     ks = jax.vmap(jax.random.split)(keys)
     nkeys, subs = ks[:, 0], ks[:, 1]
     if framework == "splitme":
         y1 = jax.nn.one_hot(data["y"], n_classes)
         fn = lambda p, k: splitme_round(kind, p, data["x"], y1, a, E, k, hp,
-                                        dt)
+                                        dt, sel, n_sel)
     else:
         fn = lambda p, k: fedavg_round(kind, p, data["x"], data["y"], a, E,
-                                       k, hp, dt)
+                                       k, hp, dt, sel, n_sel)
     params, losses = jax.vmap(fn)(params, subs)
     return params, losses, nkeys
+
+
+_ROUND_STATIC = ("kind", "framework", "hp_items", "n_classes", "dt")
+_round = jax.jit(_round_seeds, static_argnames=_ROUND_STATIC)
+# the blocked path's round gives its input parameters' buffers to its
+# output: a round never holds two copies of a large model
+_round_blocked = jax.jit(_round_seeds, static_argnames=_ROUND_STATIC,
+                         donate_argnames="params")
+
+
+def selected(a: np.ndarray, block: int):
+    """Each round's selected clients for the blocked round: (R, P) int32
+    indices, P the largest |A_t| rounded up to a whole ``block`` (so one
+    campaign compiles one round), each row padded with its first client,
+    and (R,) the count of each row's real entries."""
+    n = (a > 0).sum(axis=1)
+    width = max(-(-int(n.max()) // block), 1) * block
+    sel = np.zeros((len(a), width), np.int32)
+    for t, row in enumerate(a):
+        idx = np.flatnonzero(row > 0)
+        if len(idx):
+            sel[t] = np.concatenate([idx, np.full(width - len(idx), idx[0])])
+    return sel, n.astype(np.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "framework", "gamma",
@@ -416,23 +517,33 @@ def run_campaign(kind, config: dict, clients, test, *, rounds: int, seeds,
     data = {"x": jnp.asarray(clients["x"]), "y": jnp.asarray(clients["y"]),
             "x_test": jnp.asarray(test[0]), "y_test": jnp.asarray(test[1])}
     n_classes = kind.sizes(config["model"])["n_classes"]
+    block = getattr(kind, "client_block", None)
+    if block is None:
+        step, blocks = _round, [{}] * rounds
+    else:
+        sel, n_sel = selected(a_train, block)
+        step = _round_blocked
+        blocks = [{"sel": jnp.asarray(s), "n_sel": jnp.int32(n)}
+                  for s, n in zip(sel, n_sel)]
     with jax.default_matmul_precision("highest"):
         init = jax.tree.map(
             lambda *l: jnp.stack(l),
             *[init_params(kind, fw, config["model"], int(s)) for s in seeds])
+        init_host = jax.device_get(init)    # before a round donates it
         params = init
         keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
         losses, acc = [], np.full((rounds, len(seeds)), np.nan)
         for t in range(rounds):
-            params, l, keys = _round(
+            params, l, keys = step(
                 params, jnp.asarray(a_train[t], jnp.float32),
                 jnp.int32(E[t]), keys, data, kind, fw,
-                tuple(sorted(hp.items())), n_classes, compute_dtype)
+                tuple(sorted(hp.items())), n_classes, compute_dtype,
+                **blocks[t])
             losses.append(l)
         acc[-1] = np.asarray(_accuracy(params, data, kind, fw,
                                        config["eval_gamma"], n_classes,
                                        compute_dtype))
         losses = np.stack([np.asarray(l) for l in losses], axis=1)
-    return {"a": a, "E": E, "init": jax.device_get(init),
+    return {"a": a, "E": E, "init": init_host,
             "params": jax.device_get(params), "losses": losses,
             "acc": acc}

@@ -115,3 +115,48 @@ def test_new_model_kind_by_name(tiny_root, cpu_peaks):
         assert calls[fn] > 0, fn
     # the harness never loaded this checkout's mlp kind
     assert str(models / "mlp.py") not in harness._KINDS
+
+
+# The probe kind, stating the reference's two block sizes, with the rows of
+# every client forward recorded.
+BLOCKED_KIND = PROBE_KIND + """
+client_block = 2
+sample_block = 5
+ROWS = []
+
+
+def client_forward(params, x, dt=None):
+    ROWS.append(x.shape[0])
+    return _base.client_forward(params, x, dt)
+"""
+
+
+def test_new_kind_with_blocks(tiny_root, monkeypatch):
+    """A kind added as a file only, with ``client_block`` and
+    ``sample_block``: the reference trains its rounds two clients at a
+    time and never runs a client forward over more than a batch."""
+    import reference
+    import run as harness
+    blocks = []
+    orig = reference._blocked_average
+
+    def spy(*a):
+        blocks.append(a[-1])
+        return orig(*a)
+
+    monkeypatch.setattr(reference, "_blocked_average", spy)
+    (tiny_root / "perfbench" / "models" / "blocked.py").write_text(
+        BLOCKED_KIND)
+    add_config(tiny_root, "splitme-blocked", "splitme-dnn10-m50")
+    cfg_path = tiny_root / "perfbench" / "configs" / "splitme-blocked.json"
+    cfg = json.loads(cfg_path.read_text())
+    cfg["model"]["kind"] = "blocked"
+    cfg_path.write_text(json.dumps(cfg))
+    name = add_cell(tiny_root, "splitme-blocked", "mini", MIX)
+
+    result = run_tiny(tiny_root, name)
+    assert result["correct"] is True, result["checks"]
+    assert blocks and set(blocks) == {2}
+    rows = harness.load_kind(tiny_root, "blocked").ROWS
+    batch = cfg["hyper"]["batch_size"]
+    assert 5 in rows and max(rows) <= batch, sorted(set(rows))
