@@ -85,7 +85,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.splitme_dnn import DNNConfig
-from repro.core import engine, population as popn, scenario as scen
+from repro.core import (allocation, engine, population as popn,
+                        scenario as scen)
 from repro.core.cost import SystemParams, schedule_metrics
 from repro.core.engine import RoundMetrics
 from repro.kernels.common import use_interpret
@@ -216,6 +217,7 @@ def plan_schedule(framework: str, sp: SystemParams, cfg: DNNConfig,
     # data-side) needs no per-round SystemParams rewrites
     dynamic = trace is not None and not trace.is_static()
     base = scen.capture_base(sp) if dynamic else None
+    steps0 = allocation.bisect_steps
     a_l, b_l, e_l = [], [], []
     for t in range(rounds):
         if dynamic:
@@ -226,8 +228,16 @@ def plan_schedule(framework: str, sp: SystemParams, cfg: DNNConfig,
         a_l.append(a), b_l.append(b), e_l.append(e)
     if dynamic:
         scen.restore_base(sp, base)
+    _count_bisect_steps(steps0)
     return sp, RoundSchedule(a=np.stack(a_l), b=np.stack(b_l),
                              E=np.asarray(e_l, np.int32), trace=trace)
+
+
+def _count_bisect_steps(since: int) -> None:
+    """Count the P2 bisection steps a plan took (``p2_bisect_steps``)."""
+    steps = allocation.bisect_steps - since
+    if steps:
+        spans.count("p2_bisect_steps", steps)
 
 
 def _bucket_cohorts(values, cap: int, max_exact: int = 8) -> Dict[int, int]:
@@ -1002,6 +1012,7 @@ def plan_population_schedule(framework: str, population: popn.Population,
         n_samples_per_client=n_samples_per_client, quant=quant)
     fold_offload = framework == "oranfed"  # make_policy folded Q_S into Q_C
     pos = np.arange(C)
+    steps0 = allocation.bisect_steps
     a_l, b_l, e_l = [], [], []
     q_c_all = np.zeros((rounds, C))
     q_s_all = np.zeros((rounds, C))
@@ -1031,6 +1042,7 @@ def plan_population_schedule(framework: str, population: popn.Population,
             a = a_real
         a_l.append(a), b_l.append(b), e_l.append(e)
         q_c_all[t], q_s_all[t], gain_all[t] = q_c, q_s, gain
+    _count_bisect_steps(steps0)
     sched = PopulationSchedule(
         ids=ids, a=np.stack(a_l), b=np.stack(b_l),
         E=np.asarray(e_l, np.int32), m_t=np.asarray(m_t, np.int64),

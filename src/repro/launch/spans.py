@@ -10,8 +10,9 @@ and what was counted while it was the innermost open span.
 ``count(name, n)`` adds to an always-on counter in ``counts``.  The
 program counts ``campaigns``, ``host_transfers``, ``segment_builds`` (a
 segment scan that missed the campaign driver's process cache: traced,
-lowered and compiled or loaded) and ``segment_hits`` (one that hit it: no
-trace; the hit share is hits / (hits + builds)).  One
+lowered and compiled or loaded), ``segment_hits`` (one that hit it: no
+trace; the hit share is hits / (hits + builds)) and ``p2_bisect_steps``
+(the bisection steps of a plan's P2 bandwidth solves).  One
 ``jax.monitoring`` listener, registered at import, counts the executables
 JAX compiles (``executables_compiled``) and loads from its persistent cache
 (``executables_loaded``); while recording it also gives the innermost open
