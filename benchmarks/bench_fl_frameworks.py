@@ -114,9 +114,8 @@ def run(fast: bool = False):
                      f"resource_cost={total_cost:.1f}"))
     # ------------------------------------------------------------------
     # Multi-seed campaign execution modes:
-    #   python-loop      : PR-1 serial engine trainers, one per seed (the
-    #                      per-round float() metric pulls included) AND the
-    #                      PR-1 vmapped runner with its per-round python loop
+    #   serial           : PR-1 serial engine trainers, one per seed (the
+    #                      per-round float() metric pulls included)
     #   scanned          : lax.scan over rounds, device-resident metric
     #                      buffers, ONE host transfer per campaign
     #   scanned+sharded  : the same scan over shard_map engine rounds
@@ -147,9 +146,7 @@ def run(fast: bool = False):
                 tr.run_round()
         serial_s = time.perf_counter() - t0
 
-        modes = {"python_loop": dict(scan=False),
-                 "scanned": dict(scan=True),
-                 "scanned_sharded": dict(scan=True, mesh=make_host_mesh())}
+        modes = {"scanned": {}, "scanned_sharded": {"mesh": make_host_mesh()}}
         mode_stats = {}
         res = None
         for mode, mkw in modes.items():
@@ -173,8 +170,6 @@ def run(fast: bool = False):
             "serial_host_transfers_per_round": 1,   # float() pull each round
             "modes": mode_stats,
             "scanned_speedup_vs_serial_python_loop": scanned_speedup,
-            "scanned_speedup_vs_vmapped_python_loop":
-                mode_stats["python_loop"]["s"] / mode_stats["scanned"]["s"],
             "final_loss_per_seed": res.losses[:, -1, 0].tolist(),
             # guard accounting (0 here — no faults scenario): surfaced so
             # the regression gate can spot a guarded-vs-unguarded mismatch

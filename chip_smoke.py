@@ -162,14 +162,13 @@ def _kernels_in_compiled_splitme(clients, test):
     from repro.core import engine
 
     spec = engine.make_spec("splitme", DNN10, masked_loss_metric=True)
-    rnd = engine.build_round_fn(spec, DNN10, jnp.asarray(clients["x"]),
-                                jnp.asarray(clients["y"]), e_max=20,
-                                gather=True)
+    rnd = engine.build_round_fn(spec, DNN10, e_max=20)
     params = spec.init_fn(jax.random.PRNGKey(0))
     cohort = 16
     round_text = rnd.lower(
-        params, jnp.arange(cohort), jnp.ones(cohort), jnp.int32(20),
-        jax.random.PRNGKey(1), engine.init_quant_state(spec, params)
+        params, jnp.asarray(clients["x"]), jnp.asarray(clients["y"]),
+        jnp.ones(cohort), jnp.int32(20), jax.random.PRNGKey(1),
+        engine.init_quant_state(spec, params), None, jnp.arange(cohort)
     ).compile().as_text()
     ev = engine.build_eval_fn(spec, DNN10, *test, client_data=clients)
     eval_text = ev.lower(params).compile().as_text()

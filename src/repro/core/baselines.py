@@ -85,8 +85,7 @@ class _FLBase:
         # CommQuant error-feedback accumulator (empty when stateless)
         self._qstate = engine.init_quant_state(self._spec, (self.params,))
         # fixed E → exact-length scan (mask is all-ones, compiled once)
-        self._round_fn = engine.build_round_fn(self._spec, cfg, self.x,
-                                               self.y, e_max=E)
+        self._round_fn = engine.build_round_fn(self._spec, cfg, e_max=E)
         # jitted test accuracy, compiled once and reused each eval round
         self._eval_fn = engine.build_eval_fn(self._spec, cfg, self.x_test,
                                              self.y_test)
@@ -102,7 +101,7 @@ class _FLBase:
             a = scen.realized_mask(a, self._trace, self._round)
         self.key, sub = jax.random.split(self.key)
         (self.params,), (loss,), self._qstate = self._round_fn(
-            (self.params,), jnp.asarray(a, jnp.float32),
+            (self.params,), self.x, self.y, jnp.asarray(a, jnp.float32),
             jnp.asarray(self.E), sub, self._qstate)
         return self._record(a, b, eval_acc,
                             float(loss) if self.interactive else loss)

@@ -2,7 +2,7 @@
 real collectives).
 
 This module used to hand-write the shard_map SplitMe round; that hot path
-now lives in ``repro.core.engine.build_sharded_round_fn`` (clients sharded
+now lives in ``repro.core.engine.build_round_fn(mesh=...)`` (clients sharded
 over the mesh ``data``/``pod`` axes, masked-FedAvg psum as the round's only
 cross-device collective).  What remains here:
 
@@ -51,9 +51,8 @@ def make_splitme_round(cfg: DNNConfig, mesh: Mesh, *, n_clients: int,
     spec = engine.make_spec("splitme", cfg, lr_c=lr_c, lr_s=lr_s,
                             temperature=temperature, batch_size=batch,
                             masked_loss_metric=True, quant=quant)
-    rf = engine.build_sharded_round_fn(spec, cfg, mesh, n_clients=n_clients,
-                                       e_max=E, jit=False,
-                                       unroll_steps=unroll_steps)
+    rf = engine.build_round_fn(spec, cfg, e_max=E, mesh=mesh, jit=False,
+                               unroll_steps=unroll_steps)
     n_shards = engine.n_client_shards(mesh)
 
     def round_fn(w_c, w_s_inv, x, y1, key):

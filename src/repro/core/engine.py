@@ -30,14 +30,13 @@ The engine owns the hot path once, for every execution mode:
 
 Execution modes over that core:
 
-* ``build_round_fn`` — single-device jitted round (optionally ``gather``
-  mode: train only a fixed-size selected cohort, numerically exact),
-* ``build_sharded_round_fn`` — the same round under ``shard_map`` with the
-  client axis sharded over the mesh ``data``/``pod`` axes.  Aggregation
-  becomes per-shard masked partial sums + one cross-client ``psum`` — the
-  paper's "one communication per round" as a real collective.  This is the
-  production pattern ``repro.core.distributed`` used to hand-write for
-  SplitMe only; that module is now a thin adapter over this builder,
+* ``build_round_fn`` — one round whose client data arrive as arguments:
+  on one device over every client of ``x`` or, given a cohort index, over
+  a fixed-size gathered cohort (numerically exact); with ``mesh=`` under
+  ``shard_map`` with the client axis sharded over the mesh ``data``/``pod``
+  axes, where aggregation becomes per-shard masked partial sums + one
+  cross-client ``psum`` — the paper's "one communication per round" as a
+  real collective (``repro.core.distributed`` is a thin adapter over it),
 * ``build_eval_fn`` — jitted, vmap-able test-set evaluation (full-model
   argmax accuracy, or SplitMe's Step-4 analytic inversion + stitched
   forward), fused into the scanned campaign via a per-round ``do_eval``
@@ -454,136 +453,6 @@ def _bound_policy(spec: FrameworkSpec,
     return bound
 
 
-def build_round_fn(spec: FrameworkSpec, cfg: DNNConfig,
-                   x: jax.Array, y: jax.Array, *, e_max: int,
-                   donate: bool = True, jit: bool = True,
-                   gather: bool = False,
-                   policy: Optional[KernelPolicy] = None,
-                   guards: Optional[RoundGuards] = None,
-                   with_faults: bool = False):
-    """Compile one federated round for `spec` over the fixed client dataset.
-
-    Returns ``round_fn(params_tuple, a_mask, e_steps, key, qstate) ->
-    (params_tuple, per_phase_losses, qstate)``.  ``qstate`` is the
-    ``CommQuant`` error-feedback accumulator (``init_quant_state``; the
-    empty tuple whenever the spec's wire format carries no state — thread
-    it through unconditionally).  ``e_max`` is the static scan
-    length; ``e_steps`` (traced) masks the tail, so frameworks with adaptive
-    E compile once with ``e_max = sp.E_max`` while fixed-E frameworks pass
-    ``e_max = E`` for an exact-length scan.  With ``jit=False`` the pure
-    function is returned for embedding in a larger program (the campaign
-    runner's whole-training scan).
-
-    ``gather=True`` changes the signature to ``round_fn(params, sel_idx,
-    sel_mask, e_steps, key, qstate)``: only the gathered client cohort
-    ``sel_idx``
-    (a fixed-size, possibly padded index vector; pads carry mask 0) is
-    trained.  This is numerically EXACT relative to the full masked round —
-    unselected clients contribute nothing to the masked aggregation or the
-    loss, and the RNG streams are the full per-client split gathered by
-    index — but skips their computation entirely.  The serial trainers keep
-    the full-M round (a varying cohort size would recompile every round);
-    the campaign runner knows the whole schedule up front and exploits it.
-
-    The kernel/precision policy is the one BOUND into the spec at
-    ``make_spec`` time (``policy`` may restate it, but a different value
-    raises — the phase losses already captured the bound policy).  The
-    engine-owned application here: under a mixed-precision policy the
-    CLIENT DATASET is cast to the compute dtype once per campaign, instead
-    of once per batch inside the loss (halves the x-gather traffic of
-    every local step).
-
-    ``guards`` (a ``RoundGuards``) arms the in-scan fault guards; the
-    returned function then yields ``(params, losses, qstate, flags)`` —
-    see ``_round_core``.  ``with_faults=True`` appends a trailing
-    ``faults`` argument (dict of per-cohort fault-channel slices) for the
-    fault-injection scenarios.  Both default off, leaving the signature,
-    numerics and compiled program untouched.
-    """
-    pol = _bound_policy(spec, policy)
-    if pol.precision.is_mixed:
-        x = x.astype(pol.precision.compute_dtype)
-    M, n = x.shape[0], x.shape[1]
-    y1 = jax.nn.one_hot(y, cfg.n_classes)
-    ctx = {"x": x, "y": y, "y1": y1}
-    runners = [_phase_runner(ph, n, spec.batch_size, e_max)
-               for ph in spec.phases]
-    n_ph = len(spec.phases)
-
-    if gather:
-        def round_fn(params: ParamsTuple, sel_idx, sel_mask, e_steps, key,
-                     qstate=(), faults=None):
-            # full per-client key split, gathered: stream m is the same
-            # whether or not the other clients are computed
-            keys = jax.random.split(key, n_ph * M).reshape(
-                n_ph, M, -1)[:, sel_idx]
-            qkey = _quant_key(spec, key)
-            ctx_c = {k: v[sel_idx] for k, v in ctx.items()}
-            return _round_core(spec, runners, params, ctx_c, sel_mask,
-                               e_steps, keys, qstate, qkey,
-                               faults=faults if with_faults else None,
-                               guards=guards)
-        donate_args = (0, 5)
-    else:
-        def round_fn(params: ParamsTuple, a_mask, e_steps, key, qstate=(),
-                     faults=None):
-            keys = jax.random.split(key, n_ph * M).reshape(n_ph, M, -1)
-            qkey = _quant_key(spec, key)
-            return _round_core(spec, runners, params, ctx, a_mask, e_steps,
-                               keys, qstate, qkey,
-                               faults=faults if with_faults else None,
-                               guards=guards)
-        donate_args = (0, 4)
-
-    if not jit:
-        return round_fn
-    return jax.jit(round_fn, donate_argnums=donate_args if donate else ())
-
-
-def build_cohort_round_fn(spec: FrameworkSpec, cfg: DNNConfig, *,
-                          e_max: int, donate: bool = True, jit: bool = True,
-                          policy: Optional[KernelPolicy] = None,
-                          guards: Optional[RoundGuards] = None):
-    """Compile one federated round whose client DATA ARRIVE AS ARGUMENTS —
-    the population-mode round (``repro.core.population``), where the
-    cohort changes every round so no fixed dataset can be closed over.
-
-    Returns ``round_fn(params_tuple, xc, yc, a_mask, e_steps, key, qstate)
-    -> (params_tuple, per_phase_losses, qstate)`` with ``xc`` a ``(C, n,
-    d)`` cohort batch, ``yc`` ``(C, n)`` labels and ``a_mask`` the ``(C,)``
-    selection mask over cohort POSITIONS.  Numerically this is exactly
-    ``build_round_fn(gather=False)`` over the same ``(C, n)`` data: the
-    per-position RNG streams are the identical ``n_phases × C`` split of
-    the round key, the masked aggregation and the quantize-before-psum
-    point are the shared ``_round_core``.  When the cohort IS the whole
-    population in id order, position == client id and the round reproduces
-    the materialized campaign bit-for-bit (the population parity test pins
-    this through whole campaigns).
-
-    ``guards`` arms the same in-scan protections as ``build_round_fn``
-    (the return grows the ``flags`` element); fault-channel injection is
-    materialized-only — population traces carry no fault channels."""
-    pol = _bound_policy(spec, policy)
-    n_ph = len(spec.phases)
-
-    def round_fn(params: ParamsTuple, xc, yc, a_mask, e_steps, key,
-                 qstate=()):
-        if pol.precision.is_mixed:
-            xc = xc.astype(pol.precision.compute_dtype)
-        C, n = xc.shape[0], xc.shape[1]
-        runners = [_phase_runner(ph, n, spec.batch_size, e_max)
-                   for ph in spec.phases]
-        ctx_c = {"x": xc, "y": yc, "y1": jax.nn.one_hot(yc, cfg.n_classes)}
-        keys = jax.random.split(key, n_ph * C).reshape(n_ph, C, -1)
-        qkey = _quant_key(spec, key)
-        return _round_core(spec, runners, params, ctx_c, a_mask, e_steps,
-                           keys, qstate, qkey, guards=guards)
-
-    if not jit:
-        return round_fn
-    return jax.jit(round_fn, donate_argnums=(0, 6) if donate else ())
-
-
 def _quant_key(spec: FrameworkSpec, key):
     """Quantization RNG stream, derived by fold_in so the per-client split
     chain (and hence quant=none numerics) is untouched.  The trailing
@@ -594,113 +463,167 @@ def _quant_key(spec: FrameworkSpec, key):
     return jax.random.fold_in(jax.random.fold_in(key, _QSALT), 0)
 
 
-def build_sharded_round_fn(spec: FrameworkSpec, cfg: DNNConfig, mesh, *,
-                           n_clients: int, e_max: int, donate: bool = True,
-                           jit: bool = True, unroll_steps: bool = False,
-                           policy: Optional[KernelPolicy] = None,
-                           guards: Optional[RoundGuards] = None,
-                           with_faults: bool = False):
-    """Compile one federated round for `spec` with the CLIENT AXIS SHARDED
-    over the mesh ``data``/``pod`` axes via ``shard_map``.
+def build_round_fn(spec: FrameworkSpec, cfg: DNNConfig, *, e_max: int,
+                   mesh=None, guards: Optional[RoundGuards] = None,
+                   with_faults: bool = False, unroll_steps: bool = False,
+                   donate: bool = True, jit: bool = True,
+                   policy: Optional[KernelPolicy] = None):
+    """Compile one federated round for `spec`.
 
-    Returns ``round_fn(params_tuple, x, y, a_mask, e_steps, key, qstate)
-    -> (params_tuple, per_phase_losses, qstate)``.  ``qstate`` is the
-    per-shard ``CommQuant`` error-feedback accumulator
-    (``init_quant_state(spec, params, n_shards=...)`` — each shard
-    quantizes its own partial sums, so each keeps its own residual; the
-    empty tuple for stateless wire formats).  Unlike ``build_round_fn`` the
-    client dataset is an argument (shard it once with
-    ``NamedSharding(mesh, P(client_axes(mesh)))`` and every round reuses the
-    placement).  Each device trains only its M/|shards| client slab; the
-    ONLY cross-device communication is the masked-FedAvg ``psum`` of the
-    per-shard (weighted params, mask count, losses) partial sums — the
-    paper's "one communication per round" as a real collective, exactly the
-    pattern ``core/distributed.py`` used to hand-write for SplitMe.
+    Returns ``round_fn(params_tuple, x, y, a_mask, e_steps, key, qstate=(),
+    faults=None, idx=None) -> (params_tuple, per_phase_losses, qstate)``.
+    The client data arrive as arguments in every mode — ``x`` ``(M, n,
+    d)``, ``y`` ``(M, n)`` — so one round serves a fixed fleet, a
+    population cohort that changes every round and a sharded fleet.
+    ``qstate`` is the ``CommQuant`` error-feedback accumulator
+    (``init_quant_state``; the empty tuple whenever the spec's wire format
+    carries no state — thread it through unconditionally).  ``e_max`` is
+    the static scan length; ``e_steps`` (traced) masks the tail, so
+    frameworks with adaptive E compile once with ``e_max = sp.E_max`` while
+    fixed-E frameworks pass ``e_max = E`` for an exact-length scan.  With
+    ``jit=False`` the pure function is returned for embedding in a larger
+    program (the campaign runner's whole-training scan).
 
-    The RNG is the full ``n_phases × M`` per-client split computed from the
-    round key *before* shard_map, sharded alongside the data, so every
-    client sees the identical stream as the single-device round: results
-    match ``build_round_fn`` to fp-reassociation error (pinned at 1e-5 by
+    ``idx`` (one device only) trains only the clients of ``x`` it indexes:
+    a fixed-size, possibly padded index vector (pads carry mask 0), and
+    ``a_mask`` / ``faults`` then index its positions.  This is numerically
+    EXACT relative to the full masked round — unselected clients contribute
+    nothing to the masked aggregation or the loss, and the RNG streams are
+    the full ``n_phases × M`` per-client split gathered by index — but
+    skips their computation entirely.  Without ``idx`` every row of ``x``
+    trains.
+
+    ``mesh`` shards the CLIENT AXIS over the mesh ``data``/``pod`` axes via
+    ``shard_map``: place ``x``/``y`` once with ``NamedSharding(mesh,
+    P(client_axes(mesh)))`` and every round reuses the placement.  Each
+    device trains only its M/|shards| client slab; the ONLY cross-device
+    communication is the masked-FedAvg ``psum`` of the per-shard (weighted
+    params, mask count, losses) partial sums — the paper's "one
+    communication per round" as a real collective.  ``qstate`` is then
+    per shard (``init_quant_state(spec, params, n_shards=...)`` — each
+    shard quantizes its own partial sums, so each keeps its own residual).
+    The RNG is the same full per-client split, computed before shard_map
+    and sharded alongside the data, so results match the single-device
+    round to fp-reassociation error (pinned at 1e-5 by
     tests/test_engine_parity.py, including a multi-device CPU case).
 
-    ``unroll_steps`` python-unrolls the local-SGD loop for the fl_dryrun
-    collective accounting (per-step collectives — none for the engine's
-    frameworks — would appear E times in the lowered HLO).
+    The kernel/precision policy is the one BOUND into the spec at
+    ``make_spec`` time (``policy`` may restate it, but a different value
+    raises — the phase losses already captured the bound policy).  The
+    engine-owned application here: under a mixed-precision policy the
+    client data are cast to the compute dtype once per round, over the
+    full ``x`` before any gather or shard_map, instead of once per batch
+    inside the loss (halves the x-gather traffic of every local step).
 
-    The kernel/precision policy rides on the spec (``policy`` may only
-    restate it; a mismatch raises): the phase losses inside the shard_map
-    body already dispatch per the spec-bound policy, and under a
-    mixed-precision policy each device's client-data slab is cast to the
-    compute dtype before the shard_map so the cast is sharded too.
+    ``guards`` (a ``RoundGuards``) arms the in-scan fault guards; the
+    returned function then yields ``(params, losses, qstate, flags)`` —
+    see ``_round_core``.  ``with_faults=True`` injects the ``faults`` dict
+    (per-client fault-channel slices).  Both default off, leaving the
+    numerics and compiled program untouched.  ``unroll_steps``
+    python-unrolls the local-SGD loop for the fl_dryrun collective
+    accounting (per-step collectives — none for the engine's frameworks —
+    would appear E times in the lowered HLO).
     """
-    from jax.sharding import PartitionSpec as P
-
     pol = _bound_policy(spec, policy)
-    axes = client_axes(mesh)
-    axis_sizes = [int(mesh.shape[a]) for a in axes]
-    n_shards = n_client_shards(mesh)
-    M = n_clients
-    if M % n_shards:
-        raise ValueError(f"n_clients={M} not divisible by the "
-                         f"{n_shards} client shards of mesh axes {axes}")
     n_ph = len(spec.phases)
 
-    def shard_index():
-        idx = jax.lax.axis_index(axes[0])
-        for a, size in zip(axes[1:], axis_sizes[1:]):
-            idx = idx * size + jax.lax.axis_index(a)
-        return idx
+    def runners(n):
+        return [_phase_runner(ph, n, spec.batch_size, e_max, unroll_steps)
+                for ph in spec.phases]
 
-    guarded = guards is not None or with_faults
-
-    def local_round(params, x_s, y_s, a_s, e_steps, keys_s, qstate_s, qkey,
-                    faults_s=None):
-        n = x_s.shape[1]
-        runners = [_phase_runner(ph, n, spec.batch_size, e_max, unroll_steps)
-                   for ph in spec.phases]
-        ctx_c = {"x": x_s, "y": y_s, "y1": jax.nn.one_hot(y_s, cfg.n_classes)}
-        # strip the shard axis from this shard's EF block; each shard draws
-        # its own quantization stream (fold_in by shard index)
-        qstate = jax.tree.map(lambda l: l[0], qstate_s)
-        if spec.quant.stochastic:
-            qkey = jax.random.fold_in(qkey, shard_index())
-        out = _round_core(
-            spec, runners, params, ctx_c, a_s, e_steps, keys_s, qstate,
-            qkey, axis_names=axes,
-            faults=faults_s if with_faults else None, guards=guards)
-        new_params, losses, qstate = out[:3]
-        qstate = jax.tree.map(lambda l: l[None], qstate)
-        if guards is not None:
-            # flags derive from post-psum values, so every shard returns
-            # the identical (replicated) decision
-            return new_params, losses, qstate, out[3]
-        return new_params, losses, qstate
-
-    c_spec = P(axes)
-    in_specs = (P(), c_spec, c_spec, c_spec, P(), P(None, axes), c_spec, P())
-    out_specs = (P(), P(), c_spec)
-    if guarded:
-        in_specs = in_specs + (c_spec,)       # faults dict (per-client)
-    if guards is not None:
-        out_specs = out_specs + (P(),)        # flags (replicated scalars)
-    sharded = jax.shard_map(local_round, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=False)
-
-    ones_faults = {"poison": jnp.zeros((M,), jnp.float32),
-                   "wire_gain": jnp.ones((M,), jnp.float32)}
-
-    def round_fn(params: ParamsTuple, x, y, a_mask, e_steps, key, qstate=(),
-                 faults=None):
+    def cast(x):
         if pol.precision.is_mixed:
-            x = x.astype(pol.precision.compute_dtype)
-        keys = jax.random.split(key, n_ph * M).reshape(n_ph, M, -1)
-        # the fold_in is dead (DCE'd) unless the spec's wire format is
-        # stochastic; passing it unconditionally keeps one shard_map arity
-        qkey = jax.random.fold_in(key, _QSALT)
-        if not guarded:
-            return sharded(params, x, y, a_mask, e_steps, keys, qstate, qkey)
-        return sharded(params, x, y, a_mask, e_steps, keys, qstate, qkey,
-                       faults if faults is not None else ones_faults)
+            return x.astype(pol.precision.compute_dtype)
+        return x
+
+    def context(x, y):
+        return {"x": x, "y": y, "y1": jax.nn.one_hot(y, cfg.n_classes)}
+
+    def client_keys(key, m):
+        return jax.random.split(key, n_ph * m).reshape(n_ph, m, -1)
+
+    if mesh is None:
+        def round_fn(params: ParamsTuple, x, y, a_mask, e_steps, key,
+                     qstate=(), faults=None, idx=None):
+            x = cast(x)
+            ctx = context(x, y)
+            keys = client_keys(key, x.shape[0])
+            if idx is not None:
+                # the full per-client key split, gathered: stream m is the
+                # same whether or not the other clients are computed
+                keys = keys[:, idx]
+                ctx = {k: v[idx] for k, v in ctx.items()}
+            return _round_core(spec, runners(x.shape[1]), params, ctx,
+                               a_mask, e_steps, keys, qstate,
+                               _quant_key(spec, key),
+                               faults=faults if with_faults else None,
+                               guards=guards)
+    else:
+        from jax.sharding import PartitionSpec as P
+
+        axes = client_axes(mesh)
+        axis_sizes = [int(mesh.shape[a]) for a in axes]
+        n_shards = n_client_shards(mesh)
+        guarded = guards is not None or with_faults
+
+        def shard_index():
+            i = jax.lax.axis_index(axes[0])
+            for a, size in zip(axes[1:], axis_sizes[1:]):
+                i = i * size + jax.lax.axis_index(a)
+            return i
+
+        def local_round(params, x_s, y_s, a_s, e_steps, keys_s, qstate_s,
+                        qkey, faults_s=None):
+            ctx_c = context(x_s, y_s)
+            # strip the shard axis from this shard's EF block; each shard
+            # draws its own quantization stream (fold_in by shard index)
+            qstate = jax.tree.map(lambda l: l[0], qstate_s)
+            if spec.quant.stochastic:
+                qkey = jax.random.fold_in(qkey, shard_index())
+            out = _round_core(
+                spec, runners(x_s.shape[1]), params, ctx_c, a_s, e_steps,
+                keys_s, qstate, qkey, axis_names=axes,
+                faults=faults_s if with_faults else None, guards=guards)
+            # guard flags derive from post-psum values, so every shard
+            # returns the identical (replicated) decision
+            return (out[0], out[1], jax.tree.map(lambda l: l[None], out[2])
+                    ) + tuple(out[3:])
+
+        c_spec = P(axes)
+        in_specs = (P(), c_spec, c_spec, c_spec, P(), P(None, axes), c_spec,
+                    P())
+        out_specs = (P(), P(), c_spec)
+        if guarded:
+            in_specs = in_specs + (c_spec,)       # faults dict (per-client)
+        if guards is not None:
+            out_specs = out_specs + (P(),)        # flags (replicated scalars)
+        sharded = jax.shard_map(local_round, mesh=mesh, in_specs=in_specs,
+                                out_specs=out_specs, check_vma=False)
+
+        def round_fn(params: ParamsTuple, x, y, a_mask, e_steps, key,
+                     qstate=(), faults=None, idx=None):
+            M = x.shape[0]
+            if idx is not None:
+                raise ValueError("a sharded round trains its whole client "
+                                 "axis; it takes no cohort index")
+            if M % n_shards:
+                raise ValueError(f"{M} clients not divisible by the "
+                                 f"{n_shards} client shards of mesh axes "
+                                 f"{axes}")
+            x = cast(x)
+            keys = client_keys(key, M)
+            # the fold_in is dead (DCE'd) unless the spec's wire format is
+            # stochastic; passing it unconditionally keeps one shard_map
+            # arity
+            qkey = jax.random.fold_in(key, _QSALT)
+            if not guarded:
+                return sharded(params, x, y, a_mask, e_steps, keys, qstate,
+                               qkey)
+            if faults is None:
+                faults = {"poison": jnp.zeros((M,), jnp.float32),
+                          "wire_gain": jnp.ones((M,), jnp.float32)}
+            return sharded(params, x, y, a_mask, e_steps, keys, qstate, qkey,
+                           faults)
 
     if not jit:
         return round_fn

@@ -86,7 +86,7 @@ class SplitMeTrainer:
         self._qstate = engine.init_quant_state(self._spec,
                                                (self.w_c, self.w_s_inv))
         self._round_fn = engine.build_round_fn(
-            self._spec, cfg, self.x, self.y, e_max=self.sp.E_max)
+            self._spec, cfg, e_max=self.sp.E_max)
         # jitted Step-4-inversion + stitched-forward accuracy (one compile,
         # reused on every eval round instead of an eager per-call inversion)
         self._eval_fn = engine.build_eval_fn(
@@ -97,7 +97,8 @@ class SplitMeTrainer:
     def _jit_round(self, w_c, w_s_inv, a_mask, e_steps, key):
         """Seed-compatible signature over the engine round (steps 3-5)."""
         (w_c, w_s_inv), (closs, sloss), self._qstate = self._round_fn(
-            (w_c, w_s_inv), a_mask, e_steps, key, self._qstate)
+            (w_c, w_s_inv), self.x, self.y, a_mask, e_steps, key,
+            self._qstate)
         return w_c, w_s_inv, closs, sloss
 
     # ------------------------------------------------------------------

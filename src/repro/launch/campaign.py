@@ -17,11 +17,12 @@ host-side once (`plan_schedule`) and shared by all seeds, exactly matching
 what each serial trainer would have done.  Knowing the schedule up front
 buys exact optimizations the serial trainers cannot apply (a varying cohort
 would recompile every round): each round gathers only its selected client
-cohort (engine ``gather`` mode) and scans exactly E_t local steps, skipping
-unselected clients and the frozen scan tail entirely; the precomputed
-A_t/b_t/E_t arrays become scan operands; and evaluation is fused into the
-scanned round behind a per-round ``do_eval`` mask (``lax.cond``), so
-training never leaves the device between rounds.  Rounds sharing a
+cohort (the engine round's cohort index) and scans exactly E_t local
+steps, skipping unselected clients and the frozen scan tail entirely; the
+precomputed A_t/b_t/E_t arrays become scan operands; and evaluation is
+fused into the scanned round behind a per-round ``do_eval`` mask
+(``lax.cond``), so training never leaves the device between rounds.
+Rounds sharing a
 (cohort-bucket, E-bucket) shape form contiguous scan segments that share
 one compiled scan (segment lengths are bucketed too; padded rounds carry a
 ``live=0`` flag and are exact no-ops), and a compiled scan outlives its
@@ -30,15 +31,18 @@ the same calls it again without tracing or lowering (``_segment_exec``).
 Trained parameters are numerically identical to serial engine-trainer runs
 (tests/test_campaign.py).
 
-Execution modes:
+Execution modes — one round builder (``engine.build_round_fn``) and one
+scanned segment path (``_run_rounds_scan`` over ``_build_segment``), whose
+scan rows come in three kinds:
 
-* ``scan=True`` (default) — the scanned campaign described above.
-* ``scan=False`` — the legacy per-round python loop (one dispatch and,
-  eventually, one host transfer per round); kept as the benchmark baseline.
-* ``mesh=...`` — rounds run through ``engine.build_sharded_round_fn``:
-  clients shard over the mesh ``data``/``pod`` axes and the masked-FedAvg
-  psum is the round's only collective, while seeds stay vmapped and rounds
-  stay scanned (scan-over-shard_map-over-vmap).
+* a gathered cohort of the fleet (the default): each round's cohort
+  index, mask and fault channels;
+* ``mesh=...`` — the full mask over the fleet: clients shard over the mesh
+  ``data``/``pod`` axes and the masked-FedAvg psum is the round's only
+  collective, while seeds stay vmapped and rounds stay scanned
+  (scan-over-shard_map-over-vmap);
+* a population cohort (``run_population_campaign``): each round's own
+  cohort data and mask.
 
 Multi-config campaigns: ``run_config_sweep`` vmaps over SystemParams
 variants sharing one (rounds, M) schedule shape — one compiled scan trains
@@ -67,15 +71,15 @@ trains against a parameterized ``Population`` of up to millions of
 virtual clients with O(cohort) memory — per-round cohorts are sampled
 from the scenario seed, their SystemParams rows / trace channels / data
 shards generated lazily for the sampled ids only, and the scan's operands
-are cohort-shaped (the checkpoint carry stays O(cohort) too).  Sampling
-the whole population as the cohort reproduces the materialized
-``run_campaign`` exactly (test-pinned at 1e-5).
+are cohort-shaped rows of the same segment path (the checkpoint carry
+stays O(cohort) too).  Sampling the whole population as the cohort
+reproduces the materialized ``run_campaign`` exactly (test-pinned at
+1e-5).
 """
 from __future__ import annotations
 
 import collections
 import contextlib
-import functools
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -96,8 +100,7 @@ from repro.launch import spans
 def _host_fetch(tree):
     """The single device→host transfer point for campaign metrics.  Every
     metrics pull in this module comes here, so the counter
-    ``spans.counts["host_transfers"]`` counts them (scanned campaign:
-    exactly 1; python loop: 1 per round)."""
+    ``spans.counts["host_transfers"]`` counts them (one per campaign)."""
     with spans.span("host_fetch"):
         spans.count("host_transfers")
         return jax.device_get(tree)
@@ -328,8 +331,8 @@ def run_campaign(framework: str, cfg: DNNConfig, sp: SystemParams,
                  client_data: Dict[str, np.ndarray], *, rounds: int,
                  seeds: Sequence[int], test_data=None,
                  K: int = 10, E: int = 10, e_initial: int = 20,
-                 policy_seed: Optional[int] = None, scan: bool = True,
-                 mesh=None, eval_every: Optional[int] = None,
+                 policy_seed: Optional[int] = None, mesh=None,
+                 eval_every: Optional[int] = None,
                  eval_gamma: float = 1e-3, strict_transfers: bool = False,
                  policy=None, quant=None,
                  scenario: scen.ScenarioLike = None,
@@ -349,16 +352,16 @@ def run_campaign(framework: str, cfg: DNNConfig, sp: SystemParams,
     to the framework spec factory (lr / lr_c / lr_s / temperature /
     batch_size).
 
-    ``scan=True`` runs the whole campaign on-device (see module docstring):
-    one host transfer for all per-round metrics, evaluation fused behind a
+    The whole campaign runs on-device (see module docstring): one host
+    transfer for all per-round metrics, evaluation fused behind a
     ``do_eval`` mask on the final round (plus every ``eval_every`` rounds).
-    ``scan=False`` is the legacy per-round python loop.  ``mesh`` switches
-    the round bodies to the shard_map engine round (clients sharded over
-    the mesh data axes).  ``strict_transfers=True`` wraps the device phase
-    in ``jax.transfer_guard_device_to_host("disallow")``, turning any
-    stray per-round pull into a hard error (used by the transfer-counting
-    test).  ``policy`` (None / ``"reference"`` / ``"kernel"`` /
-    ``"kernel_bf16"`` / a ``repro.kernels.dispatch.KernelPolicy``) selects
+    ``mesh`` switches the round bodies to the shard_map engine round
+    (clients sharded over the mesh data axes).  ``strict_transfers=True``
+    wraps the device phase in ``jax.transfer_guard_device_to_host(
+    "disallow")``, turning any stray per-round pull into a hard error (used
+    by the transfer-counting test).  ``policy`` (None / ``"reference"`` /
+    ``"kernel"`` / ``"kernel_bf16"`` / a
+    ``repro.kernels.dispatch.KernelPolicy``) selects
     the kernel dispatch + precision for every round AND the fused eval, so
     the whole scanned campaign runs kernelized end-to-end.
 
@@ -424,114 +427,133 @@ def run_campaign(framework: str, cfg: DNNConfig, sp: SystemParams,
         spec_kw = dict(hyper, masked_loss_metric=True)
         spec = engine.make_spec(framework, cfg, policy=policy, quant=quant,
                                 **spec_kw)
-        comm, nsel, sim, cost, energy = _schedule_system_metrics(
-            spec, sched, sp)
-
-        trace = sched.trace
-        has_faults = trace is not None and trace.has_faults()
-        if guards is None and has_faults:
-            guards = engine.RoundGuards()       # faults auto-arm the defaults
-        elif guards is False or guards is None:
-            guards = None
-        if checkpoint_every or checkpoint_dir or resume:
-            if not (checkpoint_every and checkpoint_dir is not None):
-                raise ValueError("checkpointing needs BOTH checkpoint_every "
-                                 "and checkpoint_dir (resume implies both)")
-            if not scan:
-                raise ValueError("checkpoint/resume requires scan=True (the "
-                                 "python loop has no segment boundaries)")
-            if strict_transfers:
-                raise ValueError("checkpoint_every is incompatible with "
-                                 "strict_transfers: each segment save is an "
-                                 "explicit device→host pull")
-
+        system = _schedule_system_metrics(spec, sched, sp)
+        guards = _resolve_guards(guards, sched.trace)
+        _check_checkpoint_args(checkpoint_every, checkpoint_dir, resume,
+                               strict_transfers)
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
             csh = NamedSharding(mesh, P(engine.client_axes(mesh)))
             x, y = jax.device_put(x, csh), jax.device_put(y, csh)
+        do_eval = _eval_mask(rounds, test_data, eval_every)
+        ckpt = _checkpoint_plan(framework, seeds, sched, do_eval, spec,
+                                checkpoint_every, checkpoint_dir, resume,
+                                _checkpoint_hook)
+        params, host = _fetched(strict_transfers, lambda: _run_rounds_scan(
+            spec, cfg, sp, sched, _scan_data(x, y, test_data), seeds,
+            do_eval, eval_gamma, mesh, guards=guards, ckpt=ckpt,
+            spec_kw=spec_kw))
+        return _campaign_result(framework, seeds, sched, params, host,
+                                system, test_data, guards)
 
-        if not scan:
-            if mesh is not None:
-                raise ValueError("mesh (sharded rounds) requires scan=True")
-            if eval_every:
-                raise ValueError("eval_every (fused per-round eval) requires "
-                                 "scan=True; the python loop only evaluates "
-                                 "post-hoc")
-            if has_faults or guards is not None:
-                raise ValueError("fault injection / RoundGuards require "
-                                 "scan=True (the guards live inside the scan)")
-            losses, params = _run_rounds_loop(spec, cfg, sp, sched, x, y,
-                                              seeds)
-            result = CampaignResult(
-                framework=framework, seeds=tuple(seeds), schedule=sched,
-                params=params, losses=losses,
-                metrics=_make_metrics(sched, comm, nsel, sim, cost, energy,
-                                      losses, None))
-            if test_data is not None:
-                result.accuracy = evaluate_campaign(
-                    result, cfg, test_data, client_data=client_data,
-                    gamma=eval_gamma, policy=spec.policy)
-            return result
 
-        do_eval = np.zeros(rounds, np.float32)
-        if test_data is not None:
-            if eval_every:
-                do_eval[eval_every - 1::eval_every] = 1.0
-            do_eval[rounds - 1] = 1.0
+def _resolve_guards(guards, trace):
+    """The rounds' ``RoundGuards``: ``None`` arms the defaults when the
+    trace injects faults (a population trace carries none), ``False``
+    forces them off."""
+    if (guards is None and isinstance(trace, scen.ScenarioTrace)
+            and trace.has_faults()):
+        return engine.RoundGuards()
+    return None if guards is False else guards
 
-        ckpt = None
-        if checkpoint_every:
-            from repro.launch import resilience
-            fp = resilience.schedule_fingerprint(
-                framework, seeds, sched, do_eval=do_eval,
-                quant_mode=spec.quant.mode, checkpoint_every=checkpoint_every)
-            resume_from = None
-            if resume:
-                resume_from = resilience.latest_checkpoint(checkpoint_dir)
-                if resume_from is not None:
-                    meta = resilience.load_checkpoint_meta(resume_from)
-                    if meta.get("fingerprint") != fp:
-                        raise ValueError(
-                            f"checkpoint {resume_from} was written by a "
-                            f"different campaign plan (schedule fingerprint "
-                            f"mismatch); refusing to resume")
-            ckpt = {"dir": checkpoint_dir, "every": int(checkpoint_every),
-                    "fingerprint": fp, "resume_from": resume_from,
-                    "hook": _checkpoint_hook, "framework": framework,
-                    "n_seeds": len(seeds)}
 
-        guard = (jax.transfer_guard_device_to_host("disallow")
-                 if strict_transfers else contextlib.nullcontext())
-        with guard:
-            params, buffers = _run_rounds_scan(
-                spec, cfg, sp, sched, _scan_data(x, y, test_data), seeds,
-                do_eval, eval_gamma, mesh, guards=guards, ckpt=ckpt,
-                spec_kw=spec_kw)
-        host = _host_fetch(buffers)            # THE per-campaign transfer
+def _fault_channels(trace):
+    """The trace's (poison, wire_gain, crash) channels; no trace and a
+    population trace carry none."""
+    if isinstance(trace, scen.ScenarioTrace):
+        return trace.poison, trace.wire_gain, trace.crash
+    return None, None, None
 
-        live = host["live"] > 0
-        losses = np.transpose(host["loss"][live], (1, 0, 2))   # (S, R, n_ph)
-        acc_rounds = np.asarray(host["acc"][live])             # (R, S)
-        skipped = quorum = crashed = None
-        if guards is not None:
-            skipped = np.asarray(host["skipped"][live])        # (R, S)
-            quorum = np.asarray(host["quorum"][live])          # (R, S)
-        if trace is not None and trace.crash is not None:
-            crashed = (np.asarray(trace.crash[:rounds]) > 0).astype(np.float64)
-        result = CampaignResult(
-            framework=framework, seeds=tuple(seeds), schedule=sched,
-            params=params, losses=losses,
-            metrics=_make_metrics(sched, comm, nsel, sim, cost, energy, losses,
-                                  acc_rounds if test_data is not None
-                                  else None,
-                                  skipped=skipped, quorum=quorum,
-                                  crashed=crashed),
-            accuracy_per_round=acc_rounds if test_data is not None else None,
-            skipped_per_round=skipped, quorum_per_round=quorum,
-            crashed_per_round=crashed)
-        if test_data is not None:
-            result.accuracy = acc_rounds[rounds - 1]
-        return result
+
+def _check_checkpoint_args(checkpoint_every, checkpoint_dir, resume,
+                           strict_transfers) -> None:
+    if checkpoint_every or checkpoint_dir or resume:
+        if not (checkpoint_every and checkpoint_dir is not None):
+            raise ValueError("checkpointing needs BOTH checkpoint_every "
+                             "and checkpoint_dir (resume implies both)")
+        if strict_transfers:
+            raise ValueError("checkpoint_every is incompatible with "
+                             "strict_transfers: each segment save is an "
+                             "explicit device→host pull")
+
+
+def _eval_mask(rounds: int, test_data, eval_every: Optional[int]):
+    """The per-round ``do_eval`` mask: with a test set, the final round
+    plus every ``eval_every``-th."""
+    do_eval = np.zeros(rounds, np.float32)
+    if test_data is not None:
+        if eval_every:
+            do_eval[eval_every - 1::eval_every] = 1.0
+        do_eval[rounds - 1] = 1.0
+    return do_eval
+
+
+def _checkpoint_plan(framework, seeds, sched, do_eval, spec,
+                     checkpoint_every, checkpoint_dir, resume, hook,
+                     extra=()):
+    """The ``ckpt`` dict ``_run_rounds_scan`` saves and resumes by (None
+    without checkpointing).  ``extra`` joins the schedule fingerprint; a
+    resume refuses a checkpoint written under another fingerprint."""
+    if not checkpoint_every:
+        return None
+    from repro.launch import resilience
+    fp = resilience.schedule_fingerprint(
+        framework, seeds, sched, do_eval=do_eval,
+        quant_mode=spec.quant.mode, checkpoint_every=checkpoint_every,
+        extra=extra)
+    resume_from = None
+    if resume:
+        resume_from = resilience.latest_checkpoint(checkpoint_dir)
+        if resume_from is not None:
+            meta = resilience.load_checkpoint_meta(resume_from)
+            if meta.get("fingerprint") != fp:
+                raise ValueError(
+                    f"checkpoint {resume_from} was written by a "
+                    f"different campaign plan (schedule fingerprint "
+                    f"mismatch); refusing to resume")
+    return {"dir": checkpoint_dir, "every": int(checkpoint_every),
+            "fingerprint": fp, "resume_from": resume_from, "hook": hook,
+            "framework": framework, "n_seeds": len(seeds)}
+
+
+def _fetched(strict_transfers: bool, run):
+    """``run()``'s ``(params, device buffers)``, the buffers then fetched
+    in THE per-campaign transfer.  ``strict_transfers`` runs it under
+    ``jax.transfer_guard_device_to_host("disallow")``."""
+    guard = (jax.transfer_guard_device_to_host("disallow")
+             if strict_transfers else contextlib.nullcontext())
+    with guard:
+        params, buffers = run()
+    return params, _host_fetch(buffers)
+
+
+def _campaign_result(framework, seeds, sched, params, host, system,
+                     test_data, guards) -> CampaignResult:
+    """A scanned campaign's result from its fetched buffers; ``system`` is
+    the schedule's (comm, nsel, sim, cost, energy)."""
+    rounds = sched.rounds
+    live = host["live"] > 0
+    losses = np.transpose(host["loss"][live], (1, 0, 2))   # (S, R, n_ph)
+    acc_rounds = (np.asarray(host["acc"][live])            # (R, S)
+                  if test_data is not None else None)
+    skipped = quorum = crashed = None
+    if guards is not None:
+        skipped = np.asarray(host["skipped"][live])        # (R, S)
+        quorum = np.asarray(host["quorum"][live])          # (R, S)
+    crash = _fault_channels(sched.trace)[2]
+    if crash is not None:
+        crashed = (np.asarray(crash[:rounds]) > 0).astype(np.float64)
+    result = CampaignResult(
+        framework=framework, seeds=tuple(seeds), schedule=sched,
+        params=params, losses=losses,
+        metrics=_make_metrics(sched, *system, losses, acc_rounds,
+                              skipped=skipped, quorum=quorum,
+                              crashed=crashed),
+        accuracy_per_round=acc_rounds, skipped_per_round=skipped,
+        quorum_per_round=quorum, crashed_per_round=crashed)
+    if acc_rounds is not None:
+        result.accuracy = acc_rounds[rounds - 1]
+    return result
 
 
 def _concat(ys_all):
@@ -551,49 +573,6 @@ def _save_checkpoint(ckpt, end: int, rounds: int, carry, ys_all) -> None:
             framework=ckpt["framework"], n_seeds=ckpt["n_seeds"])
     if ckpt["hook"] is not None:
         ckpt["hook"](end)
-
-
-def _run_rounds_loop(spec, cfg, sp, sched, x, y, seeds):
-    """Legacy per-round python loop (the PR-1 hot path, kept as benchmark
-    baseline): one dispatch per round, one host transfer per round when the
-    loss rows are pulled."""
-    rounds = sched.rounds
-    counts = sched.a.sum(axis=1).astype(int)
-    size_of = _bucket_cohorts(counts, sp.M)
-    e_of = _bucket_cohorts(sched.E, int(sp.E_max))
-    fns: Dict[Tuple[int, int], Any] = {}
-
-    def round_exec(k_bucket: int, e_bucket: int):
-        if (k_bucket, e_bucket) not in fns:
-            raw = engine.build_round_fn(spec, cfg, x, y,
-                                        e_max=max(1, e_bucket),
-                                        jit=False, gather=True)
-            fns[k_bucket, e_bucket] = jax.jit(
-                jax.vmap(raw, in_axes=(0, None, None, None, 0, 0)),
-                donate_argnums=(0, 5))
-        return fns[k_bucket, e_bucket]
-
-    params, key_arr, qstate = _init_state(spec, seeds)
-    loss_rows = []
-    for r in range(rounds):
-        k_r, e_r = int(counts[r]), int(sched.E[r])
-        kb = size_of[k_r]
-        idx = np.zeros(kb, np.int32)
-        mask = np.zeros(kb, np.float32)
-        idx[:k_r] = np.nonzero(sched.a[r])[0]   # pads index client 0 and
-        mask[:k_r] = 1.0                        # carry mask weight 0
-        # per-seed key chains advance exactly like the serial trainers
-        ks = jax.vmap(jax.random.split)(key_arr)
-        key_arr, subs = ks[:, 0], ks[:, 1]
-        params, loss_r, qstate = round_exec(kb, e_of[e_r])(
-            params, jnp.asarray(idx), jnp.asarray(mask), jnp.asarray(e_r),
-            subs, qstate)
-        loss_rows.append(loss_r)
-
-    losses = np.stack(
-        [np.stack(_host_fetch(row), axis=-1) for row in loss_rows],
-        axis=1)                                   # (S, R, n_phases)
-    return losses, params
 
 
 def _scan_data(x, y, test_data):
@@ -657,6 +636,7 @@ class _SegmentKey(NamedTuple):
     guards: Optional[engine.RoundGuards]
     with_faults: bool
     robust: bool
+    population: bool      # rows carry their cohort's data (xc, yc)
     data: tuple           # (name, shape, dtype) of each scan-data leaf
     n_seeds: int
     # what the trace reads of the environment: the default matmul
@@ -674,21 +654,22 @@ def clear_segment_cache() -> None:
 
 
 def _segment_key(spec, cfg, spec_kw, data, n_seeds, eval_gamma, mesh,
-                 guards, with_faults, robust) -> _SegmentKey:
+                 guards, with_faults, robust,
+                 population: bool = False) -> _SegmentKey:
     """The key of a campaign's segments, their (kb, eb, lb) left at 0."""
     kw = dict(spec_kw, policy=spec.policy, quant=spec.quant)
     return _SegmentKey(
         framework=spec.name, cfg=cfg,
         spec_kw=tuple(sorted((k, type(v), v) for k, v in kw.items())),
         eval_gamma=eval_gamma, mesh=mesh, guards=guards,
-        with_faults=with_faults, robust=robust,
+        with_faults=with_faults, robust=robust, population=population,
         data=tuple((k, v.shape, str(v.dtype))
                    for k, v in sorted(data.items())),
         n_seeds=n_seeds,
         settings=(jax.config.jax_default_matmul_precision,
                   jax.config.jax_default_device, use_interpret()),
         code=(engine._round_core, engine.build_round_fn,
-              engine.build_sharded_round_fn, engine.build_eval_fn))
+              engine.build_eval_fn))
 
 
 def _segment_exec(key: _SegmentKey):
@@ -707,39 +688,25 @@ def _segment_exec(key: _SegmentKey):
 
 
 def _round_caller(spec, cfg, mesh, eb: int, guards, with_faults: bool,
-                  x, y):
-    """One seed-vmapped round over the scan row ``xr``: the gathered
-    cohort round, or the shard_map round over ``mesh``."""
-    if mesh is None:
-        raw = engine.build_round_fn(spec, cfg, x, y, e_max=max(1, eb),
-                                    jit=False, gather=True, guards=guards,
-                                    with_faults=with_faults)
-
-        def call_round(params, xr, subs, qstate):
-            if not with_faults:
-                return jax.vmap(
-                    raw, in_axes=(0, None, None, None, 0, 0))(
-                    params, xr["idx"], xr["mask"], xr["e"], subs, qstate)
-            faults = {"poison": xr["poison"], "wire_gain": xr["wire"]}
-            return jax.vmap(
-                raw, in_axes=(0, None, None, None, 0, 0, None))(
-                params, xr["idx"], xr["mask"], xr["e"], subs, qstate,
-                faults)
-        return call_round
-
-    raw = engine.build_sharded_round_fn(
-        spec, cfg, mesh, n_clients=x.shape[0], e_max=max(1, eb), jit=False,
-        guards=guards, with_faults=with_faults)
+                  data):
+    """One seed-vmapped round over the scan row ``xr``, which comes in one
+    of three kinds: a gathered cohort of ``data`` (``idx``, ``mask`` and
+    the fault channels gathered by ``idx``), the full mask over ``data``'s
+    clients on ``mesh`` (``mask``), or a population cohort with its own
+    data (``xc``, ``yc``, ``mask``)."""
+    raw = engine.build_round_fn(spec, cfg, e_max=max(1, eb), mesh=mesh,
+                                jit=False, guards=guards,
+                                with_faults=with_faults)
+    vround = jax.vmap(raw, in_axes=(0, None, None, None, None, 0, 0, None,
+                                    None))
 
     def call_round(params, xr, subs, qstate):
-        if not with_faults:
-            return jax.vmap(
-                raw, in_axes=(0, None, None, None, None, 0, 0))(
-                params, x, y, xr["mask"], xr["e"], subs, qstate)
-        faults = {"poison": xr["poison"], "wire_gain": xr["wire"]}
-        return jax.vmap(
-            raw, in_axes=(0, None, None, None, None, 0, 0, None))(
-            params, x, y, xr["mask"], xr["e"], subs, qstate, faults)
+        x, y = ((xr["xc"], xr["yc"]) if "xc" in xr
+                else (data["x"], data["y"]))
+        faults = ({"poison": xr["poison"], "wire_gain": xr["wire"]}
+                  if with_faults else None)
+        return vround(params, x, y, xr["mask"], xr["e"], subs, qstate,
+                      faults, xr.get("idx"))
     return call_round
 
 
@@ -754,7 +721,7 @@ def _build_segment(key: _SegmentKey):
         # the round and eval close over the traced data, never over
         # device arrays (see _scan_data)
         call_round = _round_caller(spec, cfg, mesh, key.eb, guards,
-                                   key.with_faults, data["x"], data["y"])
+                                   key.with_faults, data)
         eval_fn = _fused_eval(spec, cfg, data, key.eval_gamma, mesh)
         nan_row = jnp.full((key_arr.shape[0],), jnp.nan, jnp.float32)
 
@@ -797,15 +764,15 @@ def _build_segment(key: _SegmentKey):
 
 
 def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
-                     mesh, *, spec_kw, guards=None, ckpt=None):
+                     mesh, *, spec_kw, guards=None, ckpt=None, cohorts=None):
     """Scan all rounds on-device; returns (params, device metric buffers).
 
     The buffers carry everything that EXISTS on the device — per-round
     per-seed losses and fused-eval accuracies (plus the live mask; under
     guards also the per-seed skipped/quorum flags); the remaining
     per-round metrics (comm_bits, selected-count, latency, cost) are
-    schedule constants already precomputed host-side by
-    ``_schedule_system_metrics`` and never touch the device.
+    schedule constants already precomputed host-side and never touch the
+    device.
 
     Rounds sharing a (cohort-bucket, E-bucket) shape form contiguous scan
     segments; segment lengths are bucketed as well, padded with ``live=0``
@@ -814,12 +781,19 @@ def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
     call (``_segment_exec``): ``spec_kw``, the keywords ``spec`` was made
     with, keys them with everything else their trace reads.
 
+    ``cohorts`` (``(xc, yc)``, every round's population cohort data, from
+    ``run_population_campaign``) makes the cohort data scan rows: each
+    round trains its ``C`` cohort positions under ``sched.a``'s mask, and
+    the device never holds more than one segment's cohorts.  Without it a
+    round gathers its selected clients of ``data`` (or, on ``mesh``,
+    trains the full masked fleet).
+
     ``guards`` (engine.RoundGuards) and the schedule trace's fault
     channels arm the robust scan body: poison/wire-corruption rows become
     extra scan operands feeding the round's fault injection, a crash round
     holds params/qstate (clients still advance their RNG — they trained;
     the server lost the aggregate), and the round's guard flags land in
-    the buffers.  ``ckpt`` (dict from ``run_campaign``: dir / every /
+    the buffers.  ``ckpt`` (``_checkpoint_plan``'s dict: dir / every /
     fingerprint / resume_from / hook) splits segments at checkpoint
     boundaries, persists the carry after each boundary via
     ``repro.launch.resilience`` and, on resume, restores it and skips the
@@ -828,22 +802,21 @@ def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
     n_seeds = len(seeds)
     counts = sched.a.sum(axis=1).astype(int)
     e_of = _bucket_cohorts(sched.E, int(sp.E_max))
-    if mesh is None:
+    gather = mesh is None and cohorts is None
+    if gather:
         size_of = _bucket_cohorts(counts, sp.M)
         kb_r = [size_of[int(c)] for c in counts]
     else:
-        kb_r = [int(sp.M)] * rounds       # sharded rounds train the full
-        # masked M axis (a gather would break the static client sharding)
+        # a sharded round trains the full masked M axis (a gather would
+        # break the static client sharding), a population round its cohort
+        kb_r = [int(sched.a.shape[1])] * rounds
     eb_r = [e_of[int(e)] for e in sched.E]
     segs = _split_at_checkpoints(_plan_segments(kb_r, eb_r),
                                  ckpt["every"] if ckpt else None)
     len_of = _bucket_cohorts([l for *_ , l in segs],
                              max(l for *_, l in segs))
 
-    trace = sched.trace
-    poison = trace.poison if trace is not None else None
-    wire = trace.wire_gain if trace is not None else None
-    crash = trace.crash if trace is not None else None
+    poison, wire, crash = _fault_channels(sched.trace)
     with_faults = poison is not None or wire is not None
     has_crash = crash is not None and bool(np.any(np.asarray(crash) > 0))
     robust = guards is not None or with_faults or has_crash
@@ -854,7 +827,8 @@ def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
              else np.asarray(wire, np.float32))
 
     base = _segment_key(spec, cfg, spec_kw, data, n_seeds, eval_gamma, mesh,
-                        guards, with_faults, robust)
+                        guards, with_faults, robust,
+                        population=cohorts is not None)
     params, key_arr, qstate = _init_state(spec, seeds, mesh)
     ys_all = []
     start_round = 0
@@ -881,58 +855,58 @@ def _run_rounds_scan(spec, cfg, sp, sched, data, seeds, do_eval, eval_gamma,
         if start + length <= start_round:
             continue                       # restored from the checkpoint
         lb = len_of[length]
+        end = start + length
         key = base._replace(kb=kb, eb=eb, lb=lb)
         with spans.span("segment", kb=kb, eb=eb, lb=lb, start=start,
                         length=length, built=key not in _segments):
-            xs = {
-                "e": np.zeros(lb, np.int32),
-                "live": np.zeros(lb, np.float32),
-                "do_eval": np.zeros(lb, np.float32),
-            }
-            xs["e"][:length] = sched.E[start:start + length]
-            xs["live"][:length] = 1.0
-            xs["do_eval"][:length] = do_eval[start:start + length]
+            xs = {"e": _pad(sched.E[start:end], lb, np.int32),
+                  "live": _pad(np.ones(length), lb),
+                  "do_eval": _pad(do_eval[start:end], lb)}
             if robust:
-                xs["crash"] = np.zeros(lb, np.float32)
-                if has_crash:
-                    xs["crash"][:length] = crash[start:start + length]
-            if mesh is None:
+                xs["crash"] = _pad(crash[start:end] if has_crash
+                                   else np.zeros(length), lb)
+            if gather:
                 idx = np.zeros((lb, kb), np.int32)
                 mask = np.zeros((lb, kb), np.float32)
-                for i, r in enumerate(range(start, start + length)):
+                for i, r in enumerate(range(start, end)):
                     k_r = int(counts[r])
                     idx[i, :k_r] = np.nonzero(sched.a[r])[0]  # pads: client
                     mask[i, :k_r] = 1.0                       # 0, weight 0
                 xs["idx"], xs["mask"] = idx, mask
                 if with_faults:
-                    # gather the fault channels by the same cohort index;
+                    # the fault channels gathered by the same cohort index;
                     # pads stay neutral (poison 0, gain 1 — and carry mask 0)
-                    pz = np.zeros((lb, kb), np.float32)
-                    wg = np.ones((lb, kb), np.float32)
-                    for i, r in enumerate(range(start, start + length)):
-                        k_r = int(counts[r])
-                        pz[i, :k_r] = p_arr[r, idx[i, :k_r]]
-                        wg[i, :k_r] = w_arr[r, idx[i, :k_r]]
-                    xs["poison"], xs["wire"] = pz, wg
+                    r_i = np.arange(start, end)[:, None]
+                    sel = mask[:length] > 0
+                    xs["poison"] = _pad(np.where(
+                        sel, p_arr[r_i, idx[:length]], 0.0), lb)
+                    xs["wire"] = _pad(np.where(
+                        sel, w_arr[r_i, idx[:length]], 1.0), lb, fill=1.0)
             else:
-                mask = np.zeros((lb, M), np.float32)
-                mask[:length] = sched.a[start:start + length]
-                xs["mask"] = mask
+                xs["mask"] = _pad(sched.a[start:end], lb)
                 if with_faults:
-                    pz = np.zeros((lb, M), np.float32)
-                    wg = np.ones((lb, M), np.float32)
-                    pz[:length] = p_arr[start:start + length]
-                    wg[:length] = w_arr[start:start + length]
-                    xs["poison"], xs["wire"] = pz, wg
+                    xs["poison"] = _pad(p_arr[start:end], lb)
+                    xs["wire"] = _pad(w_arr[start:end], lb, fill=1.0)
+            if cohorts is not None:
+                xs["xc"] = _pad(cohorts[0][start:end], lb)
+                xs["yc"] = _pad(cohorts[1][start:end], lb, np.int32)
             (params, key_arr, qstate), ys = _segment_exec(key)(
                 params, key_arr, qstate, xs, data)
         ys_all.append(ys)
-        end = start + length
         if ckpt is not None and (end % ckpt["every"] == 0 or end == rounds):
             _save_checkpoint(ckpt, end, rounds, {"params": params,
                                                  "keys": key_arr,
                                                  "qstate": qstate}, ys_all)
     return params, _concat(ys_all)
+
+
+def _pad(rows, lb: int, dtype=np.float32, fill=0.0):
+    """``rows`` along a segment's round axis, padded to its ``lb`` rounds
+    with ``fill``."""
+    rows = np.asarray(rows, dtype)
+    out = np.full((lb,) + rows.shape[1:], fill, dtype)
+    out[:len(rows)] = rows
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -1074,8 +1048,9 @@ def run_population_campaign(framework: str, cfg: DNNConfig,
     its clients' lazy shards from it (``Population.sample_shards``), and
     the stacked per-round cohort data become scan operands — the runner
     holds O(rounds × cohort × samples) host bytes and O(cohort) device
-    bytes, NEVER O(population).  Everything else matches ``run_campaign``:
-    one compiled scan per (E-bucket, length-bucket), one host transfer
+    bytes, NEVER O(population).  Everything else is ``run_campaign``'s
+    segment path: one compiled scan per (E-bucket, length-bucket), kept
+    in the same process cache (``_segment_exec``), one host transfer
     (``strict_transfers`` enforceable), fused eval behind ``do_eval``,
     CommQuant wire formats, ``RoundGuards``, and checkpoint/resume with
     the cohort plan hashed into the schedule fingerprint.  Fault-injection
@@ -1101,8 +1076,9 @@ def run_population_campaign(framework: str, cfg: DNNConfig,
                 n_samples_per_client=samples_per_client, quant=quant,
                 scenario=scenario, scenario_seed=scenario_seed,
                 stratified=stratified)
-        spec = engine.make_spec(framework, cfg, masked_loss_metric=True,
-                                policy=policy, quant=quant, **hyper)
+        spec_kw = dict(hyper, masked_loss_metric=True)
+        spec = engine.make_spec(framework, cfg, policy=policy, quant=quant,
+                                **spec_kw)
         comm = np.atleast_1d(np.asarray(
             spec.comm_model(sched.a, sched.E, sp), np.float64))
         nsel = sched.a.sum(axis=1).astype(int)
@@ -1122,215 +1098,23 @@ def run_population_campaign(framework: str, cfg: DNNConfig,
                                           samples_per_client, alpha=alpha)
             xc_all[t], yc_all[t] = sh["x"], sh["y"]
 
-        if guards is False:
-            guards = None
-        if checkpoint_every or checkpoint_dir or resume:
-            if not (checkpoint_every and checkpoint_dir is not None):
-                raise ValueError("checkpointing needs BOTH checkpoint_every "
-                                 "and checkpoint_dir (resume implies both)")
-            if strict_transfers:
-                raise ValueError("checkpoint_every is incompatible with "
-                                 "strict_transfers: each segment save is an "
-                                 "explicit device→host pull")
-
-        do_eval = np.zeros(rounds, np.float32)
-        if test_data is not None:
-            if eval_every:
-                do_eval[eval_every - 1::eval_every] = 1.0
-            do_eval[rounds - 1] = 1.0
-
-        ckpt = None
-        if checkpoint_every:
-            from repro.launch import resilience
-            fp = resilience.schedule_fingerprint(
-                framework, seeds, sched, do_eval=do_eval,
-                quant_mode=spec.quant.mode, checkpoint_every=checkpoint_every,
-                extra=(sched.ids, sched.m_t))
-            resume_from = None
-            if resume:
-                resume_from = resilience.latest_checkpoint(checkpoint_dir)
-                if resume_from is not None:
-                    meta = resilience.load_checkpoint_meta(resume_from)
-                    if meta.get("fingerprint") != fp:
-                        raise ValueError(
-                            f"checkpoint {resume_from} was written by a "
-                            f"different campaign plan (schedule fingerprint "
-                            f"mismatch); refusing to resume")
-            ckpt = {"dir": checkpoint_dir, "every": int(checkpoint_every),
-                    "fingerprint": fp, "resume_from": resume_from,
-                    "hook": _checkpoint_hook, "framework": framework,
-                    "n_seeds": len(seeds)}
-
-        guard = (jax.transfer_guard_device_to_host("disallow")
-                 if strict_transfers else contextlib.nullcontext())
-        with guard:
-            params, buffers = _run_population_scan(
-                spec, cfg, sp, sched, xc_all, yc_all, seeds, do_eval,
-                _scan_data(jnp.asarray(xc_all[-1]), jnp.asarray(yc_all[-1]),
-                           test_data), eval_gamma, guards=guards, ckpt=ckpt)
-        host = _host_fetch(buffers)            # THE per-campaign transfer
-
-        live = host["live"] > 0
-        losses = np.transpose(host["loss"][live], (1, 0, 2))   # (S, R, n_ph)
-        acc_rounds = np.asarray(host["acc"][live])             # (R, S)
-        skipped = quorum = None
-        if guards is not None:
-            skipped = np.asarray(host["skipped"][live])
-            quorum = np.asarray(host["quorum"][live])
-        result = CampaignResult(
-            framework=framework, seeds=tuple(seeds), schedule=sched,
-            params=params, losses=losses,
-            metrics=_make_metrics(sched, comm, nsel, sim, cost, energy, losses,
-                                  acc_rounds if test_data is not None
-                                  else None,
-                                  skipped=skipped, quorum=quorum),
-            accuracy_per_round=acc_rounds if test_data is not None else None,
-            skipped_per_round=skipped, quorum_per_round=quorum)
-        if test_data is not None:
-            result.accuracy = acc_rounds[rounds - 1]
-        return result
-
-
-def _run_population_scan(spec, cfg, sp, sched: PopulationSchedule, xc_all,
-                         yc_all, seeds, do_eval, data, eval_gamma,
-                         guards=None, ckpt=None):
-    """Scan all rounds of a population campaign on-device.
-
-    The structure mirrors ``_run_rounds_scan`` with one inversion: instead
-    of gathering cohorts out of a fixed closed-over dataset, the per-round
-    cohort DATA are scan operands (``xc``/``yc``) feeding
-    ``engine.build_cohort_round_fn`` — the device never holds more than
-    one segment's cohorts.  The cohort width C is constant, so segments
-    split only on (E-bucket, length-bucket) and checkpoint boundaries;
-    the carry ({params, keys, qstate}) is population-size-free and
-    persists/restores through the same resilience layer."""
-    rounds = sched.rounds
-    n_seeds = len(seeds)
-    C = int(sched.ids.shape[1])
-    e_of = _bucket_cohorts(sched.E, int(sp.E_max))
-    eb_r = [e_of[int(e)] for e in sched.E]
-    segs = _split_at_checkpoints(_plan_segments([C] * rounds, eb_r),
-                                 ckpt["every"] if ckpt else None)
-    len_of = _bucket_cohorts([l for *_, l in segs],
-                             max(l for *_, l in segs))
-    n_ph = len(spec.phases)
-    fns: Dict[Tuple[int, int], Any] = {}
-
-    def seg_exec(eb: int, lb: int):
-        if (eb, lb) in fns:
-            return fns[eb, lb]
-        spans.count("segment_builds")
-        raw = engine.build_cohort_round_fn(spec, cfg, e_max=max(1, eb),
-                                           jit=False, guards=guards)
-        fns[eb, lb] = jax.jit(functools.partial(seg, raw),
-                              donate_argnums=(0, 1, 2))
-        return fns[eb, lb]
-
-    def seg(raw, params, key_arr, qstate, xs, data):
-        # the SplitMe eval's client data and the test set arrive traced
-        # (see _scan_data)
-        eval_fn = _fused_eval(spec, cfg, data, eval_gamma)
-        nan_row = jnp.full((n_seeds,), jnp.nan, jnp.float32)
-
-        def body(carry, xr):
-            params, keys, qstate = carry
-            ks = jax.vmap(jax.random.split)(keys)
-            nkeys, subs = ks[:, 0], ks[:, 1]
-            out = jax.vmap(raw, in_axes=(0, None, None, None, None, 0, 0))(
-                params, xr["xc"], xr["yc"], xr["mask"], xr["e"], subs,
-                qstate)
-            if guards is not None:
-                nparams, phase_losses, nqstate, flags = out
-            else:
-                nparams, phase_losses, nqstate = out
-                flags = None
-            live = xr["live"] > 0
-            params = jax.tree.map(lambda n, o: jnp.where(live, n, o),
-                                  nparams, params)
-            qstate = jax.tree.map(lambda n, o: jnp.where(live, n, o),
-                                  nqstate, qstate)
-            keys = jnp.where(live, nkeys, keys)
-            loss_row = jnp.where(live, jnp.stack(phase_losses, -1), jnp.nan)
-            if eval_fn is None:
-                acc = nan_row
-            else:
-                acc = jax.lax.cond(
-                    jnp.logical_and(xr["do_eval"] > 0, live),
-                    jax.vmap(eval_fn), lambda p: nan_row, params)
-            ys = {"loss": loss_row, "acc": acc, "live": xr["live"]}
-            if guards is not None:
-                ys["skipped"] = jnp.where(live, flags["skipped"], 0.0)
-                ys["quorum"] = jnp.where(live, flags["quorum"], 0.0)
-            return (params, keys, qstate), ys
-
-        return jax.lax.scan(body, (params, key_arr, qstate), xs)
-
-    params, key_arr, qstate = _init_state(spec, seeds)
-    ys_all = []
-    start_round = 0
-    if ckpt is not None and ckpt["resume_from"] is not None:
-        from repro.checkpoint import io
-        path = ckpt["resume_from"]
-        like = {"params": params, "keys": key_arr, "qstate": qstate}
-        state = io.restore(path, like)
-        params, key_arr, qstate = \
-            state["params"], state["keys"], state["qstate"]
-        buf = io.load_arrays(Path(path).with_name(Path(path).name
-                                                  + "-buffers"))
-        ys_all.append({k: jnp.asarray(v) for k, v in buf.items()})
-        start_round = int(io.manifest(path)["metadata"]["round_cursor"])
-    n_samples = xc_all.shape[2]
-    for _, eb, start, length in segs:
-        if start + length <= start_round:
-            continue                       # restored from the checkpoint
-        lb = len_of[length]
-        with spans.span("segment", kb=C, eb=eb, lb=lb, start=start,
-                        length=length, built=(eb, lb) not in fns):
-            xs = {
-                "e": np.zeros(lb, np.int32),
-                "live": np.zeros(lb, np.float32),
-                "do_eval": np.zeros(lb, np.float32),
-                "mask": np.zeros((lb, C), np.float32),
-                "xc": np.zeros((lb, C, n_samples, xc_all.shape[3]),
-                               np.float32),
-                "yc": np.zeros((lb, C, n_samples), np.int32),
-            }
-            end = start + length
-            xs["e"][:length] = sched.E[start:end]
-            xs["live"][:length] = 1.0
-            xs["do_eval"][:length] = do_eval[start:end]
-            xs["mask"][:length] = sched.a[start:end]
-            xs["xc"][:length] = xc_all[start:end]
-            xs["yc"][:length] = yc_all[start:end]
-            (params, key_arr, qstate), ys = seg_exec(eb, lb)(
-                params, key_arr, qstate, xs, data)
-        ys_all.append(ys)
-        if ckpt is not None and (end % ckpt["every"] == 0 or end == rounds):
-            _save_checkpoint(ckpt, end, rounds, {"params": params,
-                                                 "keys": key_arr,
-                                                 "qstate": qstate}, ys_all)
-    return params, _concat(ys_all)
-
-
-def evaluate_campaign(result: CampaignResult, cfg: DNNConfig, test_data,
-                      client_data=None, gamma: float = 1e-3,
-                      policy=None) -> np.ndarray:
-    """Per-seed test accuracy of a finished campaign (post-hoc; the scanned
-    campaign fuses the same jitted evaluation into its round scan).
-
-    Full-model frameworks evaluate the aggregated MLP directly; SplitMe
-    first recovers each seed's server model via the one-shot analytic
-    inversion (Step 4), which needs the client data for the Gram sums.
-    Both paths are the engine's jitted ``build_eval_fn``, vmapped over the
-    seed axis; ``policy`` selects kernels/precision for them."""
-    spec = engine.make_spec(result.framework, cfg, policy=policy)
-    if result.framework == "splitme" and client_data is None:
-        raise ValueError("splitme evaluation needs client_data for Step 4")
-    eval_fn = engine.build_eval_fn(
-        spec, cfg, *test_data, gamma=gamma, jit=False,
-        client_data=client_data if result.framework == "splitme" else None)
-    acc = _host_fetch(jax.jit(jax.vmap(eval_fn))(result.params))
-    return np.asarray(acc, dtype=np.float64)
+        guards = _resolve_guards(guards, sched.trace)
+        _check_checkpoint_args(checkpoint_every, checkpoint_dir, resume,
+                               strict_transfers)
+        do_eval = _eval_mask(rounds, test_data, eval_every)
+        ckpt = _checkpoint_plan(framework, seeds, sched, do_eval, spec,
+                                checkpoint_every, checkpoint_dir, resume,
+                                _checkpoint_hook,
+                                extra=(sched.ids, sched.m_t))
+        params, host = _fetched(strict_transfers, lambda: _run_rounds_scan(
+            spec, cfg, sp, sched,
+            _scan_data(jnp.asarray(xc_all[-1]), jnp.asarray(yc_all[-1]),
+                       test_data), seeds, do_eval, eval_gamma, None,
+            spec_kw=spec_kw, guards=guards, ckpt=ckpt,
+            cohorts=(xc_all, yc_all)))
+        return _campaign_result(framework, seeds, sched, params, host,
+                                (comm, nsel, sim, cost, energy), test_data,
+                                guards)
 
 
 def run_config_sweep(framework: str, cfg: DNNConfig,
@@ -1395,16 +1179,11 @@ def run_config_sweep(framework: str, cfg: DNNConfig,
 
     spec = engine.make_spec(framework, cfg, masked_loss_metric=True,
                             policy=policy, quant=quant, **hyper)
-    do_eval = np.zeros(rounds, np.float32)
-    if test_data is not None:
-        if eval_every:
-            do_eval[eval_every - 1::eval_every] = 1.0
-        do_eval[rounds - 1] = 1.0
+    do_eval = _eval_mask(rounds, test_data, eval_every)
 
     def sweep(init_keys, key_arr, xs, data):
         # round and eval over the traced data (see _scan_data)
-        raw = engine.build_round_fn(spec, cfg, data["x"], data["y"],
-                                    e_max=e_max, jit=False, gather=False)
+        raw = engine.build_round_fn(spec, cfg, e_max=e_max, jit=False)
         eval_fn = _fused_eval(spec, cfg, data, eval_gamma)
         params_s = jax.vmap(spec.init_fn)(init_keys)          # (S, …)
         params = jax.tree.map(
@@ -1418,8 +1197,9 @@ def run_config_sweep(framework: str, cfg: DNNConfig,
             nkeys, subs = ks[:, 0], ks[:, 1]
             nparams, phase_losses, nqstate = jax.vmap(
                 lambda pv, av, ev, qv: jax.vmap(
-                    raw, in_axes=(0, None, None, 0, 0))(
-                    pv, av, ev, subs, qv))(params, xr["a"], xr["e"], qstate)
+                    raw, in_axes=(0, None, None, None, None, 0, 0))(
+                    pv, data["x"], data["y"], av, ev, subs, qv))(
+                params, xr["a"], xr["e"], qstate)
             loss_row = jnp.stack(phase_losses, -1)        # (V, S, n_ph)
             if eval_fn is None:
                 acc = jnp.full((V, S), jnp.nan, jnp.float32)
@@ -1435,17 +1215,16 @@ def run_config_sweep(framework: str, cfg: DNNConfig,
                                           xs)
         return params, ys
 
-    guard = (jax.transfer_guard_device_to_host("disallow")
-             if strict_transfers else contextlib.nullcontext())
-    with guard:
+    def run():
         init_keys = jnp.stack([jax.random.PRNGKey(s + spec.init_key_offset)
                                for s in seeds])
         key0 = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
         xs = {"a": a_all.transpose(1, 0, 2), "e": e_all.T,
               "do_eval": do_eval}
-        params, ys = jax.jit(sweep)(init_keys, key0, xs,
-                                    _scan_data(x, y, test_data))
-    host = _host_fetch(ys)                 # ONE transfer for the sweep
+        return jax.jit(sweep)(init_keys, key0, xs,
+                              _scan_data(x, y, test_data))
+
+    params, host = _fetched(strict_transfers, run)   # ONE transfer
 
     results = []
     for v in range(V):

@@ -35,12 +35,11 @@ def run_check(data_shards: int) -> None:
         spec = engine.make_spec(name, DNN10)
         params = spec.init_fn(jax.random.PRNGKey(3))
         qs = engine.init_quant_state(spec, params)     # () for quant=none
-        single = engine.build_round_fn(spec, DNN10, x, y, e_max=e_max,
+        single = engine.build_round_fn(spec, DNN10, e_max=e_max,
                                        donate=False)
-        p1, l1, _ = single(params, a, jnp.asarray(e_steps), key, qs)
-        sharded = engine.build_sharded_round_fn(spec, DNN10, mesh,
-                                                n_clients=M, e_max=e_max,
-                                                donate=False)
+        p1, l1, _ = single(params, x, y, a, jnp.asarray(e_steps), key, qs)
+        sharded = engine.build_round_fn(spec, DNN10, e_max=e_max, mesh=mesh,
+                                        donate=False)
         p2, l2, _ = sharded(params, x, y, a, jnp.asarray(e_steps), key, qs)
         for g, h in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
             np.testing.assert_allclose(np.asarray(g), np.asarray(h),

@@ -131,14 +131,9 @@ def test_scanned_campaign_single_host_transfer(small_data, monkeypatch):
         seeds=(0, 1), test_data=test, strict_transfers=True)
     assert len(calls) == 1
     assert np.isfinite(res.losses).all()
-    # the python loop pulls once per round instead
-    calls.clear()
-    campaign.run_campaign("oranfed", DNN10, SystemParams(M=12, seed=0), cd,
-                          rounds=ROUNDS, seeds=(0, 1), E=5, scan=False)
-    assert len(calls) == ROUNDS
 
 
-@pytest.mark.parametrize("runner", ["campaign", "sweep"])
+@pytest.mark.parametrize("runner", ["campaign", "sweep", "population"])
 def test_scanned_campaign_captures_no_constants(small_data, runner):
     """The client and test data reach the compiled scan as ARGUMENTS.  A
     device array closed over instead is baked into the program as a
@@ -159,30 +154,22 @@ def test_scanned_campaign_captures_no_constants(small_data, runner):
                     "splitme", DNN10, SystemParams(M=12, seed=0), cd,
                     rounds=ROUNDS, seeds=(0, 1), test_data=test,
                     eval_every=2, strict_transfers=True)
-            else:
+            elif runner == "sweep":
                 campaign.run_config_sweep(
                     "splitme", DNN10, [SystemParams(M=12, seed=0)] * 2, cd,
                     rounds=2, seeds=(0,), test_data=test,
                     strict_transfers=True)
+            else:
+                from repro.core.population import Population
+                pool = (cd["x"].reshape(-1, cd["x"].shape[-1]),
+                        cd["y"].reshape(-1))
+                campaign.run_population_campaign(
+                    "splitme", DNN10, Population(size=40, seed=0), pool,
+                    rounds=ROUNDS, seeds=(0, 1), cohort=8,
+                    samples_per_client=16, test_data=test, eval_every=2,
+                    strict_transfers=True)
     finally:
         jax.config.update("jax_captured_constants_warn_bytes", prev)
-
-
-def test_scanned_campaign_matches_python_loop(small_data):
-    """lax.scan-over-rounds reproduces the per-round python loop (identical
-    round functions and RNG chains; scan just removes the host round trip)."""
-    cd, _ = small_data
-    for fw, kw in (("fedavg", {"K": 4, "E": 5}), ("splitme", {})):
-        res_s = campaign.run_campaign(fw, DNN10, SystemParams(M=12, seed=0),
-                                      cd, rounds=ROUNDS, seeds=SEEDS, **kw)
-        res_l = campaign.run_campaign(fw, DNN10, SystemParams(M=12, seed=0),
-                                      cd, rounds=ROUNDS, seeds=SEEDS,
-                                      scan=False, **kw)
-        np.testing.assert_allclose(res_s.losses, res_l.losses, atol=1e-6,
-                                   rtol=0)
-        for i in range(len(SEEDS)):
-            _leaves_close(res_s.params_for(i), res_l.params_for(i),
-                          atol=1e-6)
 
 
 def test_sharded_campaign_matches_gathered(small_data):

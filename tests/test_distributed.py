@@ -1,7 +1,7 @@
 """Distributed SplitMe/SFL rounds (shard_map) + MoE dispatch variants.
 
 ``make_splitme_round`` is now an engine adapter (the shard_map round lives
-in ``repro.core.engine.build_sharded_round_fn``); the hand-written vanilla
+in ``repro.core.engine.build_round_fn(mesh=...)``); the hand-written vanilla
 SFL boundary-exchange round moved to ``repro.launch.fl_dryrun`` (dry-run
 collective accounting only)."""
 import jax

@@ -64,12 +64,10 @@ def test_round_builder_rejects_policy_mismatch():
     builders refuse a different override (it could only half-apply)."""
     from repro.core import engine
     spec = engine.make_spec("fedavg", DNN10, policy="reference")
-    x = jnp.zeros((4, 8, DNN10.n_features))
-    y = jnp.zeros((4, 8), jnp.int32)
     with pytest.raises(ValueError, match="spec-bound"):
-        engine.build_round_fn(spec, DNN10, x, y, e_max=2, policy=KERNEL_ON)
+        engine.build_round_fn(spec, DNN10, e_max=2, policy=KERNEL_ON)
     # restating the bound policy is fine
-    engine.build_round_fn(spec, DNN10, x, y, e_max=2,
+    engine.build_round_fn(spec, DNN10, e_max=2,
                           policy=dispatch.get_policy("reference"))
 
 

@@ -111,14 +111,15 @@ def test_int8_stochastic_rounding_unbiased():
 # ---------------------------------------------------------------------------
 
 def _run_rounds(spec, x, y, rounds=4, e=3):
-    rf = engine.build_round_fn(spec, DNN10, x, y, e_max=e, donate=False)
+    rf = engine.build_round_fn(spec, DNN10, e_max=e, donate=False)
     params = spec.init_fn(jax.random.PRNGKey(3))
     qstate = engine.init_quant_state(spec, params)
     key = jax.random.PRNGKey(7)
     a = jnp.ones(x.shape[0], jnp.float32)
     for _ in range(rounds):
         key, sub = jax.random.split(key)
-        params, losses, qstate = rf(params, a, jnp.asarray(e), sub, qstate)
+        params, losses, qstate = rf(params, x, y, a, jnp.asarray(e), sub,
+                                    qstate)
     return params, losses
 
 
@@ -173,8 +174,8 @@ def test_sharded_quantized_round_one_all_reduce(quant):
     spec = engine.make_spec("splitme", DNN10, masked_loss_metric=True,
                             quant=quant)
     M, n = 8, 16
-    rf = engine.build_sharded_round_fn(spec, DNN10, mesh, n_clients=M,
-                                       e_max=2, jit=False, donate=False)
+    rf = engine.build_round_fn(spec, DNN10, e_max=2, mesh=mesh, jit=False,
+                               donate=False)
     params = spec.init_fn(jax.random.PRNGKey(0))
     qstate = engine.init_quant_state(spec, params, n_shards=1)
     x = jnp.zeros((M, n, DNN10.n_features))
@@ -202,12 +203,12 @@ def test_sharded_quantized_round_matches_single_device(quant, small_data):
     params = spec.init_fn(jax.random.PRNGKey(3))
     a = jnp.ones(M, jnp.float32)
     key = jax.random.PRNGKey(7)
-    single = engine.build_round_fn(spec, DNN10, x, y, e_max=3, donate=False)
-    p1, l1, _ = single(params, a, jnp.asarray(3), key,
+    single = engine.build_round_fn(spec, DNN10, e_max=3, donate=False)
+    p1, l1, _ = single(params, x, y, a, jnp.asarray(3), key,
                        engine.init_quant_state(spec, params))
     mesh = _one_device_mesh()
-    sharded = engine.build_sharded_round_fn(spec, DNN10, mesh, n_clients=M,
-                                            e_max=3, donate=False)
+    sharded = engine.build_round_fn(spec, DNN10, e_max=3, mesh=mesh,
+                                    donate=False)
     p2, l2, _ = sharded(params, x, y, a, jnp.asarray(3), key,
                         engine.init_quant_state(spec, params, n_shards=1))
     assert _leaves_delta(p1, p2) < 1e-6

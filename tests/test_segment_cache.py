@@ -50,6 +50,20 @@ def _run(data, framework, seeds=SEEDS, **kw):
                                  clients, rounds=ROUNDS, seeds=seeds, **kw)
 
 
+def _run_population(data, framework, seeds=SEEDS):
+    """A population campaign whose cohorts draw from the clients' pooled
+    samples."""
+    from repro.core.population import Population
+    clients, test = data
+    pool = (clients["x"].reshape(-1, clients["x"].shape[-1]),
+            clients["y"].reshape(-1))
+    kw = {"K": 4, "E": 3} if framework == "fedavg" else {}
+    return campaign.run_population_campaign(
+        framework, CFG, Population(size=40, seed=0), pool, rounds=ROUNDS,
+        seeds=seeds, cohort=6, samples_per_client=16, test_data=test,
+        eval_every=2, **kw)
+
+
 def _counted(fn):
     """``fn()``'s result, its counters and its ``segment`` spans."""
     before = spans.counts.copy()
@@ -90,6 +104,18 @@ def test_warm_campaign_is_bit_identical_to_cold(data, framework):
     campaign.clear_segment_cache()
     cold, counted, _ = _counted(lambda: _run(data, framework))
     assert counted["segment_hits"] == 0
+    _assert_same(warm, cold)
+
+
+@pytest.mark.parametrize("framework", FRAMEWORKS)
+def test_second_population_campaign_hits_and_matches_cold(data, framework):
+    """run_population_campaign's segments live in the same cache."""
+    cold, first, segs = _counted(lambda: _run_population(data, framework))
+    assert first["segment_builds"] == len(segs) >= 1
+    warm, second, segs = _counted(lambda: _run_population(data, framework))
+    assert second["segment_builds"] == 0
+    assert second["segment_hits"] == len(segs) >= 1
+    assert second["executables_compiled"] == 0
     _assert_same(warm, cold)
 
 
